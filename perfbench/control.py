@@ -1,0 +1,105 @@
+#!/usr/bin/env python3
+"""The control: the reference put in the program's place with one
+guarantee of the configuration broken, judged by the same comparison
+as a run. It has to come out not correct.
+
+    python3 perfbench/control.py --workload NAME --seed N --seconds S \\
+        --control first_sig_only|no_dedup
+
+  first_sig_only  verifies only each txn's first signature (the lane a
+                  later PR could be tempted to drop the rest of): breaks
+                  "no transaction with a failing signature is delivered"
+  no_dedup        verifies every signature but delivers every frame,
+                  resends included: breaks "each transaction is
+                  delivered at most once" (dupflood only)
+
+The corpus and schedule are the cell's own (its rate, its window), as a
+run builds them. No topology boots and no chip is touched; the driver's
+runs never run this. Prints each number compared beside its limit, then
+{"control", "correct", "checks"} as the last line.
+"""
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def _first_sig_ok(payloads):
+    from cryptography.exceptions import InvalidSignature
+    from cryptography.hazmat.primitives.asymmetric.ed25519 import \
+        Ed25519PublicKey
+
+    from perfbench.reference import parse
+    out = []
+    for p in payloads:
+        sigs, pubs, msg = parse(p)
+        try:
+            Ed25519PublicKey.from_public_bytes(pubs[0]).verify(sigs[0], msg)
+            out.append(True)
+        except InvalidSignature:
+            out.append(False)
+    return out
+
+
+def control_run(workload: str, seed: int, seconds: float, control: str,
+                overrides: dict | None = None) -> dict:
+    import multiprocessing as mp
+
+    import numpy as np
+
+    from perfbench import harness, reference, traffic
+    spec = harness.load_cell(workload)
+    for k, v in (overrides or {}).items():
+        spec[k] = v(spec[k])
+    mix, cell = spec["mix"], spec["cell"]
+    warm_s = float(cell["warmup_s"])
+    p = traffic.plan(mix, seed, float(cell["rate"]), warm_s + seconds)
+    procs = traffic.default_procs()
+    corpus = traffic.sign(mix, seed, p, procs)
+    n = len(corpus.size)
+    offered = np.zeros(n, bool)
+    offered[p.send_txn] = True
+    payloads = [corpus.txn(i) for i in range(n)]
+    if control == "first_sig_only":
+        step = -(-n // (procs * 4))
+        chunks = [payloads[i:i + step] for i in range(0, n, step)]
+        with mp.get_context("spawn").Pool(procs) as pool:
+            ok = np.array([v for c in pool.map(_first_sig_ok, chunks)
+                           for v in c], bool)
+        deliveries = ok.astype(np.int32)
+    elif control == "no_dedup":
+        ok = reference.reference_verdicts(payloads, procs)
+        frames = np.bincount(p.send_txn, minlength=n).astype(np.int32)
+        deliveries = np.where(ok, frames, 0)
+    else:
+        raise ValueError(control)
+    ref_idx = reference.sample(corpus.valid, offered, seed)
+    ref_ok = reference.reference_verdicts([payloads[i] for i in ref_idx],
+                                          procs)
+    checks = reference.judge(corpus.valid, offered, deliveries, 0, 0,
+                             ref_idx, ref_ok)
+    return {"control": control, "correct": reference.correct(checks),
+            "checks": checks}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--control", required=True,
+                    choices=("first_sig_only", "no_dedup"))
+    args = ap.parse_args(argv)
+    out = control_run(args.workload, args.seed, args.seconds, args.control)
+    for k, (v, lim) in out["checks"].items():
+        print(f"check {k}: {v} (limit {lim})", file=sys.stderr)
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
