@@ -270,7 +270,7 @@ def _e2e_run(count: int, unique: int, batch: int,
         # per-link attribution (fdmetrics v2): WHERE the hot-path time
         # and backpressure went, hop by hop — published/consumed (loss
         # per hop), producer backpressure ticks, and the consumer-side
-        # consume-latency quantiles — so the bench trajectory records
+        # queue-wait quantiles — so the bench trajectory records
         # which hop throttles end-to-end TPS, not just the number
         link_budget = {}
         for ln, rec in read_link_metrics(runner.wksp,

@@ -79,7 +79,8 @@ Schema:
     [[slo.target]]           # one objective per table (merged by name
     name = "verify-latency"  #  across layers); expr grammar:
     expr = "verify.work p99 < 500us"   # <source> [agg] <op> <threshold>
-                             #  sources: tile.metric, tile.wait|work|tpu,
+                             #  sources: tile.metric,
+                             #  tile.wait|work|tpu|stall|gc,
                              #  link.<link>.<counter>
 
     [shed]                   # front-door policing (disco/shed.py):

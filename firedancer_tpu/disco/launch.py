@@ -184,6 +184,13 @@ class TopologyRunner:
         # supervisor counters ride in the region's top slots
         from .supervise import sup_counters
         out.update(sup_counters(vals))
+        # the queue wait on each in link (the tile's consumer blocks):
+        # frags timed and their summed publish -> consume wait
+        from .metrics import read_queue_waits
+        for ln, (cnt, ns) in read_queue_waits(
+                self.wksp, self.plan, tile_name).items():
+            out[f"{ln}.wait_count"] = cnt
+            out[f"{ln}.wait_ns"] = ns
         return out
 
     def _halt_for_supervisor(self):

@@ -18,37 +18,47 @@ Histogram region layout per tile (all u64, little-endian, single writer):
     [0] count   [1] sum_ns   [2..2+NBUCKETS) bucket counts
 
 bucket i counts samples with ns in [2^i, 2^(i+1)) (bucket 0 takes 0/1ns,
-bucket NBUCKETS-1 is the overflow tail). Three histograms per tile:
+bucket NBUCKETS-1 is the overflow tail). Five histograms per tile:
 WAIT (poll_once returned 0 — idle spin), WORK (frags were processed) —
 the same wait/work split the reference attributes per link pair
 (ref: fd_stem.c metrics, src/disco/metrics/fd_metrics.h regime
-counters) — and TPU (device dispatch + verdict readback time, fed by
-the verify tile's `tpu_hist` accumulator; zero for host-only tiles).
+counters) — TPU (device dispatch + verdict readback time, fed by
+the verify tile's `tpu_hist` accumulator; zero for host-only tiles),
+STALL (polls and housekeeping passes of STALL_NS or longer, outside a
+device capture's own start/stop) and GC (Python collector pauses in
+the tile process). The plan's `hist_u64` records how many kinds a
+tile's region holds, so readers of an older plan read fewer.
 
 Per-link telemetry (fdmetrics v2) extends the same ABI below the tile
 regions: every link gets a PRODUCER block (written only by the
 producing tile's stem — links are SPMC, so the single-writer rule
 holds) and every (consumer tile, in link) pair gets a CONSUMER block
-with a consume-latency histogram. Producer-side publish counters and
-consumer-side consume counters land in one ABI so any reader can
-compute per-hop loss (published - consumed) — the reference attributes
-time and backpressure per link pair the same way (fd_stem.c regime
-counters).
+with a queue-wait histogram: each frag's publish -> consume wait, from
+the 32-bit publish stamp in its ring slot (runtime/tango.py Ring
+m_wait; waits of 2^32 ns, 4.29 s, or more alias). Producer-side
+publish counters and consumer-side consume counters land in one ABI so
+any reader can compute per-hop loss (published - consumed) — the
+reference attributes time and backpressure per link pair the same way
+(fd_stem.c regime counters).
 
     producer block (u64): [0] pub  [1] pub_bytes  [2] backpressure
     consumer block (u64): [0] consumed [1] bytes [2] overruns
-                          [3..3+HIST_U64) consume-latency histogram
+                          [3..3+HIST_U64) queue-wait histogram
 """
 from __future__ import annotations
 
 import numpy as np
 
-from ..runtime.tango import u64_snapshot
+from ..runtime.tango import WAIT_HIST_U64, u64_snapshot
 
 NBUCKETS = 32
 HIST_U64 = 2 + NBUCKETS          # count, sum_ns, buckets
-HIST_KINDS = ("wait", "work", "tpu")   # order fixes the shm layout
+assert HIST_U64 == WAIT_HIST_U64     # the ring fills this layout natively
+# order fixes the shm layout
+HIST_KINDS = ("wait", "work", "tpu", "stall", "gc")
 HIST_REGION_U64 = HIST_U64 * len(HIST_KINDS)
+# kinds rendered as fdtpu_tile_<kind>_seconds, and only once non-empty
+TILE_HIST_KINDS = ("tpu", "stall", "gc")
 
 # -- per-link telemetry block ABI -------------------------------------------
 LINK_PROD_COUNTERS = ("pub", "pub_bytes", "backpressure")
@@ -135,7 +145,7 @@ def link_lag(rec: dict, consumer: str) -> int:
 
 def merge_hists(hists) -> dict | None:
     """Bucketwise sum of log2 histogram dicts (None if all empty) —
-    e.g. one link-level consume-latency quantile over rr-sharded
+    e.g. one link-level queue-wait quantile over rr-sharded
     consumers instead of one arbitrary shard's."""
     hs = [h for h in hists if h["count"]]
     if not hs:
@@ -195,6 +205,19 @@ def read_link_metrics(wksp, plan: dict, links=None) -> dict:
     return out
 
 
+def read_queue_waits(wksp, plan: dict, tile_name: str) -> dict:
+    """{in link: (frags timed, summed queue wait ns)} from one tile's
+    consumer blocks — the count and sum words of each queue-wait
+    histogram, read in one copy so the pair is coherent."""
+    out = {}
+    for ln, off in (plan["tiles"][tile_name].get("link_metrics")
+                    or {}).items():
+        o = off + len(LINK_CONS_COUNTERS) * 8
+        raw = wksp.view(o, 16).view(np.uint64).copy()
+        out[ln] = (int(raw[0]), int(raw[1]))
+    return out
+
+
 def quantile_ns(hist: dict, q: float) -> int:
     """Upper-bound estimate of the q-quantile from log2 buckets.
     Edges: an empty histogram is 0; q=0.0 is the minimum sample's
@@ -238,10 +261,10 @@ def _render_hist(lines: list[str], base: str, lab: str, h: dict):
 
 
 def render_prometheus(plan: dict, wksp) -> str:
-    """All tiles' named counters, wait/work/tpu histograms, liveness,
-    per-link telemetry, and device (`tpu_*`) series, in prometheus text
-    exposition format. Reader-side only (any process attached to the
-    workspace can render)."""
+    """All tiles' named counters, wait/work/tpu/stall/gc histograms,
+    liveness, per-link telemetry, and device (`tpu_*`) series, in
+    prometheus text exposition format. Reader-side only (any process
+    attached to the workspace can render)."""
     from ..runtime import Cnc, CNC_RUN
     from . import topo as topo_mod
 
@@ -253,7 +276,7 @@ def render_prometheus(plan: dict, wksp) -> str:
         "# TYPE fdtpu_tile_gauge gauge",
     ]
     hist_lines: list[str] = []
-    tpu_hist_lines: list[str] = []
+    tile_hist_lines: dict[str, list[str]] = {}
     # DEVICE_SERIES-declared slots are the device-telemetry series:
     # promoted to their own family (fdtpu_tile_<name>) instead of the
     # generic name-labeled series, so dashboards get first-class
@@ -296,13 +319,13 @@ def render_prometheus(plan: dict, wksp) -> str:
                 else "fdtpu_tile_metric"
             lines.append(f'{series}{{{lab},name="{nm}"}} {val}')
         for kind, h in read_hists(wksp, plan, tn).items():
-            if kind == "tpu":
-                # device-time attribution: only tiles that actually
-                # drive a device populate it — zero-count tiles stay
+            if kind in TILE_HIST_KINDS:
+                # device time, stalls, collector pauses: only tiles
+                # that have any populate them — zero-count tiles stay
                 # out of the exposition (no empty series per tile)
                 if h["count"]:
-                    _render_hist(tpu_hist_lines,
-                                 "fdtpu_tile_tpu_seconds", lab, h)
+                    _render_hist(tile_hist_lines.setdefault(kind, []),
+                                 f"fdtpu_tile_{kind}_seconds", lab, h)
                 continue
             _render_hist(hist_lines, f"fdtpu_poll_{kind}_seconds",
                          lab, h)
@@ -310,9 +333,10 @@ def render_prometheus(plan: dict, wksp) -> str:
         lines.append("# TYPE fdtpu_poll_wait_seconds histogram")
         lines.append("# TYPE fdtpu_poll_work_seconds histogram")
         lines.extend(hist_lines)
-    if tpu_hist_lines:
-        lines.append("# TYPE fdtpu_tile_tpu_seconds histogram")
-        lines.extend(tpu_hist_lines)
+    for kind in TILE_HIST_KINDS:
+        if kind in tile_hist_lines:
+            lines.append(f"# TYPE fdtpu_tile_{kind}_seconds histogram")
+            lines.extend(tile_hist_lines[kind])
     for fam in sorted(tpu_series):
         typ, out = tpu_series[fam]
         lines.append(f"# TYPE {fam} {typ}")
@@ -324,8 +348,8 @@ def render_prometheus(plan: dict, wksp) -> str:
 def _render_links(plan: dict, wksp, topo: str) -> list[str]:
     """fdtpu_link_* per-link series, labeled link/producer/consumer —
     the per-hop half of the exposition (publish counters from the
-    producer block, consume counters + latency histogram per consumer,
-    and the derived lag gauge = published - consumed)."""
+    producer block, consume counters + queue-wait histogram per
+    consumer, and the derived lag gauge = published - consumed)."""
     links = read_link_metrics(wksp, plan)
     if not links:
         return []
@@ -356,6 +380,8 @@ def _render_links(plan: dict, wksp, topo: str) -> list[str]:
                 _render_hist(hist_lines, "fdtpu_link_consume_seconds",
                              clab, c["hist"])
     if hist_lines:
+        lines.append("# HELP fdtpu_link_consume_seconds queue wait "
+                     "(publish -> consume) of each frag on the link")
         lines.append("# TYPE fdtpu_link_consume_seconds histogram")
         lines.extend(hist_lines)
     return lines

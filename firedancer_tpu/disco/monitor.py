@@ -83,8 +83,9 @@ def snapshot(plan: dict, wksp: Workspace) -> dict:
 def links_table(link_metrics: dict) -> dict:
     """read_link_metrics output -> one JSON-able row per (link,
     consumer): publish/consume counters, per-hop loss, backpressure,
-    and consume-latency quantiles — the fdmetrics v2 surface shared by
-    the monitor table, --json, and the metric tile's /summary.json."""
+    and the queue-wait (publish -> consume) quantiles p50_us/p99_us —
+    the fdmetrics v2 surface shared by the monitor table, --json, and
+    the metric tile's /summary.json."""
     from .metrics import link_lag, quantile_ns
     rows: dict = {}
     for ln, rec in link_metrics.items():
@@ -196,12 +197,13 @@ def format_table(snap: dict, prev: dict | None = None) -> str:
 
 def format_links(links: dict) -> str:
     """Per-link table: one row per (link, consumer) with publish /
-    consume / loss / backpressure and the consume-latency quantiles."""
+    consume / loss / backpressure and the queue-wait (publish ->
+    consume) quantiles."""
     if not links:
         return ""
     lines = [f"{'link':<18}{'producer':<12}{'consumer':<12}"
              f"{'pub':>10}{'consumed':>10}{'lost':>7}{'bp':>8}"
-             f"{'p50us':>8}{'p99us':>8}"]
+             f"{'wait_p50us':>11}{'wait_p99us':>11}"]
     for ln in sorted(links):
         rec = links[ln]
         cons = rec["consumers"] or {"-": None}
@@ -211,13 +213,13 @@ def format_links(links: dict) -> str:
                 lines.append(
                     f"{ln:<18}{rec['producer'] or '-':<12}{'-':<12}"
                     f"{rec['pub']:>10}{'-':>10}{'-':>7}"
-                    f"{rec['backpressure']:>8}{'-':>8}{'-':>8}")
+                    f"{rec['backpressure']:>8}{'-':>11}{'-':>11}")
                 continue
             lines.append(
                 f"{ln:<18}{rec['producer'] or '-':<12}{tn:<12}"
                 f"{rec['pub']:>10}{c['consumed']:>10}{c['lag']:>7}"
-                f"{rec['backpressure']:>8}{c['p50_us']:>8.0f}"
-                f"{c['p99_us']:>8.0f}")
+                f"{rec['backpressure']:>8}{c['p50_us']:>11.0f}"
+                f"{c['p99_us']:>11.0f}")
     return "\n".join(lines)
 
 

@@ -6,8 +6,8 @@ meeting the objective" without a sidecar stack: the metric tile owns
 exposition (fd_metric_tile.c), and alerting-grade roll-ups belong next
 to it. Here a validated `[slo]` topology section declares objectives
 as one-line expressions over the SAME shm regions every other reader
-uses (tile metric slots, wait/work/tpu histograms, per-link telemetry
-blocks), and the metric tile evaluates them at its housekeeping
+uses (tile metric slots, wait/work/tpu/stall/gc histograms, per-link
+telemetry blocks), and the metric tile evaluates them at its housekeeping
 cadence — reader-side only, so the engine survives any tile's death.
 
 Expression grammar (one line per target):
@@ -15,7 +15,7 @@ Expression grammar (one line per target):
     <source> [<agg>] <op> <threshold>
 
     source   <tile>.<metric>          a named tile metric slot
-             <tile>.<hist>            wait | work | tpu histogram
+             <tile>.<hist>            wait | work | tpu | stall | gc histogram
              link.<link>.<counter>    per-link telemetry (consumer
                                       counters are summed across the
                                       link's consumers)
@@ -218,7 +218,7 @@ def parse_expr(expr: str) -> dict:
     if len(parts) != 2:
         raise ValueError(
             f"slo expr {expr!r}: tile source is '<tile>.<metric>' or "
-            f"'<tile>.<wait|work|tpu>'")
+            f"'<tile>.<wait|work|tpu|stall|gc>'")
     if agg in _QUANTILES:
         return {"kind": "hist", "tile": parts[0], "hist": parts[1],
                 "agg": agg, "op": op, "threshold": value}

@@ -17,14 +17,28 @@ HALT/FAIL), heartbeating, the lazy housekeeping interval with jitter
 consumer-side fseq publication (so upstream producers can credit-gate),
 and flushing tile metrics into the shared-memory metrics region the
 monitor reads (ref: src/disco/metrics/fd_metrics.h:6-40).
+
+It also keeps the tile's stall and collector-pause histograms: a poll
+or housekeeping pass of STALL_NS or longer is a stall (traced, an
+EV_STALL instant naming the stage it spent most time in — the tile's
+`stages` StageClock, a collector pause, or the poll/housekeeping pass
+itself); a stall in a device capture's own start/stop is left to the
+tile's capture stage counter, so traced runs can leave it out. Python
+collector pauses (gc.callbacks) land in the gc histogram.
 """
 from __future__ import annotations
 
+import gc
 import random
 import time
 
 from ..runtime import CNC_RUN, CNC_HALT, CNC_FAIL
-from .metrics import HIST_U64, HistAccum
+from ..trace.events import STAGE_IDS
+from .metrics import HIST_KINDS, HIST_U64, HistAccum
+
+STALL_NS = 50_000_000     # a poll or housekeeping pass this long stalls
+_CAPTURE, _GC, _POLL, _HOUSEKEEP = (
+    STAGE_IDS[s] for s in ("capture", "gc", "poll", "housekeep"))
 
 
 class Stem:
@@ -56,37 +70,34 @@ class Stem:
         # insertion order of its first metrics_items() result
         self._metrics_names: list[str] | None = \
             list(ctx.spec.get("metrics_names", [])) or None
-        # wait/work poll latency histograms (flushed at housekeeping);
-        # seeded from shm so a supervised restart RESUMES the
-        # cumulative series (flush_into writes wholesale — a fresh
-        # accumulator would rewind readers to zero), same continuity
-        # contract as the link counters below. The tile-owned tpu
-        # histogram (verify's tpu_hist) gets the same seeding.
-        self._hists = {"wait": HistAccum(), "work": HistAccum()}
+        # wait/work poll latency, stall and gc histograms (flushed at
+        # housekeeping); seeded from shm so a supervised restart
+        # RESUMES the cumulative series (flush_into writes wholesale —
+        # a fresh accumulator would rewind readers to zero), same
+        # continuity contract as the link counters. The tile-owned tpu
+        # histogram (verify's tpu_hist) gets the same seeding. Only the
+        # kinds the plan's region holds are bound (an older plan holds
+        # fewer).
+        self._hists = {"wait": HistAccum(), "work": HistAccum(),
+                       "stall": HistAccum(), "gc": HistAccum()}
+        owned = dict(self._hists)
+        if getattr(tile, "tpu_hist", None) is not None:
+            owned["tpu"] = tile.tpu_hist
+        self._hist_views = []
         hv = ctx.hist_view()
         if hv is not None:
-            self._hists["wait"].seed_from(hv[0:HIST_U64])
-            self._hists["work"].seed_from(hv[HIST_U64:2 * HIST_U64])
-            tpu = getattr(tile, "tpu_hist", None)
-            if tpu is not None and len(hv) >= 3 * HIST_U64:
-                tpu.seed_from(hv[2 * HIST_U64:3 * HIST_U64])
-        # per-link consume-latency histograms (fdmetrics v2): one
-        # accumulator per in link, fed in the poll loop by attributing
-        # each productive poll's duration to every link whose consume
-        # counter advanced — no extra timestamp beyond the t0/t1 the
-        # wait/work split already takes (the reference's per-link-pair
-        # regime attribution, fd_stem.c)
-        self._link_hists = {ln: HistAccum()
-                            for ln in ctx.link_cons_views} \
-            if getattr(ctx, "link_cons_views", None) else {}
-        # restart continuity: resume the cumulative consume-latency
-        # series from shm, and start the seen-cursor at the (seeded,
-        # TileCtx) consume counter so the first poll after a respawn
-        # isn't falsely attributed to every link
-        for ln, h in self._link_hists.items():
-            h.seed_from(ctx.link_cons_views[ln][3:3 + HIST_U64])
-        self._link_seen = {ln: ctx.in_rings[ln].m_consumed
-                          for ln in self._link_hists}
+            for i, kind in enumerate(HIST_KINDS[:len(hv) // HIST_U64]):
+                if kind in owned:
+                    view = hv[i * HIST_U64:(i + 1) * HIST_U64]
+                    owned[kind].seed_from(view)
+                    self._hist_views.append((view, owned[kind]))
+        # the tile's stage accounting (trace/recorder.py StageClock), if
+        # it keeps one: a stall names the stage it spent most time in
+        self._stages = getattr(tile, "stages", None)
+        self._gc_t0 = 0
+        self._gc_mark = 0              # gc ns at the last housekeeping
+        self._gc_spans: list[tuple[int, int]] = []
+        self._stall_said = 0           # when a stall was last logged
         # chaos harness: a seeded fault plan injected purely via tile
         # args (utils/chaos.py) — fires deterministically in run()
         self._chaos = None
@@ -112,6 +123,12 @@ class Stem:
         self._prof_region = getattr(ctx, "prof", None)
         self._prof_state = None
         self._sampler = None
+        # fdprof's active in-link: the in link whose consume counter
+        # advanced in the last productive poll (profiled tiles only)
+        self._link_seen = {
+            ln: r.m_consumed
+            for ln, r in getattr(ctx, "in_rings", {}).items()} \
+            if self._prof_region is not None else {}
         self._wait_t0: int | None = None      # idle-streak start (ns)
         # WORK attribution accumulators: with sample>1 one EV_WORK
         # record aggregates the last `sample` productive polls
@@ -152,15 +169,55 @@ class Stem:
                 if hook is not None:
                     hook(ev)
 
-    def _stop_sampler(self):
-        """Stop the fdprof sampler on ANY loop exit (halt, fail,
-        external FAIL): the shm region keeps the aggregate — a stopped
-        sampler loses nothing, but a sampler outliving run() would
-        keep attributing samples to a loop that no longer exists."""
+    def _leave(self):
+        """On ANY loop exit (halt, fail, external FAIL): stop the fdprof
+        sampler — the shm region keeps the aggregate, but a sampler
+        outliving run() would keep attributing samples to a loop that
+        no longer exists — and stop timing collector pauses."""
         if self._sampler is not None:
             self._sampler.stop()
             self._sampler = None
             self._prof_state = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict):
+        """gc.callbacks hook: one collector pause into the gc histogram
+        (traced: queued for an EV_STAGE span at the next housekeeping
+        pass — the collector can run in the middle of a ring append)."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0:
+            t1 = time.perf_counter_ns()
+            self._hists["gc"].add(t1 - self._gc_t0)
+            if self._trace is not None and len(self._gc_spans) < 64:
+                self._gc_spans.append((t1, t1 - self._gc_t0))
+            self._gc_t0 = 0
+
+    def _stall(self, dt: int, t_end: int, where: int):
+        """A poll or housekeeping pass of dt >= STALL_NS ns ending at
+        t_end: name the stage it spent most time in (the tile's longest
+        stage since the pass began, a collector pause since the last
+        housekeeping if longer, else `where`), count it unless it is
+        a device capture's own start/stop, and trace it."""
+        stage, top = where, 0
+        st = self._stages
+        if st is not None and st.top_ns:
+            stage, top = st.top, st.top_ns
+        if self._hists["gc"].sum_ns - self._gc_mark > top:
+            stage = _GC
+        if stage != _CAPTURE:
+            self._hists["stall"].add(dt)
+        if self._trace is not None:
+            from ..trace.events import EV_STALL
+            self._trace.ring.append(t_end, EV_STALL, arg=dt, count=stage)
+        if t_end - self._stall_said >= 1_000_000_000:
+            # at most one line a second: the histogram counts them all
+            self._stall_said = t_end
+            from ..trace.events import STAGE_NAMES
+            from ..utils import log
+            log.warning(f"stall: {dt / 1e6:.1f} ms, longest in "
+                        f"{STAGE_NAMES[stage]}")
 
     def _trace_flush(self, tr):
         """Close out pending trace state on any loop exit (halt, fail,
@@ -188,28 +245,25 @@ class Stem:
                 if i >= len(view):
                     break
                 view[i] = d.get(k, 0)
-        hv = self.ctx.hist_view()
-        if hv is not None:
-            self._hists["wait"].flush_into(hv[0:HIST_U64])
-            self._hists["work"].flush_into(hv[HIST_U64:2 * HIST_U64])
-            # device-time attribution: a tile that drives an
-            # accelerator exposes a `tpu_hist` HistAccum (verify tile's
-            # dispatch+readback spans); host-only tiles leave slot 3
-            # zero and the renderer skips the empty series
-            tpu = getattr(self.tile, "tpu_hist", None)
-            if tpu is not None and len(hv) >= 3 * HIST_U64:
-                tpu.flush_into(hv[2 * HIST_U64:3 * HIST_U64])
+        # device-time attribution: a tile that drives an accelerator
+        # exposes a `tpu_hist` HistAccum (verify tile's dispatch +
+        # readback time); host-only tiles leave its slot zero and the
+        # renderer skips the empty series
+        for view, acc in self._hist_views:
+            acc.flush_into(view)
         # per-link telemetry blocks: the Ring join's instance-local
-        # counters (runtime/tango.py) are THE per-link truth for this
-        # tile; flushing them wholesale keeps the hot path free of any
-        # shm write (same single-writer cumulative contract as hists)
+        # counters and queue-wait histogram (runtime/tango.py) are THE
+        # per-link truth for this tile; flushing them wholesale keeps
+        # the hot path free of any shm write (same single-writer
+        # cumulative contract as hists: the count word lands last)
         for ln, view in getattr(self.ctx, "link_cons_views",
                                 {}).items():
             r = self.ctx.in_rings[ln]
             view[0] = r.m_consumed
             view[1] = r.m_bytes
             view[2] = r.m_overruns
-            self._link_hists[ln].flush_into(view[3:3 + HIST_U64])
+            view[4:3 + HIST_U64] = r.m_wait[1:]
+            view[3] = r.m_wait[0]
         for ln, view in getattr(self.ctx, "link_prod_views",
                                 {}).items():
             r = self.ctx.out_rings[ln]
@@ -251,6 +305,9 @@ class Stem:
                 stack_depth=int(spec.get("prof_stack_depth", 16)),
             ).start()
         ps = self._prof_state
+        stg = self._stages
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         if tr is not None:
             tr.event(trace_ev.EV_BOOT)
         # jittered lazy interval: same reasoning as the reference's
@@ -275,10 +332,11 @@ class Stem:
                             self._trace_flush(tr)
                             tr.event(trace_ev.EV_FAIL)
                         self._flush_metrics()
-                        self._stop_sampler()
+                        self._leave()
                         return
-                    hk_t0 = time.perf_counter_ns() if tr is not None \
-                        else 0
+                    hk_t0 = time.perf_counter_ns()
+                    if stg is not None:
+                        stg.top_ns = 0
                     if ps is not None:
                         ps.state = 2          # fdprof: housekeep
                     self._update_in_fseqs()
@@ -286,9 +344,17 @@ class Stem:
                     if hk is not None:
                         hk()
                     self._flush_metrics()
+                    hk_t1 = time.perf_counter_ns()
                     if tr is not None:
-                        tr.event(trace_ev.EV_HOUSEKEEP,
-                                 arg=time.perf_counter_ns() - hk_t0)
+                        tr.ring.append(hk_t1, trace_ev.EV_HOUSEKEEP,
+                                       arg=hk_t1 - hk_t0)
+                        for t_end, d in self._gc_spans:
+                            tr.ring.append(t_end, trace_ev.EV_STAGE,
+                                           arg=d, count=_GC)
+                        self._gc_spans.clear()
+                    if hk_t1 - hk_t0 >= STALL_NS:
+                        self._stall(hk_t1 - hk_t0, hk_t1, _HOUSEKEEP)
+                    self._gc_mark = self._hists["gc"].sum_ns
                     next_hk = now + self.hk_interval_s * (
                         0.7 + 0.6 * random.random())
                 if self._wedged:
@@ -297,6 +363,8 @@ class Stem:
                     time.sleep(0.005)
                     iters += 1
                     continue
+                if stg is not None:
+                    stg.top_ns = 0
                 t0 = time.perf_counter_ns()
                 n = self.tile.poll_once()
                 t1 = time.perf_counter_ns()
@@ -304,18 +372,15 @@ class Stem:
                 # spent waiting on upstream, a productive one is work
                 # (the reference's per-link regime split)
                 self._hists["work" if n else "wait"].add(t1 - t0)
+                if t1 - t0 >= STALL_NS:
+                    self._stall(t1 - t0, t1, _POLL)
                 if ps is not None:
                     ps.state = 1 if n else 0  # fdprof: work / wait
-                if n and self._link_hists:
-                    # per-link consume latency: attribute this poll's
-                    # duration to every in link whose Ring consume
-                    # counter advanced (one int compare per link)
-                    for ln, h in self._link_hists.items():
-                        c = self.ctx.in_rings[ln].m_consumed
-                        if c != self._link_seen[ln]:
-                            self._link_seen[ln] = c
-                            h.add(t1 - t0)
-                            if ps is not None:
+                    if n:
+                        for ln, c in self._link_seen.items():
+                            cur = self.ctx.in_rings[ln].m_consumed
+                            if cur != c:
+                                self._link_seen[ln] = cur
                                 ps.link = ln  # fdprof: active in-link
                 if tr is not None:
                     # trace shape: one WAIT span per idle STREAK
@@ -360,7 +425,7 @@ class Stem:
                 self._trace_flush(tr)
                 tr.event(trace_ev.EV_FAIL)
             self._flush_metrics()
-            self._stop_sampler()
+            self._leave()
             from ..utils import log
             log.err(f"tile failed: {e!r}")
             raise
@@ -373,5 +438,5 @@ class Stem:
         if tr is not None:
             self._trace_flush(tr)
             tr.event(trace_ev.EV_HALT)
-        self._stop_sampler()
+        self._leave()
         cnc.state = CNC_HALT

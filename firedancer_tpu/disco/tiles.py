@@ -16,8 +16,13 @@ import time
 import numpy as np
 
 from ..protocol.txn import MTU
+from ..trace.events import STAGE_IDS, VERIFY_STAGES
 
 REGISTRY: dict[str, type] = {}
+
+# the verify tile's ns_<stage> slots: its batch stages + capture
+_VERIFY_NS = VERIFY_STAGES + ("capture",)
+_STAGE_CAPTURE = STAGE_IDS["capture"]
 
 
 def register(kind: str):
@@ -292,7 +297,18 @@ class VerifyAdapter:
                "tpu_inflight", "tpu_mem_bytes",
                # fdprof: warmup compile wall time + device-capture
                # windows served (the observability of the profiler)
-               "tpu_compile_ns", "prof_captures"]
+               "tpu_compile_ns", "prof_captures",
+               # stage accounting (tiles/verify.py): host ns per stage
+               # (trace/events.py STAGE_IDS; capture = a device
+               # capture's own start/stop), strict lanes dispatched,
+               # parked duplicates and their host re-verifies, and the
+               # summed residence of the txns that reached a device lane
+               "ns_gather", "ns_parse", "ns_dedup", "ns_assemble",
+               "ns_dispatch", "ns_readback", "ns_finalize",
+               "ns_deferred", "ns_publish", "ns_capture",
+               "lanes", "deferred_parked", "deferred_host_verifies",
+               "txn_hold_ns", "txn_device_ns", "txn_egress_ns",
+               "txn_timed"]
     GAUGES = ["cpu_fallback", "tpu_jit_compiles", "tpu_jit_cache_miss",
               "tpu_inflight", "tpu_mem_bytes", "tpu_compile_ns"]
     # declared (not name-sniffed) device-telemetry slots: the renderer
@@ -339,8 +355,10 @@ class VerifyAdapter:
         self.in_link = next(iter(ctx.in_rings))
         self.tile.seq = ctx.in_seq0.get(self.in_link, 0)
         # device-time attribution: the stem flushes this accumulator
-        # into the tile's third (tpu) histogram slot
+        # into the tile's third (tpu) histogram slot; a stall names the
+        # stage the tile's clock saw take longest
         self.tpu_hist = self.tile.tpu_hist
+        self.stages = self.tile.stages
         # fdprof device side: compile-event watch (EV_COMPILE + a
         # manifest when profiled) and the capture doorbell handler —
         # both polled at housekeeping cadence, never in the hot loop
@@ -360,7 +378,13 @@ class VerifyAdapter:
     def housekeeping(self):
         self._compile_watch.poll()
         if self._capture is not None:
+            # a window opening or closing is the capture stage: the
+            # profiler's own start/stop, not the tile's work
+            from ..utils.tempo import monotonic_ns
+            was, t0 = self._capture.active, monotonic_ns()
             self._capture.poll()
+            if self._capture.active != was:
+                self.stages.add(_STAGE_CAPTURE, t0)
         knobs = getattr(self.ctx, "knobs", None)
         if knobs is not None:
             v = knobs.get("coalesce_us")
@@ -409,6 +433,9 @@ class VerifyAdapter:
         m["tpu_compile_ns"] = self.tile.compile_ns
         m["prof_captures"] = self._capture.captures \
             if self._capture is not None else 0
+        ns = self.tile.stages.ns
+        for name in _VERIFY_NS:
+            m[f"ns_{name}"] = ns[STAGE_IDS[name]]
         return m
 
 
@@ -2968,7 +2995,7 @@ class MetricAdapter:
 @register("flight")
 class FlightAdapter:
     """fdflight recorder tile (r19): drains the shm observability
-    plane — metric slot deltas, link counters + consume-latency
+    plane — metric slot deltas, link counters + queue-wait
     quantiles, SLO breach/clear transitions, sampled trace events,
     prof folded-stack digests — into the durable on-disk archive the
     `[flight]` section configures (flight/archive.py segments +

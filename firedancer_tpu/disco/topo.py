@@ -47,7 +47,7 @@ def _metric_gauges(kind: str) -> list[str]:
 # fdtpu_tile_<name> families the renderer already emits; a promoted
 # device series may not shadow them (checked at build, below)
 _RESERVED_TILE_FAMILIES = ("metric", "gauge", "liveness_seconds",
-                           "tpu_seconds")
+                           "tpu_seconds", "stall_seconds", "gc_seconds")
 
 
 def _metric_device(kind: str) -> list[str]:
@@ -408,7 +408,7 @@ class Topology:
                         f"collide with supervisor slots (max "
                         f"{SUP_SLOT_MIN})")
                 # per-(consumer, in-link) telemetry block: consume
-                # counters + a consume-latency histogram, fed by this
+                # counters + a queue-wait histogram, fed by this
                 # tile's stem (single writer) — the reader side matches
                 # by (tile, link) from the plan, never by order
                 link_metrics = {}
@@ -585,7 +585,7 @@ class TileCtx:
         # single-writer by construction, flushed by the stem. Plans
         # built before the link ABI existed leave both dicts empty.
         import numpy as np
-        from .metrics import LINK_CONS_U64, LINK_PROD_U64
+        from .metrics import HIST_U64, LINK_CONS_U64, LINK_PROD_U64
         self.link_cons_views = {
             ln: self.wksp.view(off, LINK_CONS_U64 * 8).view(np.uint64)
             for ln, off in (self.spec.get("link_metrics") or {}).items()
@@ -603,12 +603,14 @@ class TileCtx:
         # so the series resumes instead of resetting (a zeroed consumed
         # counter would count everything consumed before the restart as
         # per-hop loss). Fresh boots seed zeros: a no-op.
+        from ..runtime.tango import u64_snapshot
         for ln, view in self.link_cons_views.items():
             r = self.in_rings.get(ln)
             if r is not None:
                 r.m_consumed = int(view[0])
                 r.m_bytes = int(view[1])
                 r.m_overruns = int(view[2])
+                r.m_wait[:] = u64_snapshot(view[3:3 + HIST_U64])
         for ln, view in self.link_prod_views.items():
             r = self.out_rings[ln]
             r.m_pub = int(view[0])

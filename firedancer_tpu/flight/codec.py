@@ -33,7 +33,7 @@ get written):
     KIND_HIST    per-tile stem-histogram series (wait/work/tpu sum_ns
                  deltas + work p99 level, aux=1 for levels)  "metrics"
     KIND_LINK    per-link counter delta (pub/consumed/backpressure/..
-                 aggregated over consumers) + consume-latency quantile
+                 aggregated over consumers) + queue-wait quantile
                  levels (aux=1)                              "links"
     KIND_SLO     SLO breach/clear transition (name = "breach"|"clear",
                  value = measured value clamped to u64, aux = total

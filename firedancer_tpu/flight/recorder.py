@@ -154,6 +154,8 @@ class FlightRecorder:
                 if d:
                     self._emit(KIND_LINK, ts, ln, nm, d)
                 prev[nm] = v
+            # the link's queue wait (publish -> consume), merged over
+            # its consumers
             h = merge_hists(c["hist"] for c in cons.values()
                             if c.get("hist"))
             if h and h.get("count"):

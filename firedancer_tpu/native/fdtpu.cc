@@ -19,6 +19,7 @@
 namespace {
 
 constexpr uint64_t kAlign = 64;  /* cacheline */
+constexpr int kHistBuckets = 32;  /* disco/metrics.py NBUCKETS */
 
 inline uint64_t align_up(uint64_t v) { return (v + kAlign - 1) & ~(kAlign - 1); }
 
@@ -482,11 +483,40 @@ int fdtpu_tcache_insert(void *base, uint64_t off, uint64_t tag) {
 
 /* ---- batch gather ------------------------------------------------------ */
 
+void fdtpu_hist_waits(uint64_t *hist, uint32_t now, const uint32_t *tspub,
+                      int64_t n) {
+  for (int64_t i = 0; i < n; i++) {
+    uint64_t ns = (uint32_t)(now - tspub[i]);  /* mod 2^32: wraps cleanly */
+    int b = ns <= 1 ? 0 : 63 - __builtin_clzll(ns);
+    if (b > kHistBuckets - 1) b = kHistBuckets - 1;
+    hist[2 + b]++;
+    hist[1] += ns;
+  }
+  hist[0] += (uint64_t)n;
+}
+
 int64_t fdtpu_ring_gather(void *base, uint64_t ring_off, uint64_t *seq_io,
                           int64_t max_n, uint8_t *out_buf,
                           uint64_t out_stride, uint32_t *out_sz,
                           uint64_t *out_sig, uint64_t *overrun_cnt,
                           uint64_t *out_seq) {
+  return fdtpu_ring_gather_wait(base, ring_off, seq_io, max_n, out_buf,
+                                out_stride, out_sz, out_sig, overrun_cnt,
+                                out_seq, nullptr);
+}
+
+int64_t fdtpu_ring_gather_wait(void *base, uint64_t ring_off,
+                               uint64_t *seq_io, int64_t max_n,
+                               uint8_t *out_buf, uint64_t out_stride,
+                               uint32_t *out_sz, uint64_t *out_sig,
+                               uint64_t *overrun_cnt, uint64_t *out_seq,
+                               uint64_t *wait_hist) {
+  /* Publish stamps of the frags gathered so far: the clock is read once
+   * per block of them, AFTER they were observed, so every stamp is at or
+   * before `now` and the mod-2^32 difference is the true wait. */
+  constexpr int kStamps = 256;
+  uint32_t stamps[kStamps];
+  int n_stamps = 0;
   int64_t n = 0;
   uint64_t seq = *seq_io;
   fdtpu_frag_t frag;
@@ -522,9 +552,19 @@ int64_t fdtpu_ring_gather(void *base, uint64_t ring_off, uint64_t *seq_io,
     if (out_sig) out_sig[n] = frag.sig;
     if (out_seq) out_seq[n] = seq;  /* per-frag seq: round-robin sharding
                                        key (ref: fd_verify_tile.c:49-53) */
+    if (wait_hist) {
+      stamps[n_stamps++] = frag.tspub;
+      if (n_stamps == kStamps) {
+        fdtpu_hist_waits(wait_hist, (uint32_t)fdtpu_ticks(), stamps,
+                         n_stamps);
+        n_stamps = 0;
+      }
+    }
     n++;
     seq++;
   }
+  if (wait_hist && n_stamps)
+    fdtpu_hist_waits(wait_hist, (uint32_t)fdtpu_ticks(), stamps, n_stamps);
   *seq_io = seq;
   return n;
 }

@@ -170,6 +170,25 @@ int64_t fdtpu_ring_gather(void *base, uint64_t ring_off, uint64_t *seq_io,
                           uint64_t *out_sig, uint64_t *overrun_cnt,
                           uint64_t *out_seq);
 
+/* fdtpu_ring_gather that also records each gathered frag's queue wait
+ * (publish -> gather, from the slot's tspub) into wait_hist, a log2
+ * histogram in the disco/metrics.py layout: [0] count, [1] sum_ns,
+ * [2..34) bucket counts (bucket i holds waits in [2^i, 2^(i+1)) ns).
+ * The clock is read once per gathered block, after the frags were
+ * observed. tspub is the low 32 bits of fdtpu_ticks, so a wait of
+ * 2^32 ns (4.29 s) or more aliases to its remainder mod 2^32. */
+int64_t fdtpu_ring_gather_wait(void *base, uint64_t ring_off,
+                               uint64_t *seq_io, int64_t max_n,
+                               uint8_t *out_buf, uint64_t out_stride,
+                               uint32_t *out_sz, uint64_t *out_sig,
+                               uint64_t *overrun_cnt, uint64_t *out_seq,
+                               uint64_t *wait_hist);
+
+/* Add (uint32)(now - tspub[i]) for each of n publish stamps to hist
+ * (the wait_hist layout above). */
+void fdtpu_hist_waits(uint64_t *hist, uint32_t now, const uint32_t *tspub,
+                      int64_t n);
+
 /* Tick counter (ns). */
 uint64_t fdtpu_ticks(void);
 

@@ -14,6 +14,16 @@ acks. A backend without a working profiler still produces the manifest
 (ok=false + the error) — a breach-triggered drill must leave an
 artifact either way.
 
+One clock with the device trace: profiler times count from the
+capture's start, fdtrace records carry CLOCK_MONOTONIC. Right after
+`start_trace` and right before `stop_trace` the capture makes a
+`fdtpu_clock` annotation whose `mono_ns` stat is the monotonic time it
+was made at, so a reader of the trace maps ring times onto trace times
+(`clock_offset_ns`). A traced tile's own ring records inside the window
+(its stage, wait, work, housekeeping and stall events) are written
+beside the trace as fdtrace.json, so nobody has to race the
+overwrite-oldest ring for them.
+
 Compile events ride the same housekeeping pass: a jit cache-size
 increase since the last pass is a compile the steady-state padding
 discipline should have prevented; it leaves an EV_COMPILE trace event
@@ -23,8 +33,12 @@ from __future__ import annotations
 
 import json
 import os
+import tempfile
 
 from ..utils.tempo import monotonic_ns
+
+CLOCK_ANNOTATION = "fdtpu_clock"
+FDTRACE_FILE = "fdtrace.json"
 
 
 def capture_manifest_path(topology: str, tile: str) -> str:
@@ -36,10 +50,53 @@ def compile_manifest_path(topology: str, tile: str) -> str:
 
 
 def trace_dir(topology: str, tile: str) -> str:
-    # the heavyweight profiler output (TensorBoard/XPlane) goes to
-    # /tmp, not /dev/shm — only the small manifest lives with the
-    # black boxes
-    return f"/tmp/fdtpu_prof_{topology}_{tile}"
+    # the heavyweight profiler output (TensorBoard/XPlane) goes to the
+    # temp directory ($TMPDIR, else /tmp), not /dev/shm — only the
+    # small manifest lives with the black boxes
+    return os.path.join(tempfile.gettempdir(),
+                        f"fdtpu_prof_{topology}_{tile}")
+
+
+def clock_mark():
+    """One instant on both clocks: a profiler annotation carrying the
+    monotonic ns at which it was made (call inside a capture)."""
+    import jax
+    with jax.profiler.TraceAnnotation(CLOCK_ANNOTATION,
+                                      mono_ns=monotonic_ns()):
+        pass
+
+
+def clock_offset_ns(marks) -> int | None:
+    """monotonic ns minus trace ns, from the (trace start_ns, mono_ns)
+    pairs of a capture's fdtpu_clock annotations (their mean; None when
+    there are none): trace time = ring time - offset."""
+    marks = list(marks)
+    if not marks:
+        return None
+    return round(sum(m - t for t, m in marks) / len(marks))
+
+
+def window_records(trace, t0: int, t1: int) -> list:
+    """A traced tile's ring records that overlap [t0, t1], as
+    [end_ns, dur_ns, name] rows oldest-first: spans and instants
+    (frag-scoped records left out), an EV_STAGE span named
+    `stage:<stage>`, an EV_STALL instant `stall:<stage>`."""
+    import numpy as np
+
+    from ..trace import events as ev
+    _, recs = trace.ring.snapshot()
+    ts, arg, meta = recs[:, 0], recs[:, 2], recs[:, 3]
+    et = meta & 0xFFFF
+    span = np.isin(et, list(ev.SPANS))
+    dur = np.where(span, arg, 0)
+    keep = ~np.isin(et, list(ev.FRAG_EVENTS)) \
+        & np.isin(et, list(ev.NAMES)) & (ts >= t0) & (ts - dur <= t1)
+    named = (ev.EV_STAGE, ev.EV_STALL)
+    return [[t, d, ev.NAMES[e] + (":" + ev.STAGE_NAMES.get(c, "?")
+                                  if e in named else "")]
+            for t, d, e, c in zip(ts[keep].tolist(), dur[keep].tolist(),
+                                  et[keep].tolist(),
+                                  (meta[keep] >> 32).tolist())]
 
 
 def request_capture(plan: dict, wksp, tile: str) -> bool:
@@ -72,6 +129,11 @@ class DeviceCapture:
         self._active: dict | None = None
         self.captures = 0
 
+    @property
+    def active(self) -> bool:
+        """A window is open."""
+        return self._active is not None
+
     def _start(self, req: int):
         t0 = monotonic_ns()
         d = trace_dir(self.topology, self.tile)
@@ -80,6 +142,7 @@ class DeviceCapture:
             os.makedirs(d, exist_ok=True)
             import jax
             jax.profiler.start_trace(d)
+            clock_mark()
         except Exception as e:     # noqa: BLE001 — manifest either way
             err = f"{e!r}"[:200]
         self._active = {"req": req, "t0": t0, "dir": d, "err": err,
@@ -88,12 +151,23 @@ class DeviceCapture:
     def _stop(self) -> str | None:
         act, self._active = self._active, None
         t1 = monotonic_ns()
+        fdtrace = None
         if act["err"] is None:
             try:
                 import jax
+                clock_mark()
                 jax.profiler.stop_trace()
             except Exception as e:  # noqa: BLE001
                 act["err"] = f"{e!r}"[:200]
+        if act["err"] is None and self.trace is not None:
+            fdtrace = os.path.join(act["dir"], FDTRACE_FILE)
+            try:
+                with open(fdtrace, "w") as f:
+                    json.dump({"tile": self.tile, "t0_ns": act["t0"],
+                               "t1_ns": t1, "records": window_records(
+                                   self.trace, act["t0"], t1)}, f)
+            except OSError:
+                fdtrace = None
         doc = {
             "topology": self.topology,
             "tile": self.tile,
@@ -103,6 +177,7 @@ class DeviceCapture:
             "window_ms": self.window_ms,
             "ok": act["err"] is None,
             "trace_dir": act["dir"] if act["err"] is None else None,
+            "fdtrace": fdtrace,
             "error": act["err"],
         }
         path = capture_manifest_path(self.topology, self.tile)

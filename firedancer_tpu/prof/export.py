@@ -1,7 +1,7 @@
 """fdprof export: shm profile regions + fdtrace rings -> one merged
 Perfetto bundle, folded-stack text, and top-k summaries.
 
-The merge is the point: fdtrace spans (wait/work/tpu_dispatch/...),
+The merge is the point: fdtrace spans (wait/work/stage/...),
 host flamegraph slices (the sampler's timestamped ring), and the
 verify tile's device/compile events all carry `utils/tempo.monotonic_ns`
 timestamps — ONE clock domain — so the bundle interleaves them on a

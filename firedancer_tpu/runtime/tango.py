@@ -67,6 +67,13 @@ def _load():
                                     ct.POINTER(ct.c_uint8), u64,
                                     ct.POINTER(u32), ct.POINTER(u64),
                                     ct.POINTER(u64), ct.POINTER(u64)]),
+        "fdtpu_ring_gather_wait": (i64, [vp, u64, ct.POINTER(u64), i64,
+                                         ct.POINTER(ct.c_uint8), u64,
+                                         ct.POINTER(u32), ct.POINTER(u64),
+                                         ct.POINTER(u64), ct.POINTER(u64),
+                                         ct.POINTER(u64)]),
+        "fdtpu_hist_waits": (None, [ct.POINTER(u64), u32,
+                                    ct.POINTER(u32), i64]),
         "fdtpu_ticks": (u64, []),
         "fdtpu_txn_parse_batch": (i64, [ct.POINTER(ct.c_uint8),
                                         ct.POINTER(u32), i64, u64, u64, u64,
@@ -115,6 +122,11 @@ def _load():
 
 
 lib = _load()
+
+# the consumer side's queue-wait histogram: the disco/metrics.py log2
+# layout ([0] count, [1] sum_ns, [2..34) buckets), filled natively
+WAIT_HIST_U64 = 2 + 32
+_u64p = ct.POINTER(ct.c_uint64)
 
 
 class Frag(ct.Structure):
@@ -183,7 +195,13 @@ class Ring:
     its own Ring per link, they ARE that tile's per-link counters: the
     stem flushes them into the per-link shm telemetry blocks at the
     housekeeping cadence (disco/metrics.py link ABI, the reference's
-    per-link-pair regime counters, src/disco/metrics/fd_metrics.h)."""
+    per-link-pair regime counters, src/disco/metrics/fd_metrics.h).
+
+    `m_wait` is the consumer side's queue-wait histogram: every frag
+    gathered or consumed adds its publish -> consume wait, taken from
+    the slot's 32-bit publish stamp (waits of 2^32 ns, 4.29 s, or more
+    alias). The native gather fills it with one clock read per block
+    of frags, so the wait costs no Python per frag."""
 
     def __init__(self, wksp: Workspace, off: int, depth: int,
                  arena_off: int = 0, mtu: int = 0, init: bool = False):
@@ -197,6 +215,8 @@ class Ring:
         self.m_consumed = 0
         self.m_bytes = 0
         self.m_overruns = 0
+        self.m_wait = np.zeros(WAIT_HIST_U64, np.uint64)
+        self._wait_p = self.m_wait.ctypes.data_as(_u64p)
         if init:
             rc = lib.fdtpu_ring_init(wksp.base, off, depth)
             if rc:
@@ -275,6 +295,9 @@ class Ring:
         if rc == 0:
             self.m_consumed += 1
             self.m_bytes += frag.sz
+            stamp = ct.c_uint32(frag.tspub)
+            lib.fdtpu_hist_waits(self._wait_p, lib.fdtpu_ticks() & 0xFFFFFFFF,
+                                 ct.byref(stamp), 1)
         elif rc == -1:
             self.m_overruns += 1
         return rc, frag
@@ -289,20 +312,21 @@ class Ring:
         Returns (n, new_seq, buf, sizes, sigs, overruns) — the microbatch
         assembly step of the TPU bridge tile. With want_seqs, appends the
         per-frag seq array (the round-robin sharding key,
-        ref: src/disco/verify/fd_verify_tile.c:49-53)."""
+        ref: src/disco/verify/fd_verify_tile.c:49-53). Each gathered
+        frag's queue wait lands in m_wait."""
         buf = np.zeros((max_n, stride), np.uint8)
         sizes = np.zeros(max_n, np.uint32)
         sigs = np.zeros(max_n, np.uint64)
         seqs = np.zeros(max_n, np.uint64) if want_seqs else None
         seq_io = ct.c_uint64(seq)
         ovr = ct.c_uint64(0)
-        n = lib.fdtpu_ring_gather(
+        n = lib.fdtpu_ring_gather_wait(
             self.wksp.base, self.off, ct.byref(seq_io), max_n,
             buf.ctypes.data_as(ct.POINTER(ct.c_uint8)), stride,
             sizes.ctypes.data_as(ct.POINTER(ct.c_uint32)),
             sigs.ctypes.data_as(ct.POINTER(ct.c_uint64)), ct.byref(ovr),
             seqs.ctypes.data_as(ct.POINTER(ct.c_uint64))
-            if want_seqs else None)
+            if want_seqs else None, self._wait_p)
         if n:
             self.m_consumed += int(n)
             self.m_bytes += int(sizes[:n].sum())
@@ -433,7 +457,7 @@ class TraceRing:
         n = min(cur, self.depth)
         if not n:
             return cur, recs[:0]
-        idx = [(cur - n + i) & (self.depth - 1) for i in range(n)]
+        idx = (cur - n + np.arange(n, dtype=np.int64)) & (self.depth - 1)
         return cur, recs[idx]
 
     def snapshot_since(self, since: int) -> tuple[int, np.ndarray, int]:

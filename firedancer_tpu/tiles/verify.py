@@ -98,6 +98,16 @@ lost TPU rather than killing the topology.
 Publishing is credit-gated: when downstream reliable consumers' fseqs are
 attached, the tile spins for credits instead of silently lapping them
 (ref: src/tango/fctl/fd_fctl.h:4-10).
+
+Stage accounting: each batch's path is timed stage by stage
+(trace/events.py STAGE_IDS: gather, parse, dedup, assemble, dispatch,
+readback, finalize, deferred, publish) with one clock read per stage
+per batch — cumulative ns per stage (`stages.ns`, the ns_<stage> metric
+slots) and, when traced, one EV_STAGE span each. A txn that reaches a
+device lane is also timed through the tile: txn_hold_ns (gather ->
+dispatch), txn_device_ns (dispatch -> the tile sees its verdict ready)
+and txn_egress_ns (verdict seen -> published), summed over txn_timed
+txns from the few gather timestamps of each batch, never per txn.
 """
 from __future__ import annotations
 
@@ -111,7 +121,14 @@ from ..disco.metrics import HistAccum
 from ..protocol.txn import MTU
 from ..runtime import Ring, Tcache
 from ..runtime.tango import lib as _lib
+from ..trace.events import STAGE_IDS
+from ..trace.recorder import StageClock
 from ..utils.tempo import monotonic_ns
+
+_GATHER, _PARSE, _DEDUP, _ASSEMBLE, _DISPATCH, _READBACK, _FINALIZE, \
+    _DEFERRED, _PUBLISH = (STAGE_IDS[s] for s in (
+        "gather", "parse", "dedup", "assemble", "dispatch", "readback",
+        "finalize", "deferred", "publish"))
 
 _u8p = ct.POINTER(ct.c_uint8)
 _i32p = ct.POINTER(ct.c_int32)
@@ -206,6 +223,12 @@ class VerifyTile:
             # runs / passes / lanes checked / lanes shed / kernel ns
             "rlc_batches": 0, "rlc_pass": 0, "rlc_lanes": 0,
             "rlc_shed": 0, "rlc_ns": 0,
+            # stage accounting: strict lanes dispatched, parked
+            # duplicates and their host reference re-verifies, and the
+            # residence of the txns that reached a device lane
+            "lanes": 0, "deferred_parked": 0, "deferred_host_verifies": 0,
+            "txn_hold_ns": 0, "txn_device_ns": 0, "txn_egress_ns": 0,
+            "txn_timed": 0,
         }
         if mode not in ("strict", "bulk_prefilter"):
             raise ValueError(f"unknown verify mode {mode!r} "
@@ -254,15 +277,19 @@ class VerifyTile:
         self._hold_sizes = np.zeros(batch, np.uint32)
         self._hold_n = 0
         self._hold_deadline = 0
+        # (first hold row, gather time) of each gather in the hold (reset
+        # when a hold starts): the txns' gather -> dispatch residence
+        self._hold_segs: list[tuple[int, int]] = []
         self._chaos = None
         if chaos:
             from ..utils.chaos import ChaosPlan
             self._chaos = ChaosPlan(chaos)
         # fdtrace flight recorder (None = untraced, zero hot-path cost:
-        # every hook below is one attribute check). Device dispatch /
-        # readback / fallback transitions are the TPU-observability
-        # events the host-side trace exists for.
+        # every hook below is one attribute check). The stage spans and
+        # the fallback transition are the events the host-side trace
+        # exists for.
         self._trace = trace
+        self.stages = StageClock(trace)
         self._trace_link = trace_link
         self._trace_link_in = trace_link_in
         # TPU-time attribution (fdmetrics v2): dispatch + readback
@@ -550,9 +577,6 @@ class VerifyTile:
                 t0 = monotonic_ns()
                 fut = self._device_verify(bs)
                 self.tpu_hist.add(monotonic_ns() - t0)
-                if self._trace is not None:
-                    from ..trace.events import EV_TPU_DISPATCH
-                    self._trace.span(EV_TPU_DISPATCH, t0, count=lanes)
                 return fut
             except Exception:
                 self.metrics["device_errors"] += 1
@@ -622,6 +646,7 @@ class VerifyTile:
         Returns number of frags CONSUMED (0 only when the ring was idle)."""
         self._drain(block=False)
         want = self.batch - self._hold_n
+        t_g = monotonic_ns()
         n, self.seq, buf, sizes, sigs, ovr, seqs = self.in_ring.gather(
             self.seq, want, self.max_len, want_seqs=True)
         self.metrics["overruns"] += ovr
@@ -664,7 +689,8 @@ class VerifyTile:
             self._trace.frag_batch(EV_CONSUME, sigs,
                                    link=self._trace_link_in)
         if not self._coalesce_ns:
-            self._process_batch(buf, sizes, n)
+            self.stages.add(_GATHER, t_g)
+            self._process_batch(buf, sizes, n, [(0, t_g)])
             return consumed
         # adaptive coalescing: accumulate sub-full gathers into the
         # hold window; dispatch when one compiled batch's lane budget
@@ -674,15 +700,18 @@ class VerifyTile:
         # paying the stage-into-hold + recycle-copy that exists only to
         # keep sub-full remainders alive across polls
         if not self._hold_n and n >= self.batch:
-            self._process_batch(buf, sizes, n)
+            self.stages.add(_GATHER, t_g)
+            self._process_batch(buf, sizes, n, [(0, t_g)])
             return consumed
         if not self._hold_n:
             self._hold_deadline = monotonic_ns() + self._coalesce_ns
+            self._hold_segs = []
         self._hold_buf[self._hold_n:self._hold_n + n] = buf
         self._hold_sizes[self._hold_n:self._hold_n + n] = sizes
+        self._hold_segs.append((self._hold_n, t_g))
         self._hold_n += n
-        if self._hold_n >= self.batch or \
-                monotonic_ns() >= self._hold_deadline:
+        t = self.stages.add(_GATHER, t_g)
+        if self._hold_n >= self.batch or t >= self._hold_deadline:
             self._flush_hold()
         return consumed
 
@@ -693,7 +722,8 @@ class VerifyTile:
         buffer, paid once per BATCH instead of once per gather)."""
         n, self._hold_n = self._hold_n, 0
         self._process_batch(self._hold_buf[:n].copy(),
-                            self._hold_sizes[:n].copy(), n)
+                            self._hold_sizes[:n].copy(), n,
+                            self._hold_segs)
 
     def set_coalesce_ns(self, ns: int):
         """Runtime coalesce-window steer (the fdtune coalesce_us
@@ -710,15 +740,19 @@ class VerifyTile:
                                       np.uint8)
         self._coalesce_ns = ns
 
-    def _process_batch(self, buf, sizes, n: int):
+    def _process_batch(self, buf, sizes, n: int, segs):
         """Parse -> tag -> ha-dedup + batched in-flight reservation ->
         fixed-shape device chunks, dispatched async (the verify
-        pipeline behind the gather/coalesce stage)."""
+        pipeline behind the gather/coalesce stage). `segs` holds the
+        (first row, gather time) of each gather the rows came from."""
+        st = self.stages
+        t = monotonic_ns()
         buf = np.ascontiguousarray(buf[:n])
         sizes = np.ascontiguousarray(sizes[:n], np.uint32)
         meta, tags = parse_batch(buf, sizes, self.dedup_seed)
         ok = meta[:, 0] != 0
         self.metrics["parse_fail"] += int(n - ok.sum())
+        t = st.add(_PARSE, t)
 
         # ha-dedup query BEFORE spending device lanes; tcache insert
         # stays AFTER verify (ref order: fd_verify_tile.h:84-101), and
@@ -754,10 +788,12 @@ class VerifyTile:
                         self._deferred.setdefault(int(tags[i]), []) \
                             .append(bytes(buf[i, :sizes[i]]))
                         self._deferred_n += 1
+                        self.metrics["deferred_parked"] += 1
                     else:
                         self.metrics["dedup_drop"] += 1  # pool overflow
         skip = np.ascontiguousarray(~ok | dup_pre).astype(np.uint8)
         cand = ok & ~dup_pre
+        t = st.add(_DEDUP, t)
         if not cand.any():
             return
 
@@ -779,6 +815,7 @@ class VerifyTile:
                 except Exception:
                     pass              # degraded inside _read_verdicts
                 self._bufset_fut[k] = None
+                t = st.add(_READBACK, t)
             bs = self._bufsets[k]
             lanes = _lib.fdtpu_verify_assemble(
                 buf.ctypes.data_as(_u8p),
@@ -791,6 +828,7 @@ class VerifyTile:
                 bs.msg.ctypes.data_as(_u8p),
                 bs.ln.ctypes.data_as(_i32p),
                 bs.txn.ctypes.data_as(_i32p))
+            t = st.add(_ASSEMBLE, t)
             if not lanes:
                 break
             if self.mode == "bulk_prefilter" and not self.degraded \
@@ -806,16 +844,30 @@ class VerifyTile:
                 self.metrics["rlc_shed"] += lanes
                 chunks.append((np.zeros(self.batch, bool),
                                bs.txn[:lanes].copy()))
+                t = st.add(_DISPATCH, t)
                 continue
             fut = self._dispatch(bs, lanes)
             if not isinstance(fut, np.ndarray):
                 self._bufset_fut[k] = fut
             self._disp += 1
             self.metrics["batches"] += 1
+            self.metrics["lanes"] += lanes
             chunks.append((fut, bs.txn[:lanes].copy()))
+            t = st.add(_DISPATCH, t)
+        # residence of the txns that reached a device lane: each waited
+        # from its gather's time to this dispatch
+        on_dev = np.zeros(n, bool)
+        for fut, live in chunks:
+            if not isinstance(fut, np.ndarray):
+                on_dev[live] = True
+        per_seg = np.add.reduceat(on_dev, [r for r, _ in segs],
+                                  dtype=np.int64)
         self._pending.append(
             {"chunks": chunks, "buf": buf, "sizes": sizes,
-             "tags": tags, "cand": cand, "n": n, "reserved": reserved})
+             "tags": tags, "cand": cand, "n": n, "reserved": reserved,
+             "t_disp": t, "timed": int(per_seg.sum()),
+             "hold_ns": sum(int(c) * (t - tg)
+                            for c, (_, tg) in zip(per_seg, segs))})
         while len(self._pending) > self.inflight:
             self._drain(block=True, max_sets=1)
 
@@ -874,20 +926,14 @@ class VerifyTile:
     def _finalize(self, rec):
         """Readback verdicts and batch-publish one record (tags were
         already reserved at dispatch)."""
+        st = self.stages
         n, cand = rec["n"], rec["cand"]
         txn_ok = cand.copy()
         covered = np.zeros(n, bool)
         rb_t0 = monotonic_ns()
-
-        def _rb_span():
-            # TPU-attributed time ONLY: closes at the end of the
-            # device-verdict wait — never around the CPU re-verify
-            # fallback, which would blame the device for host work
-            self.tpu_hist.add(monotonic_ns() - rb_t0)
-            if self._trace is not None:
-                from ..trace.events import EV_TPU_READBACK
-                self._trace.span(EV_TPU_READBACK, rb_t0,
-                                 count=len(rec["chunks"]))
+        # the readback stage (and the tile's TPU-attributed time) closes
+        # at the end of the device-verdict wait — never around the CPU
+        # re-verify fallback, which would blame the device for host work
         try:
             had_device = False
             for fut, live in rec["chunks"]:
@@ -898,16 +944,18 @@ class VerifyTile:
                 failed = live[~lane_ok[:len(live)]]
                 txn_ok[failed] = False
             txn_ok &= covered
+            t_ready = st.add(_READBACK, rb_t0)
             if had_device:
                 self._consec_fail = 0    # a healthy device round-trip
-                _rb_span()
+                self.tpu_hist.add(t_ready - rb_t0)
         except Exception:
             # lost verdicts (device died mid-flight / readback timeout):
             # recompute the whole record on the CPU reference path — the
             # batch still serves rather than dropping or crashing. The
             # readback span closes HERE (the device wait up to the
             # failure), before the CPU re-verify starts.
-            _rb_span()
+            t_ready = st.add(_READBACK, rb_t0)
+            self.tpu_hist.add(t_ready - rb_t0)
             self.metrics["device_errors"] += 1
             self._consec_fail += 1
             if self._consec_fail >= self.device_fail_limit:
@@ -926,7 +974,9 @@ class VerifyTile:
         late = txn_ok & (dup_post != 0)
         self.metrics["dedup_drop"] += int(late.sum())
         txn_ok &= dup_post == 0
+        t = st.add(_FINALIZE, t_ready)
         self._resolve_deferred(rec["reserved"])
+        t = st.add(_DEFERRED, t)
 
         mask = txn_ok.astype(np.uint8)
         start, fwd = 0, 0
@@ -940,7 +990,14 @@ class VerifyTile:
             # out of downstream credits mid-batch
             if not self._wait_credits():
                 break               # halted while backpressured
+        t_pub = st.add(_PUBLISH, t)
         self.metrics["tx"] += fwd
+        m = rec["timed"]
+        if m:
+            self.metrics["txn_timed"] += m
+            self.metrics["txn_hold_ns"] += rec["hold_ns"]
+            self.metrics["txn_device_ns"] += m * (t_ready - rec["t_disp"])
+            self.metrics["txn_egress_ns"] += m * (t_pub - t_ready)
         if self._trace is not None and fwd:
             # frag-lineage anchors: (sampled) publish records keyed by
             # dedup tag — the sig the downstream consume hooks carry,
@@ -970,6 +1027,7 @@ class VerifyTile:
                 if self.tcache.query(t):
                     self.metrics["dedup_drop"] += 1
                     continue
+                self.metrics["deferred_host_verifies"] += 1
                 if not self._host_verify_payload(p):
                     self.metrics["verify_fail"] += 1
                     continue

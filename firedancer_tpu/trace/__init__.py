@@ -28,6 +28,6 @@ from .export import (  # noqa: F401
     to_chrome,
 )
 from .recorder import (  # noqa: F401
-    TILE_TRACE_KEYS, TRACE_DEFAULTS, TraceWriter, chaos_event,
+    TILE_TRACE_KEYS, TRACE_DEFAULTS, StageClock, TraceWriter, chaos_event,
     effective_trace, link_ids, link_names, normalize_trace, writer_for,
 )

@@ -81,7 +81,9 @@ def main(argv=None) -> int:
                   "topology config?)", file=sys.stderr)
             return 1
         if args.format in ("summary", "both"):
-            sys.stdout.write(export.summary(evs))
+            from ..disco.metrics import read_link_metrics
+            sys.stdout.write(export.summary(
+                evs, links=read_link_metrics(wksp, plan)))
         chrome = export.to_chrome(evs, plan.get("topology", "?"))
         if args.out:
             with open(args.out, "w") as f:

@@ -29,8 +29,7 @@ EV_HOUSEKEEP = 6     # span: one housekeeping pass
 EV_CONSUME = 7       # instant, frag-scoped: frag consumed (sig, link)
 EV_PUBLISH = 8       # instant, frag-scoped: frag published (sig, link)
 EV_BACKPRESSURE = 9  # span: blocked on downstream credits (link)
-EV_TPU_DISPATCH = 10  # span: device dispatch call (count = lanes)
-EV_TPU_READBACK = 11  # span: verdict readback wait (count = chunks)
+# 10, 11: retired (device dispatch / readback are EV_STAGE spans now)
 EV_CPU_FALLBACK = 12  # instant: verify degraded to the CPU path
 EV_CHAOS = 13        # instant: chaos fault fired (count = action id)
 EV_WATCHDOG = 14     # instant: supervisor wedge-watchdog trip (sup-written)
@@ -49,22 +48,29 @@ EV_TUNE = 20         # instant: controller knob decision (fdtune;
                      #   arg = new knob value, count = knob index into
                      #   the plan's tune_knobs list, link = the
                      #   saturating hop that justified the move)
+EV_STAGE = 21        # span: one stage of a tile's batch (count = stage
+                     #   id, STAGE_IDS below) — the verify tile's host
+                     #   and device stages, a device capture's own
+                     #   start/stop, a collector pause
+EV_STALL = 22        # instant: a poll or housekeeping pass of STALL_NS
+                     #   or longer (disco/stem.py; arg = its ns,
+                     #   count = the stage id it spent most time in)
 
 NAMES = {
     EV_BOOT: "boot", EV_HALT: "halt", EV_FAIL: "fail",
     EV_WAIT: "wait", EV_WORK: "work", EV_HOUSEKEEP: "housekeep",
     EV_CONSUME: "consume", EV_PUBLISH: "publish",
     EV_BACKPRESSURE: "backpressure",
-    EV_TPU_DISPATCH: "tpu_dispatch", EV_TPU_READBACK: "tpu_readback",
     EV_CPU_FALLBACK: "cpu_fallback", EV_CHAOS: "chaos",
     EV_WATCHDOG: "watchdog", EV_RESTART: "restart", EV_DOWN: "down",
     EV_SLO: "slo", EV_COMPILE: "compile",
     EV_PROF_CAPTURE: "prof_capture", EV_TUNE: "tune",
+    EV_STAGE: "stage", EV_STALL: "stall",
 }
 
 # span events: record.ts is the END, record.arg the duration in ns
 SPANS = {EV_WAIT, EV_WORK, EV_HOUSEKEEP, EV_BACKPRESSURE,
-         EV_TPU_DISPATCH, EV_TPU_READBACK, EV_PROF_CAPTURE}
+         EV_PROF_CAPTURE, EV_STAGE}
 
 # frag-scoped events (sig is a lineage key, not 0-means-nothing)
 FRAG_EVENTS = {EV_CONSUME, EV_PUBLISH}
@@ -85,6 +91,32 @@ CHAOS_ACTION_IDS = {
     "stale_snapshot_offer": 13, "diverge_block": 14,
 }
 CHAOS_ACTION_NAMES = {v: k for k, v in CHAOS_ACTION_IDS.items()}
+
+# stage ids (record.count of EV_STAGE spans and EV_STALL instants). The
+# verify tile's batch runs through the first nine; each also keeps a
+# cumulative host-ns counter in its metric slots (ns_<stage>):
+#   gather    ring gather + the coalesce hold copy
+#   parse     txn parse + dedup tags
+#   dedup     tcache query, in-flight reservation, parking
+#   assemble  device lane assembly
+#   dispatch  device dispatch (also the RLC prefilter's equation, and
+#             a degraded tile's host verify)
+#   readback  every wait for a verdict: a free staging set, the drain
+#   finalize  verdict merge, tcache insert (and a lost record's host
+#             re-verify)
+#   deferred  resolving parked duplicates (host re-verifies included)
+#   publish   publish and the credit waits it takes
+# The rest name what a stall was in outside those stages: a device
+# capture's own start/stop, a collector pause, a tile's poll or a
+# housekeeping pass that no stage accounts for.
+STAGE_IDS = {
+    "gather": 1, "parse": 2, "dedup": 3, "assemble": 4, "dispatch": 5,
+    "readback": 6, "finalize": 7, "deferred": 8, "publish": 9,
+    "capture": 10, "gc": 11, "poll": 12, "housekeep": 13,
+}
+STAGE_NAMES = {v: k for k, v in STAGE_IDS.items()}
+VERIFY_STAGES = ("gather", "parse", "dedup", "assemble", "dispatch",
+                 "readback", "finalize", "deferred", "publish")
 
 
 def decode(rec, link_names: list[str] | None = None) -> dict:

@@ -10,7 +10,9 @@ sees exactly the final events).
 Chrome-trace mapping (the Perfetto-ingestible JSON array format):
 
     tile            -> one thread (tid = tile index, named via M events)
-    span events     -> "X" complete events (ts = end - dur)
+    span events     -> "X" complete events (ts = end - dur); a stage
+                       span is named by its stage (trace/events.py
+                       STAGE_IDS), a stall instant `stall:<stage>`
     instant events  -> "i" instants
     frag lineage    -> "s"/"f" flow arrows keyed by the frag sig: the
                        publish on the producing tile starts the flow,
@@ -84,15 +86,20 @@ def to_chrome(events_by_tile: dict[str, list[dict]],
             if e["etype"] == ev.EV_CHAOS:
                 args["action"] = ev.CHAOS_ACTION_NAMES.get(
                     e["count"], "?")
+            name = e["ev"]
+            if e["etype"] == ev.EV_STAGE:
+                name = ev.STAGE_NAMES.get(e["count"], "?")
+            elif e["etype"] == ev.EV_STALL:
+                name = "stall:" + ev.STAGE_NAMES.get(e["count"], "?")
             if e["etype"] in ev.SPANS:
                 trace_events.append(
                     {"ph": "X", "pid": pid, "tid": tid, "cat": "fdtpu",
-                     "name": e["ev"], "ts": (e["ts"] - e["arg"]) / 1e3,
+                     "name": name, "ts": (e["ts"] - e["arg"]) / 1e3,
                      "dur": e["arg"] / 1e3, "args": args})
             else:
                 trace_events.append(
                     {"ph": "i", "pid": pid, "tid": tid, "cat": "fdtpu",
-                     "name": e["ev"], "ts": ts_us, "s": "t",
+                     "name": name, "ts": ts_us, "s": "t",
                      "args": args})
             if e["etype"] in ev.FRAG_EVENTS:
                 fp = first_pub.get(e["sig"])
@@ -129,36 +136,36 @@ def lineage(events_by_tile: dict[str, list[dict]]) -> dict[int, list]:
     return chains
 
 
-def summary(events_by_tile: dict[str, list[dict]]) -> str:
-    """Text report: per-link publish->consume latency (frag lineage
-    deltas) + per-tile wait/backpressure attribution — the 'where did
-    the microseconds go' answer counters cannot give."""
+def summary(events_by_tile: dict[str, list[dict]],
+            links: dict | None = None) -> str:
+    """Text report: per-link queue wait (publish -> consume, from the
+    link telemetry blocks' histograms — `links` is read_link_metrics
+    output), per-tile wait/backpressure/work/device attribution and
+    per-stage time from the rings — the 'where did the microseconds
+    go' answer counters cannot give."""
+    from ..disco.metrics import quantile_ns
     lines = ["fdtrace summary", "==============="]
-    # per-link latency: each consume is measured against the MOST
-    # RECENT publish in the sig's chain (per-hop delta, not cumulative
-    # from the chain's origin — a slow hop must blame itself)
-    per_link: dict[str, list[int]] = {}
-    for chain in lineage(events_by_tile).values():
-        pub_ts = None
-        for ts, _tn, name, link in chain:
-            if name == "publish":
-                pub_ts = ts
-            elif name == "consume" and pub_ts is not None and link:
-                per_link.setdefault(link, []).append(ts - pub_ts)
-    if per_link:
+    rows = [(ln, tn, c["hist"])
+            for ln, rec in sorted((links or {}).items())
+            for tn, c in sorted(rec["consumers"].items())
+            if c["hist"]["count"]]
+    if rows:
         lines.append("")
-        lines.append(f"{'link':<20}{'frags':>8}{'p50_us':>10}"
-                     f"{'p99_us':>10}{'max_us':>10}")
-        for link, dts in sorted(per_link.items()):
-            dts.sort()
-            p = lambda q: dts[min(len(dts) - 1,
-                                  int(q * len(dts)))] / 1e3
-            lines.append(f"{link:<20}{len(dts):>8}{p(0.50):>10.1f}"
-                         f"{p(0.99):>10.1f}{dts[-1] / 1e3:>10.1f}")
+        lines.append("queue wait (publish -> consume)")
+        lines.append(f"{'link':<20}{'consumer':<12}{'frags':>10}"
+                     f"{'mean_us':>10}{'p50_us':>10}{'p99_us':>10}")
+        for ln, tn, h in rows:
+            lines.append(
+                f"{ln:<20}{tn:<12}{h['count']:>10}"
+                f"{h['sum_ns'] / h['count'] / 1e3:>10.1f}"
+                f"{quantile_ns(h, 0.50) / 1e3:>10.1f}"
+                f"{quantile_ns(h, 0.99) / 1e3:>10.1f}")
     # per-tile attribution
     lines.append("")
     lines.append(f"{'tile':<14}{'events':>8}{'wait_ms':>10}"
                  f"{'bp_ms':>8}{'work_ms':>9}{'tpu_ms':>8}  notes")
+    device = {ev.STAGE_IDS["dispatch"], ev.STAGE_IDS["readback"]}
+    stages: dict[str, dict[str, int]] = {}
     for tn, evs in sorted(events_by_tile.items()):
         acc = {k: 0 for k in ("wait", "backpressure", "work", "tpu")}
         notes = []
@@ -169,8 +176,15 @@ def summary(events_by_tile: dict[str, list[dict]]) -> str:
                 acc["backpressure"] += e["arg"]
             elif e["etype"] == ev.EV_WORK:
                 acc["work"] += e["arg"]
-            elif e["etype"] in (ev.EV_TPU_DISPATCH, ev.EV_TPU_READBACK):
-                acc["tpu"] += e["arg"]
+            elif e["etype"] == ev.EV_STAGE:
+                name = ev.STAGE_NAMES.get(e["count"], "?")
+                per = stages.setdefault(tn, {})
+                per[name] = per.get(name, 0) + e["arg"]
+                if e["count"] in device:
+                    acc["tpu"] += e["arg"]
+            elif e["etype"] == ev.EV_STALL:
+                notes.append("stall:" + ev.STAGE_NAMES.get(
+                    e["count"], "?"))
             elif e["etype"] == ev.EV_CPU_FALLBACK:
                 notes.append("CPU-FALLBACK")
             elif e["etype"] == ev.EV_CHAOS:
@@ -186,6 +200,12 @@ def summary(events_by_tile: dict[str, list[dict]]) -> str:
             f"{acc['backpressure'] / 1e6:>8.2f}"
             f"{acc['work'] / 1e6:>9.2f}{acc['tpu'] / 1e6:>8.2f}  "
             + " ".join(notes[:6]))
+    for tn, per in sorted(stages.items()):
+        lines.append("")
+        lines.append(f"{tn} stages (ms): " + "  ".join(
+            f"{k}={v / 1e6:.2f}" for k, v in
+            sorted(per.items(),
+                   key=lambda kv: ev.STAGE_IDS.get(kv[0], 99))))
     return "\n".join(lines) + "\n"
 
 

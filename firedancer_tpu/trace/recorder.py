@@ -171,6 +171,35 @@ class TraceWriter:
                          link=link, count=count)
 
 
+class StageClock:
+    """A tile's stage accounting (trace/events.py STAGE_IDS): `add(stage,
+    t0)` closes a stage opened at t0 with one clock read, adds its ns to
+    the stage's cumulative counter (`ns[stage]`, the tile's ns_<stage>
+    metric slots), keeps the longest stage since the stem last zeroed
+    `top_ns` (the stage a stall names), and, when traced, records one
+    EV_STAGE span. It returns the close time, so the next stage opens
+    there without another read. Callers take one pair per stage per
+    batch, never per frag."""
+
+    __slots__ = ("ns", "trace", "top", "top_ns")
+
+    def __init__(self, trace: TraceWriter | None = None):
+        self.ns = [0] * (max(ev.STAGE_IDS.values()) + 1)
+        self.trace = trace
+        self.top = 0
+        self.top_ns = 0
+
+    def add(self, stage: int, t0: int) -> int:
+        now = monotonic_ns()
+        d = now - t0
+        self.ns[stage] += d
+        if d > self.top_ns:
+            self.top_ns, self.top = d, stage
+        if self.trace is not None:
+            self.trace.ring.append(now, ev.EV_STAGE, arg=d, count=stage)
+        return now
+
+
 def writer_for(ctx_or_plan, wksp, tile_name: str) -> TraceWriter | None:
     """TraceWriter over an EXISTING tile ring (reader/supervisor side:
     plan + joined workspace), or None if the tile is untraced."""

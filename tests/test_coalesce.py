@@ -302,3 +302,100 @@ def test_publish_batch_backpressure_resume(wksp):
         seq += 1
     want = [bytes(buf[i, :8]) for i in range(n) if mask[i]]
     assert got == want
+
+
+# -- stage accounting (tiles/verify.py StageClock, residence counters) ------
+
+class _StemCtx:
+    """The least a Stem needs to run a bare VerifyTile in-process."""
+
+    def __init__(self, wksp):
+        from firedancer_tpu.runtime import Cnc
+        self.cnc = Cnc(wksp)
+        self.spec = {"args": {}}
+        self.trace = self.prof = None
+
+    def hist_view(self):
+        return None
+
+
+def _forged(txn: bytes) -> bytes:
+    b = bytearray(txn)
+    b[1 + 10] ^= 0x01                     # a bit of the first signature
+    return bytes(b)
+
+
+def test_stage_ns_within_stem_time_and_lanes_counted(wksp, txns,
+                                                     _shared_tile):
+    """Under a real Stem, the verify stages' summed host ns stay within
+    the stem's poll time (work + wait): each stage is timed inside a
+    poll, never twice. `lanes` counts the lanes dispatched (one per
+    single-signer txn), and every txn that reached a lane is timed
+    through hold, device and egress."""
+    from firedancer_tpu.disco.stem import Stem
+    from firedancer_tpu.trace.events import VERIFY_STAGES
+    tile, in_ring, out_ring = _mk_tile(wksp, coalesce_us=200.0,
+                                       _tile=_shared_tile)
+    futs, _, _ = _script_backend(tile)
+    real_fn = tile._fn
+
+    def ready_fn(flat):                   # verdicts land after 2 polls
+        fut = real_fn(flat)
+        fut.ready_after = 2
+        return fut
+    tile._fn = ready_fn
+    ns0 = list(tile.stages.ns)
+    for t in txns[:20]:
+        in_ring.publish(t, sig=1)
+    stem = Stem(_StemCtx(wksp), tile, idle_sleep_s=0)
+    stem.run(max_iters=100)               # full batch, then the held 4
+    assert not tile._pending and tile._hold_n == 0 and len(futs) == 2
+    assert tile.metrics["tx"] == 20
+    from firedancer_tpu.trace.events import STAGE_IDS
+    stage_ns = sum(tile.stages.ns[STAGE_IDS[s]] - ns0[STAGE_IDS[s]]
+                   for s in VERIFY_STAGES)
+    poll_ns = stem._hists["work"].sum_ns + stem._hists["wait"].sum_ns
+    assert 0 < stage_ns <= poll_ns
+    assert tile.metrics["lanes"] == 20 and tile.metrics["batches"] >= 2
+    m = tile.metrics
+    assert m["txn_timed"] == 20
+    assert m["txn_hold_ns"] > 0 and m["txn_device_ns"] > 0 \
+        and m["txn_egress_ns"] > 0
+    # the three residences of a txn fit inside the whole run
+    assert m["txn_hold_ns"] + m["txn_device_ns"] + m["txn_egress_ns"] \
+        <= 20 * (stage_ns + poll_ns)
+    assert stem._hists["stall"].count == 0
+
+
+@pytest.mark.parametrize("forged,raced,parked,host_verifies", [
+    (True, True, 1, 1),      # the reserver failed: its twin re-verified
+    (False, True, 1, 0),     # the reserver passed: the twin is dropped
+    (True, False, 0, 0),     # no race: the twin goes to the device
+])
+def test_deferred_host_verifies_only_for_raced_forged_twins(
+        wksp, txns, _shared_tile, forged, raced, parked, host_verifies):
+    """A duplicate parks only while its twin is in device flight; the
+    host reference re-verifies it only if that twin FAILED — a forged
+    txn whose duplicate raced it. Scripted verdicts: the device says
+    what the txn is."""
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    futs = []
+
+    def fake_fn(flat):
+        futs.append(ScriptedFut(np.full(tile.batch, not forged)))
+        return futs[-1]
+    tile._fn = fake_fn
+    txn = _forged(txns[0]) if forged else txns[0]
+    in_ring.publish(txn, sig=1)
+    tile.poll_once()                      # the original, in flight
+    if not raced:
+        futs[0].ready = True
+        tile.poll_once()                  # ...and finalized
+    in_ring.publish(txn, sig=2)
+    tile.poll_once()                      # its twin
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert tile.metrics["deferred_parked"] == parked
+    assert tile.metrics["deferred_host_verifies"] == host_verifies
+    assert tile.metrics["tx"] == (0 if forged else 1)

@@ -356,7 +356,7 @@ def test_old_plan_hist_region_not_overread():
                   create=False)
     try:
         # current plans record the region length
-        assert plan["tiles"]["b"]["hist_u64"] == 3 * HIST_U64
+        assert plan["tiles"]["b"]["hist_u64"] == 5 * HIST_U64
         # simulate attaching to an old topology: 2-kind region, no key
         old = json.loads(json.dumps(plan))
         del old["tiles"]["b"]["hist_u64"]

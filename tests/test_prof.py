@@ -456,7 +456,7 @@ def test_merged_bundle_single_clock_and_device_events(prof_pipeline):
     # verify's device + compile events ride the same timeline
     vtid = trace_tids["verify"]
     vnames = {e["name"] for e in te if e.get("tid") == vtid}
-    assert "tpu_dispatch" in vnames and "compile" in vnames
+    assert "dispatch" in vnames and "compile" in vnames
     # single clock domain: host slices interleave the fdtrace span
     # range (both are utils/tempo.monotonic_ns)
     trace_ts = [e["ts"] for e in te
@@ -566,3 +566,79 @@ def test_post_mortem_export_after_tile_death():
     finally:
         runner.halt(join_timeout_s=5)
         runner.close()
+
+
+# -- one clock with the device trace ----------------------------------------
+
+class _Doorbell:
+    """A ProfRegion's capture doorbell, in process."""
+
+    def __init__(self):
+        self.capture_req = self.capture_ack = 0
+
+    def ack_capture(self, req):
+        self.capture_ack = req
+
+
+def test_trace_dir_honours_tmpdir(tmp_path, monkeypatch):
+    import tempfile
+
+    from firedancer_tpu.prof import device
+    monkeypatch.setattr(tempfile, "tempdir", None)
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    assert device.trace_dir("topo", "verify") == str(
+        tmp_path / "fdtpu_prof_topo_verify")
+
+
+def test_clock_anchor_maps_ring_span_onto_trace(wksp, tmp_path,
+                                                monkeypatch):
+    """A CPU jax.profiler capture: the fdtpu_clock marks DeviceCapture
+    makes at its start and stop map a ring span (CLOCK_MONOTONIC) onto
+    the trace's own times within 1 ms — checked against a profiler
+    annotation made over the same interval — and the tile's ring
+    records inside the window land beside the trace as fdtrace.json."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from firedancer_tpu.prof import device
+    from firedancer_tpu.runtime import TraceRing
+    from firedancer_tpu.trace import TraceWriter
+    from firedancer_tpu.trace import events as tev
+    from firedancer_tpu.utils.tempo import monotonic_ns
+    monkeypatch.setattr(device, "trace_dir",
+                        lambda topo, tile: str(tmp_path / "cap"))
+    tr = TraceWriter(TraceRing.create(wksp, 256))
+    bell = _Doorbell()
+    cap = device.DeviceCapture({"topology": "clk",
+                                "prof": {"capture_ms": 50.0}},
+                               "verify", bell, trace=tr)
+    bell.capture_req = 1
+    cap.poll()
+    assert cap.active
+    t0 = monotonic_ns()
+    with jax.profiler.TraceAnnotation("probe"):
+        time.sleep(0.005)
+    tr.span(tev.EV_STAGE, t0, count=tev.STAGE_IDS["deferred"])
+    time.sleep(0.06)
+    path = cap.poll()
+    assert not cap.active and bell.capture_ack == 1
+    man = json.load(open(path))
+    os.unlink(path)
+    assert man["ok"] and man["fdtrace"] == str(tmp_path / "cap" /
+                                               device.FDTRACE_FILE)
+    pd = ProfileData.from_file(sorted(glob.glob(
+        str(tmp_path / "cap" / "**" / "*.xplane.pb"), recursive=True))[-1])
+    evs = [e for p in pd.planes for ln in p.lines for e in ln.events]
+    marks = [(int(e.start_ns), int(dict(e.stats)["mono_ns"]))
+             for e in evs if e.name == device.CLOCK_ANNOTATION]
+    assert len(marks) == 2                 # after start, before stop
+    off = device.clock_offset_ns(marks)
+    probe = next(e for e in evs if e.name == "probe")
+    recs = json.load(open(man["fdtrace"]))["records"]
+    end, dur, name = next(r for r in recs if r[2] == "stage:deferred")
+    assert abs((end - dur - off) - probe.start_ns) < 1e6
+    assert abs((end - off) - (probe.start_ns + probe.duration_ns)) < 1e6
+    # only the window's records, frag-scoped ones left out
+    assert all(man["t0_ns"] <= r[0] for r in recs)

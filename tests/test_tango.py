@@ -280,3 +280,61 @@ def test_ring_publish_batch_masked_and_credit_gated(wksp):
         got.append(int(frag.sig))
         seq += 1
     assert got == published[3:]
+
+
+# -- queue wait per hop (native gather, Ring.m_wait) -------------------------
+
+def _hist_of(waits):
+    from firedancer_tpu.disco.metrics import HistAccum
+    h = HistAccum()
+    for w in waits:
+        h.add(w)
+    return [h.count, h.sum_ns] + h.buckets
+
+
+def test_hist_waits_exact_with_32bit_wrap():
+    """fdtpu_hist_waits adds (uint32)(now - tspub) per stamp in the
+    disco/metrics.py layout: a stamp past `now` numerically is one that
+    the low 32 bits of the clock wrapped under, and reads as its true
+    wait, not a negative or a 4.29 s one."""
+    import ctypes as ct
+
+    from firedancer_tpu.runtime.tango import WAIT_HIST_U64, lib
+    now = 5
+    stamps = np.array([2**32 - 10, 5, 0, 3, 2**32 - 1, 5 - 3_000_000 + 2**32],
+                      np.uint32)
+    waits = [15, 0, 5, 2, 6, 3_000_000]
+    hist = np.zeros(WAIT_HIST_U64, np.uint64)
+    lib.fdtpu_hist_waits(hist.ctypes.data_as(ct.POINTER(ct.c_uint64)), now,
+                         stamps.ctypes.data_as(ct.POINTER(ct.c_uint32)),
+                         len(stamps))
+    assert hist.tolist() == _hist_of(waits)
+
+
+def test_gather_records_each_frags_queue_wait(wksp):
+    """The native gather times every frag it gathers from its slot's
+    publish stamp, with one clock read per block of frags: stamps set
+    known distances back from the clock read before the gather land in
+    their exact buckets, and the summed wait is the distances plus at
+    most the gather's own span per frag. Ring.consume times its frag
+    the same way."""
+    from firedancer_tpu.runtime.tango import lib
+    ring = Ring.create(wksp, depth=512, mtu=MTU)
+    back = [3_000_000, 100_000_000, 1_200_000] * 100    # 300 frags
+    for i in range(len(back) + 1):
+        ring.publish(bytes([i % 251]) * 8, sig=i)
+    # rewrite the first 300 slots' 32-bit publish stamps
+    tspub = wksp.view(ring.off + 64, 512 * 32).view(np.uint32) \
+        .reshape(512, 8)[:, 7]
+    t_before = lib.fdtpu_ticks()
+    for i, d in enumerate(back):
+        tspub[i] = (t_before - d) & 0xFFFFFFFF
+    n, seq, *_ = ring.gather(0, len(back), MTU)
+    slack = lib.fdtpu_ticks() - t_before
+    assert n == len(back) and seq == len(back)
+    want = _hist_of(back)
+    got = ring.m_wait.tolist()
+    assert got[0] == want[0] and got[2:] == want[2:]
+    assert want[1] <= got[1] <= want[1] + n * slack
+    rc, frag = ring.consume(seq)          # the one frag left
+    assert rc == 0 and int(ring.m_wait[0]) == n + 1

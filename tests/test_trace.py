@@ -185,8 +185,10 @@ def _fe(ts, etype, sig, link):
 
 def test_lineage_sig_zero_and_per_hop_latency():
     """sig=0 is a real lineage key (synth sigs start at 0), and the
-    summary's per-link latency is the PER-HOP delta (consume vs the
-    most recent publish), not cumulative from the chain's origin."""
+    summary's per-link latency is each hop's own queue wait (publish ->
+    consume, from the link telemetry block's histogram), not the
+    cumulative time from the chain's origin."""
+    from firedancer_tpu.disco.metrics import HistAccum
     evs = {
         "a": [_fe(100_000, tev.EV_PUBLISH, 0, "a_b")],
         "b": [_fe(150_000, tev.EV_CONSUME, 0, "a_b"),
@@ -195,10 +197,26 @@ def test_lineage_sig_zero_and_per_hop_latency():
     }
     chains = lineage(evs)
     assert 0 in chains and len(chains[0]) == 4
-    text = summary(evs)
+
+    def hop(*waits_ns):
+        h = HistAccum()
+        for w in waits_ns:
+            h.add(w)
+        return {"producer": None, "pub": 0, "pub_bytes": 0,
+                "backpressure": 0,
+                "consumers": {"c": {"consumed": len(waits_ns),
+                                    "bytes": 0, "overruns": 0,
+                                    "hist": {"count": h.count,
+                                             "sum_ns": h.sum_ns,
+                                             "buckets": h.buckets}}}}
+    text = summary(evs, links={"b_c": hop(40_000, 40_000, 44_000),
+                               "a_b": hop()})
     row = next(ln for ln in text.splitlines() if ln.startswith("b_c"))
-    # 200us - 160us = 40us per-hop (NOT 100us from the origin publish)
-    assert row.split()[2] == "40.0"
+    # the b_c hop's own waits (mean 41.3us; both quantiles in the
+    # [32.768, 65.536) us bucket), NOT 100us from the origin publish
+    assert row.split()[1:] == ["c", "3", "41.3", "65.5", "65.5"]
+    assert "queue wait (publish -> consume)" in text
+    assert not any(ln.startswith("a_b") for ln in text.splitlines())
     doc = to_chrome(evs)
     flows = [e for e in doc["traceEvents"] if e.get("id") == "0x0"]
     assert any(e["ph"] == "s" for e in flows)
@@ -403,10 +421,12 @@ def test_frag_lineage_appears_as_correlated_spans(traced_pipeline):
     assert starts[0]["tid"] == tids["verify"]
     assert finishes[-1]["tid"] == tids["sink"]
     assert starts[0]["ts"] <= finishes[-1]["ts"]
-    # the verify tile's device spans are present as complete events
+    # the verify tile's stage spans (device dispatch and readback
+    # among them) are present as complete events, named by stage
     span_names = {e["name"] for e in te if e.get("ph") == "X"
                   and e["tid"] == tids["verify"]}
-    assert {"tpu_dispatch", "tpu_readback"} <= span_names
+    assert {"gather", "parse", "dedup", "assemble", "dispatch",
+            "readback", "finalize", "deferred", "publish"} <= span_names
 
 
 def test_cli_exports_live_and_post_mortem(traced_pipeline, tmp_path,
@@ -427,10 +447,18 @@ def test_cli_exports_live_and_post_mortem(traced_pipeline, tmp_path,
 
 
 def test_summary_attributes_wait_and_link_latency(traced_pipeline):
+    from firedancer_tpu.disco.metrics import read_link_metrics
     runner = traced_pipeline
     evs = read_rings(runner.plan, runner.wksp)
-    text = summary(evs)
+    text = summary(evs, links=read_link_metrics(runner.wksp,
+                                                runner.plan))
     assert "verify_sink" in text and "p99_us" in text
+    # every frag the sink consumed was timed on the verify_sink hop
+    row = next(ln for ln in text.splitlines()
+               if ln.startswith("verify_sink"))
+    assert row.split()[1:3] == ["sink", str(N_TXNS)]
+    # the verify tile's stage time is broken out per stage
+    assert "verify stages (ms):" in text and "dispatch=" in text
     # the idle sink accumulated wait spans; verify did device work
     assert "sink" in text and "verify" in text
 
