@@ -16,6 +16,8 @@ one process holds the chip at any time and this parent never imports JAX:
             `pallas_ed.verify_batch` over a seeded mixed set (valid;
             corrupt R, S, msg, A; non-canonical S; small-order A and
             R), verdicts equal bit for bit to utils/ed25519_ref.py; the
+            same call limited to its first LIVE lanes (`n_live`) gives
+            those lanes the same verdicts and every later lane False; the
             RLC prefilter `pallas_msm.rlc_verify_batch_tpu` passes an
             all-valid batch and fails one with a forged lane; the
             Mosaic lane/sublane rolls rotate like jnp.roll.
@@ -58,6 +60,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, ".chip_smoke")         # logs (git-ignored)
 BATCH, MSG_LEN, SEED = 8192, 1232, 21
+LIVE = 1300                     # a part-full call: 6 of 32 grid programs
 KERNEL_TIMEOUT_S = 900          # cold MSM compile alone is ~4 min
 PIPELINE_BOOT_S = 600
 PIPELINE_RUN_S = 300
@@ -261,6 +264,20 @@ def kernel_child(data: str, mesh: int) -> dict:
         f"({int(got.sum())} valid)")
     if mesh:
         return info
+
+    fn = jax.jit(lambda s, p, m, n, live: pallas_ed.verify_batch(
+        s, p, m, n, n_live=live))
+    first, steady, ok = _timed(fn, *args, np.int32(LIVE))
+    part = np.asarray(ok)
+    say(f"kernel: strict n_live={LIVE} steady {steady * 1e3:.1f} ms/call "
+        f"(info)")
+    if not (np.array_equal(part[:LIVE], got[:LIVE])
+            and not part[LIVE:].any()):
+        raise PhaseError(f"strict n_live={LIVE}: live verdicts differ from "
+                         f"the full-width call, or a lane past the count "
+                         f"reads True")
+    say(f"kernel: strict n_live={LIVE} verdicts == full width on the live "
+        f"lanes, False on the {len(part) - LIVE} past the count")
 
     from firedancer_tpu.ops.ed25519 import rlc_verify_fn
     rlc = rlc_verify_fn()       # the verify tile's resolver

@@ -302,13 +302,14 @@ class VerifyAdapter:
                # (trace/events.py STAGE_IDS; capture = a device
                # capture's own start/stop), strict lanes dispatched,
                # parked duplicates and their host re-verifies, and the
-               # summed residence of the txns that reached a device lane
+               # summed residence of the txns that reached a device lane,
+               # and the strict kernel's grid programs run
                "ns_gather", "ns_parse", "ns_dedup", "ns_assemble",
                "ns_dispatch", "ns_readback", "ns_finalize",
                "ns_deferred", "ns_publish", "ns_capture",
                "lanes", "deferred_parked", "deferred_host_verifies",
                "txn_hold_ns", "txn_device_ns", "txn_egress_ns",
-               "txn_timed"]
+               "txn_timed", "kernel_programs"]
     GAUGES = ["cpu_fallback", "tpu_jit_compiles", "tpu_jit_cache_miss",
               "tpu_inflight", "tpu_mem_bytes", "tpu_compile_ns"]
     # declared (not name-sniffed) device-telemetry slots: the renderer
@@ -378,12 +379,13 @@ class VerifyAdapter:
     def housekeeping(self):
         self._compile_watch.poll()
         if self._capture is not None:
-            # a window opening or closing is the capture stage: the
-            # profiler's own start/stop, not the tile's work
+            # a window opening, closing or landing on disk is the
+            # capture stage: the profiler's own start/stop, not the
+            # tile's work
             from ..utils.tempo import monotonic_ns
             was, t0 = self._capture.active, monotonic_ns()
-            self._capture.poll()
-            if self._capture.active != was:
+            landed = self._capture.poll()
+            if self._capture.active != was or landed:
                 self.stages.add(_STAGE_CAPTURE, t0)
         knobs = getattr(self.ctx, "knobs", None)
         if knobs is not None:

@@ -14,7 +14,11 @@ measures ~55 ns/lane; in-kernel the same multiply is ~1.3 ns/lane.
 Layout: field elements are (NLIMB, TB) int32 limb-major blocks (batch in
 the lane dimension, limbs in sublanes), radix 2^13, same representation
 and bound discipline as ops/fe25519.py (see its module docstring for the
-carry analysis). The grid splits the batch into TB-lane programs.
+carry analysis). The grid splits the batch into TB-lane programs. The
+live-lane count (a traced scalar prefetched to SMEM; the whole batch
+when the caller gives none) guards each program: those that hold only
+padding run nothing, so a part-full batch costs what its live programs
+cost under the one compiled shape.
 
 One fused kernel (`_verify_kernel`, r4 — previously decompress and
 dsm+encode were two dispatches with an HBM bounce of x/t between them):
@@ -635,12 +639,23 @@ def _verify_core(pub, rb, k64, s32, fb_ymx_ref, fb_ypx_ref, fb_t2d_ref,
     return (dec_ok & match).astype(jnp.int32)
 
 
-def _verify_kernel(pub_ref, r_ref, k64_ref, s32_ref,
+def _verify_kernel(n_ref, pub_ref, r_ref, k64_ref, s32_ref,
                    fb_ymx_ref, fb_ypx_ref, fb_t2d_ref, ok_ref,
                    kw_ref, sw_ref):
-    ok_ref[:] = _verify_core(pub_ref[:], r_ref[:], k64_ref[:],
-                             s32_ref[:], fb_ymx_ref, fb_ypx_ref,
-                             fb_t2d_ref, kw_ref, sw_ref)
+    """Runs the core for a program that holds a lane below the live
+    count n_ref[0]; a program made only of padding runs nothing and
+    writes 0 (fail), so a dead lane never reads as verified."""
+    live = pl.program_id(0) * ok_ref.shape[-1] < n_ref[0]
+
+    @pl.when(live)
+    def _run():
+        ok_ref[:] = _verify_core(pub_ref[:], r_ref[:], k64_ref[:],
+                                 s32_ref[:], fb_ymx_ref, fb_ypx_ref,
+                                 fb_t2d_ref, kw_ref, sw_ref)
+
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        ok_ref[:] = jnp.zeros(ok_ref.shape, jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -660,36 +675,49 @@ def _win_spec(tb):
 
 
 def _byte_spec(nrows, tb):
-    return pl.BlockSpec((nrows, tb), lambda i: (0, i),
-                        memory_space=pltpu.VMEM)
+    def live_block(i, n_ref):
+        # a program past the count maps its inputs to the last live
+        # block: the pipeline skips a block it already holds
+        return 0, jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0) // tb)
+    return pl.BlockSpec((nrows, tb), live_block, memory_space=pltpu.VMEM)
 
 
 def _tab_spec():
-    return pl.BlockSpec((64, 16, NL), lambda i: (0, 0, 0),
+    return pl.BlockSpec((64, 16, NL), lambda i, n_ref: (0, 0, 0),
                         memory_space=pltpu.VMEM)
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "interpret"))  # fdlint: disable=missing-donate — inputs are host numpy (copied on transfer), nothing device-resident to donate
-def verify_tpu(pub_t, r_t, k64_t, s32_t, tb=DEFAULT_TB, interpret=False):
+def verify_tpu(pub_t, r_t, k64_t, s32_t, n_live=None, tb=DEFAULT_TB,
+               interpret=False):
     """Fused verify core. pub_t/r_t/s32_t (32, B) and k64_t (64, B)
     int32 LE byte rows (pub/R encodings, sha512(R||A||M) output, S).
-    Returns ok (1, B)."""
+    Returns ok (1, B).
+
+    `n_live` (an int32 scalar, traced: one compiled program serves every
+    count; None means all B) says lanes [0, n_live) are live. Only the
+    grid programs that hold a live lane run; the lanes of the others
+    read 0. A program's work per lane is the same either way."""
     b = pub_t.shape[-1]
     assert b % tb == 0, (b, tb)
-    ymx, ypx, t2d = _fb_tables()
-    grid = (b // tb,)
-    return pl.pallas_call(
-        _verify_kernel,
-        grid=grid,
+    n = jnp.reshape(jnp.asarray(b if n_live is None else n_live,
+                                jnp.int32), (1,))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b // tb,),
         in_specs=[_byte_spec(32, tb), _byte_spec(32, tb),
                   _byte_spec(64, tb), _byte_spec(32, tb),
                   _tab_spec(), _tab_spec(), _tab_spec()],
-        out_specs=[_row_spec(tb)],
+        out_specs=[pl.BlockSpec((1, tb), lambda i, n_ref: (0, i),
+                                memory_space=pltpu.VMEM)],
+        scratch_shapes=[pltpu.VMEM((64, tb), jnp.int32)] * 2)
+    return pl.pallas_call(
+        _verify_kernel,
+        grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((1, b), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((64, tb), jnp.int32)] * 2,
         interpret=interpret,
-    )(pub_t, r_t, k64_t, s32_t,
-      jnp.asarray(ymx), jnp.asarray(ypx), jnp.asarray(t2d))[0]
+    )(n, pub_t, r_t, k64_t, s32_t,
+      *[jnp.asarray(t) for t in _fb_tables()])[0]
 
 
 # ---------------------------------------------------------------------------
@@ -722,11 +750,15 @@ def _bytes_lt(b, const_int: int, mask_top7: bool = False):
     return lt
 
 
-def verify_batch(sig, pub, msg, msg_len, tb=DEFAULT_TB, interpret=False):
+def verify_batch(sig, pub, msg, msg_len, n_live=None, tb=DEFAULT_TB,
+                 interpret=False):
     """Drop-in equivalent of ops.ed25519.verify_batch on the Pallas path.
 
     sig (B, 64) u8, pub (B, 32) u8, msg (B, L) u8, msg_len (B,) i32
     -> (B,) bool. Batch is padded up to a multiple of `tb` internally.
+    With `n_live`, lanes [0, n_live) are live: the kernel skips the
+    `tb`-lane programs of padding (verify_tpu), and every lane past the
+    count reads False.
     """
     bsz = sig.shape[0]
     b_pad = -(-bsz // tb) * tb
@@ -746,5 +778,9 @@ def verify_batch(sig, pub, msg, msg_len, tb=DEFAULT_TB, interpret=False):
     to_rows = lambda a: _pad_to(                          # noqa: E731
         jnp.moveaxis(a.astype(jnp.int32), 0, -1), b_pad, axis=1)
     ok = verify_tpu(to_rows(pub), to_rows(r_bytes), to_rows(k64),
-                    to_rows(s_bytes), tb=tb, interpret=interpret)
-    return s_ok & a_ok & r_ok & (ok[0, :bsz] == 1)
+                    to_rows(s_bytes), n_live=n_live, tb=tb,
+                    interpret=interpret)
+    ok = s_ok & a_ok & r_ok & (ok[0, :bsz] == 1)
+    if n_live is not None:
+        ok = ok & (jnp.arange(bsz) < n_live)
+    return ok

@@ -10,9 +10,13 @@ ProfRegion (bumped by the metric tile on an SLO breach, or by
 per-tile directory, lets the normal poll loop run the window out, then
 stops the trace, writes a JSON manifest next to the supervisor black
 boxes, stamps an EV_PROF_CAPTURE span into the flight recorder, and
-acks. A backend without a working profiler still produces the manifest
-(ok=false + the error) — a breach-triggered drill must leave an
-artifact either way.
+acks. `stop_trace` serializes the whole window (seconds for a busy
+device tile) and, with the Python tracer off, releases the GIL while
+it does, so it runs on a thread of its own: the tile keeps polling,
+and a later housekeeping pass writes the manifest once the trace is
+on disk. A backend without
+a working profiler still produces the manifest (ok=false + the error)
+— a breach-triggered drill must leave an artifact either way.
 
 One clock with the device trace: profiler times count from the
 capture's start, fdtrace records carry CLOCK_MONOTONIC. Right after
@@ -34,6 +38,7 @@ from __future__ import annotations
 import json
 import os
 import tempfile
+import threading
 
 from ..utils.tempo import monotonic_ns
 
@@ -114,11 +119,11 @@ class DeviceCapture:
     """The owning tile's capture state machine (one per device tile,
     polled from its housekeeping — never from the hot loop):
 
-        poll() -> started | stopped-manifest-path | None
+        poll() -> started | stopping | stopped-manifest-path | None
 
     Window length comes from the plan's [prof] capture_ms; the window
     runs out across housekeeping passes so the poll loop keeps driving
-    the device while the profiler records it."""
+    the device while the profiler records it, and so does the stop."""
 
     def __init__(self, plan: dict, tile: str, region, trace=None):
         self.plan, self.tile, self.region = plan, tile, region
@@ -127,12 +132,18 @@ class DeviceCapture:
         self.window_ms = float(
             (plan.get("prof") or {}).get("capture_ms", 200.0))
         self._active: dict | None = None
+        self._stopping: dict | None = None   # window closed, not on disk
         self.captures = 0
 
     @property
     def active(self) -> bool:
         """A window is open."""
         return self._active is not None
+
+    @property
+    def stopping(self) -> bool:
+        """A closed window's trace is still being written."""
+        return self._stopping is not None
 
     def _start(self, req: int):
         t0 = monotonic_ns()
@@ -141,31 +152,58 @@ class DeviceCapture:
         try:
             os.makedirs(d, exist_ok=True)
             import jax
-            jax.profiler.start_trace(d)
+            # XLA ops, dispatches and annotations only: Python frames
+            # are the host sampler's to explain (recorder.py), and the
+            # Python tracer's collect holds the GIL for its event per
+            # call of the busy poll loop — a second-long tile stall,
+            # even with the stop off the tile's thread
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(d, profiler_options=opts)
             clock_mark()
         except Exception as e:     # noqa: BLE001 — manifest either way
             err = f"{e!r}"[:200]
         self._active = {"req": req, "t0": t0, "dir": d, "err": err,
                         "deadline": t0 + int(self.window_ms * 1e6)}
 
-    def _stop(self) -> str | None:
+    def _stop(self):
+        """Close the window on both clocks and take the tile's ring
+        records in it, then start stop_trace on a thread of its own."""
         act, self._active = self._active, None
-        t1 = monotonic_ns()
-        fdtrace = None
+        act["t1"] = monotonic_ns()
+        act["thread"] = act["records"] = None
         if act["err"] is None:
             try:
-                import jax
                 clock_mark()
-                jax.profiler.stop_trace()
             except Exception as e:  # noqa: BLE001
                 act["err"] = f"{e!r}"[:200]
-        if act["err"] is None and self.trace is not None:
+        if act["err"] is None:
+            if self.trace is not None:
+                act["records"] = window_records(self.trace, act["t0"],
+                                                act["t1"])
+
+            def export():
+                try:
+                    import jax
+                    jax.profiler.stop_trace()
+                except Exception as e:  # noqa: BLE001
+                    act["err"] = f"{e!r}"[:200]
+            act["thread"] = threading.Thread(
+                target=export, name="fdtpu-capture-stop", daemon=True)
+            act["thread"].start()
+        self._stopping = act
+
+    def _finish(self) -> str | None:
+        """The trace is on disk (or failed): manifest, span, ack."""
+        act, self._stopping = self._stopping, None
+        fdtrace = None
+        if act["err"] is None and act["records"] is not None:
             fdtrace = os.path.join(act["dir"], FDTRACE_FILE)
             try:
                 with open(fdtrace, "w") as f:
                     json.dump({"tile": self.tile, "t0_ns": act["t0"],
-                               "t1_ns": t1, "records": window_records(
-                                   self.trace, act["t0"], t1)}, f)
+                               "t1_ns": act["t1"],
+                               "records": act["records"]}, f)
             except OSError:
                 fdtrace = None
         doc = {
@@ -173,7 +211,7 @@ class DeviceCapture:
             "tile": self.tile,
             "req": act["req"],
             "t0_ns": act["t0"],
-            "t1_ns": t1,
+            "t1_ns": act["t1"],
             "window_ms": self.window_ms,
             "ok": act["err"] is None,
             "trace_dir": act["dir"] if act["err"] is None else None,
@@ -196,20 +234,30 @@ class DeviceCapture:
 
     def poll(self) -> str | None:
         """One housekeeping-cadence pass; returns the manifest path
-        when a window just closed."""
+        when a window's trace just landed on disk."""
         if self._active is not None:
-            if monotonic_ns() >= self._active["deadline"]:
-                return self._stop()
-            return None
+            if monotonic_ns() < self._active["deadline"]:
+                return None
+            self._stop()
+        if self._stopping is not None:
+            th = self._stopping["thread"]
+            if th is not None and th.is_alive():
+                return None
+            return self._finish()
         req = self.region.capture_req
         if req > self.region.capture_ack:
             self._start(req)
         return None
 
     def flush(self):
-        """Halt path: close an open window so the ack never dangles."""
+        """Halt path: close an open window and wait for its trace, so
+        the ack never dangles."""
         if self._active is not None:
             self._stop()
+        if self._stopping is not None:
+            if self._stopping["thread"] is not None:
+                self._stopping["thread"].join()
+            self._finish()
 
 
 class CompileWatch:

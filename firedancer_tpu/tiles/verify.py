@@ -10,9 +10,14 @@ req/resp offload behind the ring ABI):
     --tcache dedup on first sig--> out ring (payload + PASS sig)
 
 Batch assembly keeps ONE compiled shape (short batches are padded with
-dead lanes, masked after) so XLA never recompiles in steady state; a txn
-with k signatures occupies k lanes and passes only if all k verify (the
-reference loops sigs the same way, fd_verify_tile.h:94).
+dead lanes, masked after) so XLA never recompiles in steady state. The
+live-lane count rides each dispatch as a traced scalar: on the Pallas
+path the strict kernel skips its 256-lane grid programs that hold only
+padding, so a short batch costs what its live lanes cost (counted in
+`kernel_programs`); the jnp path on the CPU (no grid) and the
+multi-device mesh take no count. A txn with k signatures occupies k
+lanes and passes only if all k verify (the reference loops sigs the
+same way, fd_verify_tile.h:94).
 
 Device staging (r10): each rotating buffer set is ONE contiguous host
 buffer (len|sig|pub|msg lanes packed back to back — the native
@@ -229,6 +234,9 @@ class VerifyTile:
             "lanes": 0, "deferred_parked": 0, "deferred_host_verifies": 0,
             "txn_hold_ns": 0, "txn_device_ns": 0, "txn_egress_ns": 0,
             "txn_timed": 0,
+            # strict-kernel grid programs run: ceil(lanes / 256) per
+            # counted device dispatch, batch / 256 (all) otherwise
+            "kernel_programs": 0,
         }
         if mode not in ("strict", "bulk_prefilter"):
             raise ValueError(f"unknown verify mode {mode!r} "
@@ -300,13 +308,16 @@ class VerifyTile:
         self.tpu_hist = HistAccum()
         if backend == "jax":
             import jax
+            from ..ops.pallas_ed import DEFAULT_TB as tb
             devs = jax.devices()
-            if devs[0].platform == "cpu":
+            # `counted`: vb takes the live-lane count (the Pallas grid
+            # of `tb`-lane programs)
+            counted = devs[0].platform != "cpu"
+            if not counted:
                 from ..ops.ed25519 import verify_batch as vb
             else:
                 # fused Pallas kernels on accelerator backends
-                from ..ops.pallas_ed import verify_batch as _pvb
-                vb = (lambda s, p, m, l: _pvb(s, p, m, l))
+                from ..ops.pallas_ed import verify_batch as vb
             ndev = min(int(devices), len(devs))
             if ndev > 1:
                 # shard the batch axis over the device mesh: the
@@ -320,14 +331,18 @@ class VerifyTile:
                 mesh = Mesh(np.array(devs[:ndev]), ("shard",))
                 # carries start as constants (sha IV / identity point)
                 # and become axis-varying in the loop body — disable
-                # the varying-manual-axes check
+                # the varying-manual-axes check. Each shard runs its
+                # whole slice: no live-lane count on the mesh path
                 vb = jax.shard_map(
                     vb, mesh=mesh, in_specs=(P("shard"),) * 4,
                     out_specs=P("shard"), check_vma=False)
+                counted = False
             self.devices = ndev
+            self._counted, self._grain = counted, tb
             # staged dispatch: the jit consumes ONE packed uint8 buffer
             # (the whole staging set) and splits the lanes on-device —
-            # host->device is a single transfer per dispatch. The
+            # host->device is a single transfer per dispatch — plus the
+            # live-lane count, an int32 scalar (traced: no retrace). The
             # transfer buffer is donated on real accelerators (each
             # dispatch device_puts a fresh copy, so the computation may
             # consume it); CPU device_put can alias host memory, where
@@ -339,14 +354,16 @@ class VerifyTile:
             if fn_key in _FN_CACHE:
                 self._fn = _FN_CACHE[fn_key]
             else:
-                def _packed(flat):
+                def _packed(flat, n):
                     import jax.numpy as jnp
                     lb = flat[:o_sig].reshape(bsz, 4).astype(jnp.int32)
                     ln = (lb[:, 0] | (lb[:, 1] << 8) | (lb[:, 2] << 16)
                           | (lb[:, 3] << 24))
-                    return vb(flat[o_sig:o_pub].reshape(bsz, 64),
-                              flat[o_pub:o_msg].reshape(bsz, 32),
-                              flat[o_msg:].reshape(bsz, mlen), ln)
+                    lanes = (flat[o_sig:o_pub].reshape(bsz, 64),
+                             flat[o_pub:o_msg].reshape(bsz, 32),
+                             flat[o_msg:].reshape(bsz, mlen), ln)
+                    return (vb(*lanes, n_live=n, tb=tb) if counted
+                            else vb(*lanes))
 
                 donate = (0,) if devs[0].platform != "cpu" else ()
                 self._fn = _FN_CACHE[fn_key] = jax.jit(
@@ -423,7 +440,9 @@ class VerifyTile:
         def _worker():
             try:
                 import jax
-                out = jax.block_until_ready(self._device_verify(bs))
+                # the full batch: boot runs every grid program once
+                out = jax.block_until_ready(
+                    self._device_verify(bs, self.batch))
                 self._warm_shards = len(
                     {s.device for s in out.addressable_shards})
                 q.put(None)
@@ -448,15 +467,16 @@ class VerifyTile:
             from ..utils import log
             log.warning(f"verify: degrading to CPU reference path ({why})")
 
-    def _device_verify(self, bs: _StageBuf):
+    def _device_verify(self, bs: _StageBuf, lanes: int):
         """Async staged dispatch: ONE host->device transfer of the
         packed staging buffer (device_put starts the copy and returns;
-        the jit splits lanes on-device), then the verdict future —
+        the jit splits lanes on-device; `lanes` live ones, the rest
+        padding the kernel skips), then the verdict future —
         never forced, callers pipeline and block later. The staging
         set stays untouched until its future resolves (_bufset_fut
         guard), so the async transfer always reads stable memory."""
         import jax
-        return self._fn(jax.device_put(bs.flat))
+        return self._fn(jax.device_put(bs.flat), np.int32(lanes))
 
     def _draw_z(self, n: int) -> np.ndarray:
         """Secret per-chunk RLC coefficients (n,16) u8. A method so the
@@ -575,7 +595,7 @@ class VerifyTile:
                         chaos_event(self._trace, "fail_dispatch")
                     raise ChaosDeviceError("injected dispatch failure")
                 t0 = monotonic_ns()
-                fut = self._device_verify(bs)
+                fut = self._device_verify(bs, lanes)
                 self.tpu_hist.add(monotonic_ns() - t0)
                 return fut
             except Exception:
@@ -852,6 +872,11 @@ class VerifyTile:
             self._disp += 1
             self.metrics["batches"] += 1
             self.metrics["lanes"] += lanes
+            # only a counted device dispatch skips programs; the jnp
+            # path, the mesh and host verdicts count the whole batch
+            live = (lanes if self._counted
+                    and not isinstance(fut, np.ndarray) else self.batch)
+            self.metrics["kernel_programs"] += -(-live // self._grain)
             chunks.append((fut, bs.txn[:lanes].copy()))
             t = st.add(_DISPATCH, t)
         # residence of the txns that reached a device lane: each waited

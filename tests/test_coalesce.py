@@ -112,15 +112,18 @@ class ScriptedFut:
         return self.v
 
 
-def _script_backend(tile):
+def _script_backend(tile, counts=None):
     """Swap the tile's jit for a scripted fake AFTER warmup: records
-    each dispatch's transfer shape and returns a ScriptedFut."""
+    each dispatch's transfer shape (and, given `counts`, its live-lane
+    count) and returns a ScriptedFut."""
     futs = []
     shapes = []
     flat_len = tile._bufsets[0].flat.shape[0]
 
-    def fake_fn(flat):
+    def fake_fn(flat, n):
         shapes.append(tuple(np.asarray(flat).shape))
+        if counts is not None:
+            counts.append(n)
         fut = ScriptedFut(np.ones(tile.batch, bool))
         futs.append(fut)
         return fut
@@ -156,6 +159,62 @@ def test_dispatch_k1_does_not_block_on_readback_of_k(wksp, txns, _shared_tile):
     assert not tile._pending
     assert tile.metrics["tx"] == 8
     assert _collect(out_ring) == [bytes(t) for t in txns[:8]]
+
+
+def test_dispatch_passes_its_live_lane_count(wksp, txns, _shared_tile,
+                                             monkeypatch):
+    """Each dispatch hands the kernel exactly the lanes it assembled, as
+    an int32 scalar (one traced shape for every count), and
+    `kernel_programs` sums ceil(lanes / grid width) over dispatches.
+    The batch here is one 16-lane program at the kernel's own width, so
+    the grid width is narrowed to 8 lanes to make the rounding show,
+    and the tile is made to count as on the Pallas path (the CPU's jnp
+    path takes no count)."""
+    from firedancer_tpu.ops.pallas_ed import DEFAULT_TB
+    assert _shared_tile._grain == DEFAULT_TB
+    monkeypatch.setattr(_shared_tile, "_grain", 8)
+    monkeypatch.setattr(_shared_tile, "_counted", True)
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    counts = []
+    futs, _, _ = _script_backend(tile, counts)
+    for t in txns[:16]:                   # one full batch (single-signer)
+        in_ring.publish(t, sig=1)
+    tile.poll_once()
+    for t in txns[16:21]:                 # then 5 lanes
+        in_ring.publish(t, sig=2)
+    tile.poll_once()
+    assert counts == [16, 5]
+    assert all(isinstance(n, np.int32) for n in counts)
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert tile.metrics["lanes"] == 21
+    assert tile.metrics["kernel_programs"] == 2 + 1   # ceil(16/8) + ceil(5/8)
+    assert _collect(out_ring) == [bytes(t) for t in txns[:21]]
+
+
+@pytest.mark.parametrize("path", ["uncounted", "host_verdicts"])
+def test_kernel_programs_whole_batch_where_nothing_skips(
+        wksp, txns, _shared_tile, monkeypatch, path):
+    """A dispatch that carries no count (the CPU jnp path, the mesh)
+    runs every program, and one answered with host verdicts (degraded)
+    skipped nothing on the device: each adds the whole batch's programs
+    to `kernel_programs`, never a skip that did not happen."""
+    monkeypatch.setattr(_shared_tile, "_grain", 8)
+    monkeypatch.setattr(_shared_tile, "_counted", path == "host_verdicts")
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    futs, _, _ = _script_backend(tile)
+    tile.degraded = path == "host_verdicts"
+    for t in txns[:5]:
+        in_ring.publish(t, sig=1)
+    tile.poll_once()
+    assert len(futs) == (path == "uncounted")
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert tile.metrics["lanes"] == 5
+    assert tile.metrics["kernel_programs"] == BATCH // 8
+    assert _collect(out_ring) == [bytes(t) for t in txns[:5]]
 
 
 def test_coalesce_holds_subfull_until_lane_budget_fills(wksp, txns, _shared_tile):
@@ -339,8 +398,8 @@ def test_stage_ns_within_stem_time_and_lanes_counted(wksp, txns,
     futs, _, _ = _script_backend(tile)
     real_fn = tile._fn
 
-    def ready_fn(flat):                   # verdicts land after 2 polls
-        fut = real_fn(flat)
+    def ready_fn(flat, n):                # verdicts land after 2 polls
+        fut = real_fn(flat, n)
         fut.ready_after = 2
         return fut
     tile._fn = ready_fn
@@ -381,7 +440,7 @@ def test_deferred_host_verifies_only_for_raced_forged_twins(
     tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
     futs = []
 
-    def fake_fn(flat):
+    def fake_fn(flat, n):
         futs.append(ScriptedFut(np.full(tile.batch, not forged)))
         return futs[-1]
     tile._fn = fake_fn

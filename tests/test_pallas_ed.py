@@ -193,3 +193,32 @@ def test_verify_core_pure_matches_reference():
     # mixed batch has canonical S and non-small-order points, so the
     # core verdict must equal the full reference verdict here
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.fixture
+def cheap_core(monkeypatch):
+    """The fused kernel with a cheap stand-in for _verify_core (the real
+    core's interpret run is opt-in slow, above): each lane's verdict is
+    its pub byte 0 plus 1, never 0. verify_tpu is retraced around it."""
+    monkeypatch.setattr(ped, "_verify_core", lambda pub, *_: pub[0:1] + 1)
+    ped.verify_tpu.clear_cache()
+    yield
+    ped.verify_tpu.clear_cache()
+
+
+@pytest.mark.parametrize("n_live", [None, 0, 1, 8, 9, 32])
+def test_live_count_skips_programs_of_padding(cheap_core, n_live):
+    """verify_tpu over 4 programs of 8 lanes: with a live-lane count,
+    the programs holding a lane below it carry the core's verdicts (dead
+    lanes of a live program included) and the others read 0; with no
+    count every program runs."""
+    b, tb = 32, 8
+    rng = np.random.default_rng(7)
+    rows = [jnp.asarray(rng.integers(0, 256, (h, b)).astype(np.int32))
+            for h in (32, 32, 64, 32)]
+    full = np.asarray(rows[0])[0] + 1
+    n = None if n_live is None else np.int32(n_live)
+    got = np.asarray(ped.verify_tpu(*rows, n, tb=tb, interpret=True))[0]
+    live = b if n_live is None else -(-n_live // tb) * tb
+    np.testing.assert_array_equal(got, np.where(np.arange(b) < live,
+                                                full, 0))

@@ -590,13 +590,29 @@ def test_trace_dir_honours_tmpdir(tmp_path, monkeypatch):
         tmp_path / "fdtpu_prof_topo_verify")
 
 
+def _python_frame_probe():
+    return sum(range(10))
+
+
+def _poll_until_landed(cap, timeout_s=60.0):
+    """Housekeeping passes until the capture's trace is on disk."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        path = cap.poll()
+        if path is not None:
+            return path
+        time.sleep(0.01)
+    raise AssertionError("the capture's trace never landed")
+
+
 def test_clock_anchor_maps_ring_span_onto_trace(wksp, tmp_path,
                                                 monkeypatch):
     """A CPU jax.profiler capture: the fdtpu_clock marks DeviceCapture
     makes at its start and stop map a ring span (CLOCK_MONOTONIC) onto
     the trace's own times within 1 ms — checked against a profiler
-    annotation made over the same interval — and the tile's ring
-    records inside the window land beside the trace as fdtrace.json."""
+    annotation made over the same interval — the trace holds no Python
+    frames, and the tile's ring records inside the window land beside
+    the trace as fdtrace.json."""
     import glob
 
     import jax
@@ -620,10 +636,11 @@ def test_clock_anchor_maps_ring_span_onto_trace(wksp, tmp_path,
     t0 = monotonic_ns()
     with jax.profiler.TraceAnnotation("probe"):
         time.sleep(0.005)
+        _python_frame_probe()
     tr.span(tev.EV_STAGE, t0, count=tev.STAGE_IDS["deferred"])
     time.sleep(0.06)
-    path = cap.poll()
-    assert not cap.active and bell.capture_ack == 1
+    path = _poll_until_landed(cap)
+    assert not cap.active and not cap.stopping and bell.capture_ack == 1
     man = json.load(open(path))
     os.unlink(path)
     assert man["ok"] and man["fdtrace"] == str(tmp_path / "cap" /
@@ -636,9 +653,56 @@ def test_clock_anchor_maps_ring_span_onto_trace(wksp, tmp_path,
     assert len(marks) == 2                 # after start, before stop
     off = device.clock_offset_ns(marks)
     probe = next(e for e in evs if e.name == "probe")
+    # annotations yes, Python frames no (the Python tracer is off)
+    assert not any("_python_frame_probe" in e.name for e in evs)
     recs = json.load(open(man["fdtrace"]))["records"]
     end, dur, name = next(r for r in recs if r[2] == "stage:deferred")
     assert abs((end - dur - off) - probe.start_ns) < 1e6
     assert abs((end - off) - (probe.start_ns + probe.duration_ns)) < 1e6
     # only the window's records, frag-scoped ones left out
     assert all(man["t0_ns"] <= r[0] for r in recs)
+
+
+@pytest.mark.parametrize("end", ["poll", "flush", "stop_fails"])
+def test_capture_stop_runs_off_the_tile_thread(tmp_path, monkeypatch, end):
+    """stop_trace (seconds on a busy device tile) runs on a thread of
+    its own: the housekeeping pass that closes the window returns at
+    once and acks nothing; a later pass (or the halt path's flush,
+    which waits for it) writes the manifest and acks — ok=false with
+    the error when the stop fails."""
+    import jax
+
+    from firedancer_tpu.prof import device
+    monkeypatch.setattr(device, "trace_dir",
+                        lambda topo, tile: str(tmp_path / "cap"))
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
+
+    def slow_stop():
+        time.sleep(0.5)
+        if end == "stop_fails":
+            raise RuntimeError("no profile started")
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    bell = _Doorbell()
+    cap = device.DeviceCapture({"topology": f"stp{os.getpid()}",
+                                "prof": {"capture_ms": 20.0}},
+                               "verify", bell)
+    bell.capture_req = 1
+    assert cap.poll() is None and cap.active
+    time.sleep(0.03)
+    t0 = time.monotonic()
+    assert cap.poll() is None
+    assert time.monotonic() - t0 < 0.25      # not held for the stop
+    assert not cap.active and cap.stopping and bell.capture_ack == 0
+    bell.capture_req = 2                     # no new window mid-stop
+    assert cap.poll() is None and not cap.active
+    if end == "flush":
+        cap.flush()
+        path = device.capture_manifest_path(f"stp{os.getpid()}", "verify")
+    else:
+        path = _poll_until_landed(cap)
+    assert not cap.stopping and bell.capture_ack == 1 and cap.captures == 1
+    man = json.load(open(path))
+    os.unlink(path)
+    assert man["ok"] is (end != "stop_fails")
+    assert (man["error"] is None) is (end != "stop_fails")
+    assert man["t1_ns"] - man["t0_ns"] >= 20e6

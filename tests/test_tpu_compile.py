@@ -66,6 +66,11 @@ def _verify(sig, pub, msg, ln):
     return verify_batch(sig, pub, msg, ln)
 
 
+def _verify_counted(sig, pub, msg, ln, n):
+    from firedancer_tpu.ops.pallas_ed import verify_batch
+    return verify_batch(sig, pub, msg, ln, n_live=n)
+
+
 def _rlc(sig, pub, msg, ln, z):
     from firedancer_tpu.ops.pallas_msm import rlc_verify_batch_tpu
     return rlc_verify_batch_tpu(sig, pub, msg, ln, z)
@@ -78,7 +83,10 @@ _SIG = [((B, 64), "uint8"), ((B, 32), "uint8"), ((B, L), "uint8"),
 @pytest.mark.parametrize("fn,shapes", [
     (_sha512, [((B, L + 64), "uint8"), ((B,), "int32")]),
     (_verify, _SIG),
-], ids=["pallas_sha.sha512", "pallas_ed.verify_batch"])
+    # the live-lane count: scalar prefetch and the program guard
+    (_verify_counted, _SIG + [((), "int32")]),
+], ids=["pallas_sha.sha512", "pallas_ed.verify_batch",
+        "pallas_ed.verify_batch.n_live"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, fn, shapes):
     _compile(one_chip, fn, *shapes)
 
