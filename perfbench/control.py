@@ -4,7 +4,7 @@ guarantee of the configuration broken, judged by the same comparison
 as a run. It has to come out not correct.
 
     python3 perfbench/control.py --workload NAME --seed N --seconds S \\
-        --control first_sig_only|no_dedup
+        --control first_sig_only|no_dedup|no_fee
 
   first_sig_only  verifies only each txn's first signature (the lane a
                   later PR could be tempted to drop the rest of): breaks
@@ -12,6 +12,11 @@ as a run. It has to come out not correct.
   no_dedup        verifies every signature but delivers every frame,
                   resends included: breaks "each transaction is
                   delivered at most once" (dupflood only)
+  no_fee          commits every valid txn once, in the order of its
+                  first send, and executes them serially without
+                  charging the signature fee: breaks "balances after
+                  the run equal a serial execution of the committed
+                  txns" (a leader cell, which observes PoH entries)
 
 The corpus and schedule are the cell's own (its rate, its window), as a
 run builds them. No topology boots and no chip is touched; the driver's
@@ -51,7 +56,7 @@ def control_run(workload: str, seed: int, seconds: float, control: str,
 
     import numpy as np
 
-    from perfbench import harness, reference, traffic
+    from perfbench import harness, ledger, reference, traffic
     spec = harness.load_cell(workload)
     for k, v in (overrides or {}).items():
         spec[k] = v(spec[k])
@@ -71,10 +76,11 @@ def control_run(workload: str, seed: int, seconds: float, control: str,
             ok = np.array([v for c in pool.map(_first_sig_ok, chunks)
                            for v in c], bool)
         deliveries = ok.astype(np.int32)
-    elif control == "no_dedup":
+    elif control in ("no_dedup", "no_fee"):
         ok = reference.reference_verdicts(payloads, procs)
         frames = np.bincount(p.send_txn, minlength=n).astype(np.int32)
-        deliveries = np.where(ok, frames, 0)
+        deliveries = np.where(ok, frames if control == "no_dedup" else 1,
+                              0)
     else:
         raise ValueError(control)
     ref_idx = reference.sample(corpus.valid, offered, seed)
@@ -82,6 +88,21 @@ def control_run(workload: str, seed: int, seconds: float, control: str,
                                           procs)
     checks = reference.judge(corpus.valid, offered, deliveries, 0, 0,
                              ref_idx, ref_ok)
+    if control == "no_fee":
+        genesis = ledger.genesis(traffic.payers(mix, seed), n,
+                                 mix["lamports"][1])
+        first = np.full(n, np.inf)
+        np.minimum.at(first, p.send_txn, p.send_t)
+        committed = [payloads[i] for i in np.argsort(first, kind="stable")
+                     if ok[i]]
+        bal = dict(genesis)
+        ledger.execute(bal, committed, fee_per_signature=0)
+        read = ledger.sample_accounts(list(genesis),
+                                      np.flatnonzero(offered), corpus.txn,
+                                      seed)
+        served = {k: bal.get(k, 0) for k in read}
+        checks["state_mismatch"] = [
+            ledger.state_mismatch(genesis, committed, served), 0]
     return {"control": control, "correct": reference.correct(checks),
             "checks": checks}
 
@@ -92,7 +113,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--control", required=True,
-                    choices=("first_sig_only", "no_dedup"))
+                    choices=("first_sig_only", "no_dedup", "no_fee"))
     args = ap.parse_args(argv)
     out = control_run(args.workload, args.seed, args.seconds, args.control)
     for k, (v, lim) in out["checks"].items():

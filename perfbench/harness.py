@@ -1,14 +1,24 @@
 """One cell, one run: sign the corpus, boot the configuration's topology,
 inject the mix open loop from the scheduled send times, observe what
-dedup delivers, judge it against the reference, print one result line.
+the configuration's `observe` link delivers, judge it against the
+reference, print one result line.
 
 This process stands in for the upstream quic->verify producer: it
 publishes pre-built frames into the ingest ring (credit-gated on the
 verify tile's fseq, as the repo's ingest link is reliable) and reads
-the ring downstream of dedup (an external, unreliable observer). It
-never imports JAX: the verify tile's process owns the chip. The device
-block comes from that process: its boot line (platform, kind, chips),
-its `tpu_mem_bytes` gauge, and, with --trace 1, its profiler capture.
+the observed ring (an external, unreliable observer): dedup's output,
+or the PoH entries of a leader. It never imports JAX: the verify
+tile's process owns the chip. The device block comes from that
+process: its boot line (platform, kind, chips), its `tpu_mem_bytes`
+gauge, and, with --trace 1, its profiler capture.
+
+The kind of the tile that produces the observed link names the judge.
+Of dedup's output: every offered txn against its signatures
+(reference.py). Of a poh tile's entries: the same over the committed
+txns, the PoH record itself, and the balances the program serves
+against a plain serial executor (ledger.py); the payers are funded
+through the bank tile's genesis arg, and read back over its JSON-RPC
+port after the drain.
 
 Everything cell-specific is found by name: the workload entry of
 BENCHMARK.json names its config (configs/<name>.json, a self-contained
@@ -168,7 +178,7 @@ def boot_line(log_path: str, tile: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the open-loop injector and the observer downstream of dedup
+# the open-loop injector and the observer of the observed link
 # ---------------------------------------------------------------------------
 
 class Injector:
@@ -206,16 +216,24 @@ class Injector:
 
 
 class Observer:
-    """The consumer downstream of dedup, in a process of its own (so
-    neither it nor the injector starves the other): it gathers what
-    dedup delivers, matches each frame to its txn by the first 8 bytes
+    """The consumer of the observed link, in a process of its own (so
+    neither it nor the injector starves the other): it gathers what the
+    link delivers, matches each txn to the corpus by the first 8 bytes
     of its first signature, checks the payload's checksum, and writes
     the first delivery time and the delivery count of each txn into
-    shared memory that this process reads."""
+    shared memory that this process reads.
 
-    STOP, SEEN, ALTERED, UNKNOWN, OVERRUN, READY = range(6)
+    With `entries` a frame is a PoH entry (ENTRY_HDR bytes of header
+    ending in a u16 txn count, then u16-framed txns): the observer joins
+    the ring at its head (PoH ticks from boot on), walks each entry's
+    txns, and also keeps the order of each txn's first delivery (the
+    commit order on the one chain) and every entry's header with the
+    time it came, for the ledger judge."""
 
-    def __init__(self, plan: dict, link: str, corpus):
+    STOP, SEEN, ALTERED, UNKNOWN, OVERRUN, READY, ENTRIES = range(7)
+
+    def __init__(self, plan: dict, link: str, corpus,
+                 entries: bool = False):
         import multiprocessing as mp
         from multiprocessing import shared_memory
         n = len(corpus.size)
@@ -225,11 +243,19 @@ class Observer:
         self.first_t[:] = np.nan
         self.count[:] = 0
         self.stats[:] = 0
+        self.ext = None
+        if entries:
+            self.ext = shared_memory.SharedMemory(
+                create=True, size=_entry_views_size(n))
+            self.first_seq, self.hdr, self.hdr_t = _entry_views(
+                self.ext.buf, n)
+            self.first_seq[:] = -1
         order = np.argsort(corpus.key)
         self.proc = mp.get_context("spawn").Process(
             target=_observe, name="perfbench-observer", daemon=True,
             args=(self.shm.name, n, plan, link, corpus.key[order], order,
-                  corpus.size, corpus.check))
+                  corpus.size, corpus.check,
+                  self.ext.name if entries else None))
         self.proc.start()
         while not self.stats[self.READY]:
             if not self.proc.is_alive():
@@ -252,6 +278,19 @@ class Observer:
         self.stats = self.stats.copy()
         self.shm.close()
         self.shm.unlink()
+        if self.ext is not None:
+            m = self.stat(self.ENTRIES)
+            self.first_seq = self.first_seq.copy()
+            self.hdr, self.hdr_t = self.hdr[:m].copy(), self.hdr_t[:m].copy()
+            self.ext.close()
+            self.ext.unlink()
+
+
+# PoH entry frag: u64 slot | u32 tick | u32 num_hashes | u8 has_mixin |
+# prev 32 | hash 32 | mixin 32 | u8 flags | u16 txn_cnt, then txn_cnt x
+# (u16 len | payload) (the program's poh tile, as its docstring states)
+ENTRY_HDR = 116
+ENTRY_CAP = 1 << 18               # entry headers kept in one run
 
 
 def _views(buf, n: int):
@@ -262,7 +301,48 @@ def _views(buf, n: int):
     return first_t, count, stats
 
 
-def _observe(shm_name, n, plan, link, keys, order, sizes, checks):
+def _entry_views_size(n: int) -> int:
+    return 8 * n + 8 * ENTRY_CAP + ENTRY_HDR * ENTRY_CAP
+
+
+def _entry_views(buf, n: int):
+    first_seq = np.ndarray((n,), np.int64, buffer=buf, offset=0)
+    hdr_t = np.ndarray((ENTRY_CAP,), np.float64, buffer=buf, offset=8 * n)
+    hdr = np.ndarray((ENTRY_CAP, ENTRY_HDR), np.uint8, buffer=buf,
+                     offset=8 * n + 8 * ENTRY_CAP)
+    return first_seq, hdr, hdr_t
+
+
+def entry_txns(buf, szs, k: int, mtu: int):
+    """The txns inside k gathered PoH entry frames -> (payloads zero
+    padded to `mtu`, (m, mtu) uint8; their sizes; frames malformed).
+    A txn that would run past its frame ends the walk of that frame,
+    which then counts as malformed."""
+    rows, offs, lens, bad = [], [], [], 0
+    for i in range(k):
+        sz = int(szs[i])
+        if sz < ENTRY_HDR:
+            bad += 1
+            continue
+        f = buf[i]
+        off = ENTRY_HDR
+        for _ in range(int(f[ENTRY_HDR - 2]) | int(f[ENTRY_HDR - 1]) << 8):
+            ln = int(f[off]) | int(f[off + 1]) << 8 if off + 2 <= sz else -1
+            if ln < 0 or off + 2 + ln > sz or ln > mtu:
+                bad += 1
+                break
+            rows.append(i)
+            offs.append(off + 2)
+            lens.append(ln)
+            off += 2 + ln
+    out = np.zeros((len(rows), mtu), np.uint8)
+    for j, (i, o, ln) in enumerate(zip(rows, offs, lens)):
+        out[j, :ln] = buf[i, o:o + ln]
+    return out, np.array(lens, np.uint32), bad
+
+
+def _observe(shm_name, n, plan, link, keys, order, sizes, checks,
+             ext_name=None):
     """The observer process's loop (see Observer)."""
     from multiprocessing import shared_memory
 
@@ -278,15 +358,20 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks):
     wksp = Workspace(plan["wksp"]["name"], plan["wksp"]["size"],
                      create=False)
     ring = _ring(wksp, plan, link)
-    n_max = 4096
-    buf = np.zeros((n_max, MTU), np.uint8)
+    if ext_name is None:
+        n_max, width, seq0 = 4096, MTU, 0
+    else:
+        ext = shared_memory.SharedMemory(name=ext_name)
+        first_seq, hdr, hdr_t = _entry_views(ext.buf, n)
+        n_max, width, seq0 = 256, plan["links"][link]["mtu"], ring.seq
+    buf = np.zeros((n_max, width), np.uint8)
     words = buf.view(np.uint64)
     szs = np.zeros(n_max, np.uint32)
     sigs = np.zeros(n_max, np.uint64)
-    seq, ovr = ct.c_uint64(0), ct.c_uint64(0)
+    seq, ovr = ct.c_uint64(seq0), ct.c_uint64(0)
     u8p = ct.POINTER(ct.c_uint8)
     args = (wksp.base, ring.off, ct.byref(seq), n_max,
-            buf.ctypes.data_as(u8p), MTU,
+            buf.ctypes.data_as(u8p), width,
             szs.ctypes.data_as(ct.POINTER(ct.c_uint32)),
             sigs.ctypes.data_as(ct.POINTER(ct.c_uint64)), ct.byref(ovr),
             None)
@@ -305,6 +390,11 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks):
                 continue
             idle = 0
             now = time.monotonic()
+            if ext_name is not None:
+                _observe_entries(buf, szs, k, now, keys, order, sizes,
+                                 checks, first_t, count, stats, first_seq,
+                                 hdr, hdr_t)
+                continue
             key = buf[:k, 1:9].copy().view(np.uint64).ravel()
             pos = np.minimum(np.searchsorted(keys, key), len(keys) - 1)
             hit = keys[pos] == key
@@ -323,6 +413,45 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks):
         del first_t, count, stats
         wksp.close()
         shm.close()
+        if ext_name is not None:
+            del first_seq, hdr, hdr_t
+            ext.close()
+
+
+def _observe_entries(buf, szs, k, now, keys, order, sizes, checks,
+                     first_t, count, stats, first_seq, hdr, hdr_t):
+    """One gather of PoH entries: keep their headers, then match the
+    txns inside them as the txn path matches frames, numbering each
+    txn's first delivery in the order delivered (numbers rise; they
+    need not be consecutive)."""
+    from .traffic import MTU, WORD_WEIGHTS
+    m = int(stats[Observer.ENTRIES])
+    if m + k > ENTRY_CAP:
+        stats[Observer.OVERRUN] += k        # a header lost: judge nothing
+    else:
+        hdr[m:m + k] = buf[:k, :ENTRY_HDR]
+        hdr_t[m:m + k] = now
+        stats[Observer.ENTRIES] = m + k
+    txns, tsz, bad = entry_txns(buf, szs, k, MTU)
+    stats[Observer.UNKNOWN] += bad
+    if not len(tsz):
+        return
+    key = txns[:, 1:9].copy().view(np.uint64).ravel()
+    pos = np.minimum(np.searchsorted(keys, key), len(keys) - 1)
+    hit = keys[pos] == key
+    idx = order[pos[hit]]
+    same = tsz[hit] == sizes[idx]
+    same &= (txns.view(np.uint64)[hit] * WORD_WEIGHTS).sum(
+        axis=1, dtype=np.uint64) == checks[idx]
+    np.add.at(count, idx, 1)
+    fresh = idx[np.isnan(first_t[idx])]
+    _, at = np.unique(fresh, return_index=True)
+    fresh = fresh[np.sort(at)]                  # in order of delivery
+    first_t[fresh] = now
+    first_seq[fresh] = stats[Observer.SEEN] + np.arange(len(fresh))
+    stats[Observer.SEEN] += len(tsz)
+    stats[Observer.UNKNOWN] += int((~hit).sum())
+    stats[Observer.ALTERED] += int((~same).sum())
 
 
 # ---------------------------------------------------------------------------
@@ -389,6 +518,11 @@ class Run:
             return None
         return 100.0 * d["hist"]["work"][1] / ((self.t1 - self.t0) * 1e9)
 
+    def tile(self, kind: str):
+        """The name of the config's first tile of this kind, or None."""
+        return next((t["name"] for t in self.cfg["tiles"]
+                     if t["kind"] == kind), None)
+
     def idle_pct(self):
         """Device idle share of the capture, in %; None untraced."""
         if self.trace is None:
@@ -418,6 +552,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              rate: float | None = None) -> dict:
     """One run of one cell; returns the result object (the last line).
     Raises BenchError where no result may be printed."""
+    from . import ledger
     from . import reference as ref
     from . import traffic
 
@@ -446,6 +581,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if tc["depth"] < n_txn:
             raise BenchError(f"tcache {tc['name']} depth {tc['depth']} < "
                              f"{n_txn} unique txns offered")
+    # a leader's record: PoH entries, judged with the bank's state
+    producer = next(t for t in cfg["tiles"]
+                    if cfg["observe"] in t.get("outs", ()))
+    leader = producer["kind"] == "poh"
+    genesis = bank = None
+    if leader:
+        # every payer funded through the bank tile's genesis arg
+        genesis = ledger.genesis(traffic.payers(mix, seed), n_txn,
+                                 mix["lamports"][1])
+        cfg = json.loads(json.dumps(cfg))
+        bank = next(t for t in cfg["tiles"] if t["kind"] == "bank")
+        bank["args"]["genesis"] = {k.hex(): v for k, v in genesis.items()}
     say(f"corpus: {n_txn} txns, {len(p.send_t)} frames, signed in "
         f"{time.monotonic() - t_start:.1f} s since start")
 
@@ -470,7 +617,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                  plan["fseqs"].items()
                  if key.split(":", 1)[0] == cfg["ingest"]]
         inj = Injector(ingest, fseqs, corpus, p.send_txn)
-        obs = Observer(plan, cfg["observe"], corpus)
+        obs = Observer(plan, cfg["observe"], corpus, leader)
         watch = [t["name"] for t in cfg["tiles"]]
         mem_peak = [0]
 
@@ -556,6 +703,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 man = load_json(cap_manifest)
         housekeep()
         r.memory_peak_bytes = mem_peak[0]
+        served = None
+        if leader:
+            # the balances the program serves, after the drain
+            t_rpc = time.monotonic()
+            read = ledger.sample_accounts(
+                list(genesis), np.unique(p.send_txn[:sent_hi]),
+                corpus.txn, seed)
+            port = int(runner.metrics(bank["name"])["rpc_port"])
+            served = ledger.read_balances(port, read)
+            say(f"state: {len(served)} balances read in "
+                f"{time.monotonic() - t_rpc:.1f} s")
     finally:
         gc.enable()
         if obs is not None:
@@ -568,7 +726,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             except OSError:
                 pass
     if obs.stat(Observer.OVERRUN):
-        raise BenchError(f"the observer fell behind dedup: "
+        raise BenchError(f"the observer fell behind {cfg['observe']}: "
                          f"{obs.stat(Observer.OVERRUN)} frames overrun")
 
     # -- what the metric readers see ----------------------------------
@@ -615,6 +773,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                        obs.stat(Observer.UNKNOWN), ref_idx, ref_ok)
     say(f"reference: {len(ref_idx)} txns re-verified in "
         f"{time.monotonic() - t_ref:.1f} s")
+    if leader:
+        t_ref = time.monotonic()
+        seen = np.flatnonzero(obs.count > 0)
+        committed = seen[np.argsort(obs.first_seq[seen], kind="stable")]
+        checks["state_mismatch"] = [ledger.state_mismatch(
+            genesis, [corpus.txn(int(i)) for i in committed], served), 0]
+        poh = producer["args"]
+        checks["poh_mismatch"] = [ledger.poh_mismatch(
+            obs.hdr, int(poh["hashes_per_tick"]),
+            int(poh["ticks_per_slot"]), seed), 0]
+        checks["ticks_early"] = [ledger.ticks_early(
+            obs.hdr, obs.hdr_t, int(poh["lazy_ns"]) * 1e-9,
+            int(poh["ticks_per_slot"])), 0]
+        say(f"ledger: {len(committed)} committed txns executed, "
+            f"{len(served)} balances compared, {len(obs.hdr)} PoH entries "
+            f"checked in {time.monotonic() - t_ref:.1f} s")
 
     say("window: " + json.dumps(diagnostics(r, rate)))
     kind = "per_layer" if trace else "end_to_end"
@@ -679,16 +853,26 @@ def diagnostics(r, rate: float) -> dict:
         "valid_in_window": int(sel.sum()),
         "delivered_share_in_window": float(
             ((r.first_t[sel] <= r.t1)).mean()) if sel.any() else None,
+        "delivered_share_1s_after": float(
+            ((r.first_t[sel] <= r.t1 + 1.0)).mean()) if sel.any() else None,
         "p50_ms": q(50), "p90_ms": q(90), "p99_ms": q(99),
         "backlog_frames": r.backlog[-8:],
         "injector_credit_stalls": r.stalls,
-        "verify_busy_pct": r.busy_pct("verify"),
-        "dedup_busy_pct": r.busy_pct("dedup"),
         "verify_batches": r.delta.get("verify", {}).get("metrics", {})
         .get("batches"),
+        "verify_counters": {k: v for k, v in r.delta.get("verify", {})
+                            .get("metrics", {}).items()
+                            if k in ("deferred_parked",
+                                     "deferred_host_verifies",
+                                     "verify_fail", "dedup_drop")},
         "late_p99_ms": float(np.nanpercentile(r.late_s, 99)) * 1e3
         if len(r.late_s) and not np.isnan(r.late_s).all() else None,
         "memory_peak_bytes": r.memory_peak_bytes,
+        "busy_pct": {t: r.busy_pct(t) for t in r.delta},
+        "pending_growth": {t: d["metrics"]["inserted"]
+                           - d["metrics"]["scheduled"]
+                           for t, d in r.delta.items()
+                           if "scheduled" in d["metrics"]},
     }
 
 

@@ -6,7 +6,11 @@ multiset of signature counts and sizes, the forged share and the
 inter-arrival gaps are fixed by the mix, the rate and the window; the
 seed permutes them and draws the keys, blockhashes, filler and which
 signature of a forged txn is bad. So two seeds differ in order, not in
-load.
+load. In a mix that resends, a txn's copies race its first verdict, and
+the racing copies of a forged txn are re-verified one signature at a
+time: there each txn's (signature count, size, forged, bad signature,
+resend delays) is one tuple fixed for every seed, and the seed permutes
+the tuples.
 
 Wire format (Solana legacy txn, built here and not by the program):
 compact-u16 sig count | sigs | header (k, 0, 1) | compact-u16 (k+1) |
@@ -14,11 +18,29 @@ k signer keys + program id | blockhash | one instruction
 (prog_idx k, accounts [0], data filler). Size = 74 + 96k + cu16(d) + d.
 
 A mix file (traffic/<name>.json) holds:
-  sig_shares    [[k, share], [[lo, hi], share], ...] (a range is uniform)
-  size          {median, sigma, min, max} log-normal target bytes
+  kind          "memo" (the default: the txns above) or "transfer"
+  sig_shares    [[k, share], [[lo, hi], share], ...] (a range is uniform;
+                memo only: a transfer has one signer)
+  size          {median, sigma, min, max} log-normal target bytes (memo
+                only: a transfer is TRANSFER_SIZE bytes)
   forged_share  share of txns with one signature bit-flipped
   resend        {copies, delay_s: [lo, hi]} extra sends of each txn
-  signer_pool   distinct signing keys
+  signer_pool   distinct signing keys (a transfer's payer is one of
+                them, drawn uniformly)
+  destinations  transfer only: {"rule": "fresh"} (a new account per
+                txn) or {"rule": "zipf", "count": N, "exponent": s}
+                (account of rank r drawn with weight r**-s, r = 1..N)
+  lamports      transfer only: [lo, hi], the amount drawn uniformly
+  compute_budget  transfer only: {"cu_limit": c, "loaded_data_bytes": b},
+                the txn's SetComputeUnitLimit and
+                SetLoadedAccountsDataSizeLimit requests
+
+A transfer is a single-signer legacy system-program transfer: accounts
+(payer, destination, System program, ComputeBudget program), header
+(1, 0, 2), one of RECENT_BLOCKHASHES seeded blockhashes, then three
+instructions: the two compute-budget requests (prog_idx 3, no accounts,
+data u8 2 | u32 c and u8 4 | u32 b) and the transfer (prog_idx 2,
+accounts [0, 1], data u32 2 | u64 lamports). TRANSFER_SIZE bytes.
 """
 from __future__ import annotations
 
@@ -33,15 +55,39 @@ MTU = 1232
 SIG_MAX = 12
 CHUNK = 4096                      # txns per signing task (seeded per chunk)
 PROGRAM_ID = hashlib.sha256(b"perfbench-memo-program").digest()
+SYSTEM_PROGRAM_ID = bytes(32)
+COMPUTE_BUDGET_PROGRAM_ID = bytes.fromhex(
+    "0306466fe5211732ffecadba72c39be7bc8ce5bbc5f7126b2c439b3a40000000")
+TRANSFER_SIZE = 263
+RECENT_BLOCKHASHES = 150          # a blockhash is valid for 150 slots
+MIX_KEYS = {
+    "memo": ("sig_shares", "size", "forged_share", "resend",
+             "signer_pool"),
+    "transfer": ("forged_share", "resend", "signer_pool", "destinations",
+                 "lamports", "compute_budget"),
+}
 
 
 def load_mix(path: str) -> dict:
     with open(path) as f:
         mix = json.load(f)
-    for k in ("sig_shares", "size", "forged_share", "resend",
-              "signer_pool"):
+    kind = mix.get("kind", "memo")
+    if kind not in MIX_KEYS:
+        raise ValueError(f"{path}: no txn kind {kind!r}")
+    for k in MIX_KEYS[kind]:
         if k not in mix:
             raise ValueError(f"{path}: mix lacks {k!r}")
+    if kind == "transfer":
+        rule = mix["destinations"].get("rule")
+        if rule not in ("fresh", "zipf"):
+            raise ValueError(f"{path}: no destination rule {rule!r}")
+        lo, hi = mix["lamports"]
+        if not 0 < lo <= hi < 1 << 63:
+            raise ValueError(f"{path}: lamports {mix['lamports']}")
+        cb = mix["compute_budget"]
+        if not (0 < cb["cu_limit"] < 1 << 32 and
+                0 < cb["loaded_data_bytes"] < 1 << 32):
+            raise ValueError(f"{path}: compute_budget {cb}")
     return mix
 
 
@@ -95,6 +141,8 @@ class Plan:
     forged: np.ndarray            # (n,) bool
     send_t: np.ndarray            # (frames,) float64 s from window start
     send_txn: np.ndarray          # (frames,) int64 txn index
+    bad_sig: np.ndarray | None = None   # (n,) int8 forged sig index, -1
+    #                               valid; None: drawn at signing
 
 
 def plan(mix: dict, seed: int, rate: float, seconds: float) -> Plan:
@@ -104,20 +152,35 @@ def plan(mix: dict, seed: int, rate: float, seconds: float) -> Plan:
     copies = 1 + int(mix["resend"].get("copies", 0))
     frames = int(round(rate * seconds))
     n = max(1, frames // copies)
-    # (sig count, size) pairs are one fixed multiset for every seed,
-    # paired by a seed-independent shuffle; the seed permutes the pairs
-    k = _fixed_multiset(mix["sig_shares"], n)
-    sz = mix["size"]
-    size = np.random.default_rng(0x73697a65).permutation(
-        _quantiles_lognormal(n, sz["median"], sz["sigma"]))
-    size = np.clip(np.rint(size), sz["min"], sz["max"])
-    base = np.array([base_size(int(x)) for x in range(SIG_MAX + 1)])
-    size = np.minimum(np.maximum(size, base[k]), MTU).astype(np.int32)
+    if mix.get("kind", "memo") == "transfer":
+        k = np.ones(n, np.int8)
+        size = np.full(n, TRANSFER_SIZE, np.int32)
+    else:
+        # (sig count, size) pairs are one fixed multiset for every
+        # seed, paired by a seed-independent shuffle; the seed permutes
+        # the pairs
+        k = _fixed_multiset(mix["sig_shares"], n)
+        sz = mix["size"]
+        size = np.random.default_rng(0x73697a65).permutation(
+            _quantiles_lognormal(n, sz["median"], sz["sigma"]))
+        size = np.clip(np.rint(size), sz["min"], sz["max"])
+        base = np.array([base_size(int(x)) for x in range(SIG_MAX + 1)])
+        size = np.minimum(np.maximum(size, base[k]), MTU).astype(np.int32)
     order = rng.permutation(n)
-    k, size = k[order], size[order]
+    n_forged = int(round(mix["forged_share"] * n))
     forged = np.zeros(n, bool)
-    forged[rng.permutation(n)[:int(round(mix["forged_share"] * n))]] = \
-        True
+    bad = delays = None
+    if copies > 1:
+        fixed = np.random.default_rng(0x72657365)
+        forged[fixed.permutation(n)[:n_forged]] = True
+        bad = np.where(forged, fixed.integers(0, SIG_MAX, n)
+                       % np.maximum(k, 1), -1).astype(np.int8)[order]
+        lo, hi = mix["resend"]["delay_s"]
+        delays = fixed.uniform(lo, hi, (copies - 1, n))[:, order]
+        forged = forged[order]
+    else:
+        forged[rng.permutation(n)[:n_forged]] = True
+    k, size = k[order], size[order]
     # open loop: exponential gaps of a fixed multiset (quantiles),
     # permuted; scaled so the n-th first send lands inside the window
     gaps = -np.log1p(-(np.arange(n) + 0.5) / n)
@@ -125,14 +188,12 @@ def plan(mix: dict, seed: int, rate: float, seconds: float) -> Plan:
     first = np.cumsum(gaps) / gaps.sum() * seconds * (n - 0.5) / n
     t = [first]
     txn = [np.arange(n)]
-    if copies > 1:
-        lo, hi = mix["resend"]["delay_s"]
-        for _ in range(copies - 1):
-            t.append(first + rng.uniform(lo, hi, n))
-            txn.append(np.arange(n))
+    for d in delays if delays is not None else ():
+        t.append(first + d)
+        txn.append(np.arange(n))
     t, txn = np.concatenate(t), np.concatenate(txn)
     order = np.argsort(t, kind="stable")
-    return Plan(k, size, forged, t[order], txn[order])
+    return Plan(k, size, forged, t[order], txn[order], bad)
 
 
 def _keys(seed: int, pool: int):
@@ -150,11 +211,13 @@ _KEYS: dict = {}
 def _sign_chunk(args):
     """Build + sign one chunk of a plan's txns (runs in a pool worker);
     every draw is made for the whole chunk at once."""
-    seed, pool, lo, k, size, forged = args
+    seed, pool, lo, k, size, forged, bad_sig, transfer = args
     if (seed, pool) not in _KEYS:
         _KEYS.clear()
         _KEYS[(seed, pool)] = _keys(seed, pool)
     keys, pubs = _KEYS[(seed, pool)]
+    if transfer is not None:
+        return _sign_transfers(seed, keys, pubs, lo, forged, transfer)
     rng = np.random.default_rng([seed & 0xFFFFFFFFFFFFFFFF, 0x6368,
                                  lo // CHUNK])
     n = len(k)
@@ -165,6 +228,8 @@ def _sign_chunk(args):
     fill = rng.bytes(int(d.sum()))
     fo = np.concatenate([[0], np.cumsum(d)])
     fj = rng.integers(0, SIG_MAX, n) % np.maximum(k, 1)
+    if bad_sig is not None:
+        fj = bad_sig
     fbit = rng.integers(0, 512, n)
     out, bad = [], []
     for i in range(n):
@@ -187,6 +252,66 @@ def _sign_chunk(args):
         out.append(b"".join((_cu16(ki), *sigs, msg)))
         bad.append(j)
     return out, bad, checksums(out)
+
+
+def _zipf_cdf(count: int, exponent: float) -> np.ndarray:
+    w = np.arange(1, count + 1, dtype=np.float64) ** -float(exponent)
+    return np.cumsum(w) / w.sum()
+
+
+def account_key(seed: int, label: bytes, i: int) -> bytes:
+    """A seeded 32-byte account address (no private key behind it)."""
+    return hashlib.sha256(b"perfbench-%s-%d-%d" % (label, seed, i)).digest()
+
+
+def _sign_transfers(seed, keys, pubs, lo, forged, spec):
+    """Build + sign one chunk of transfers: payer uniform over the
+    signer pool, destination by the mix's rule, amount uniform in the
+    mix's lamports, one of RECENT_BLOCKHASHES seeded blockhashes, after
+    the mix's compute-budget requests."""
+    dests, (lam_lo, lam_hi), budget = spec
+    n = len(forged)
+    rng = np.random.default_rng([seed & 0xFFFFFFFFFFFFFFFF, 0x7478,
+                                 lo // CHUNK])
+    payer = rng.integers(0, len(keys), n)
+    amount = rng.integers(lam_lo, lam_hi + 1, n, dtype=np.uint64)
+    bh = rng.integers(0, RECENT_BLOCKHASHES, n)
+    fbit = rng.integers(0, 512, n)
+    if dests["rule"] == "zipf":
+        cdf = _zipf_cdf(int(dests["count"]), dests["exponent"])
+        dst = [account_key(seed, b"acct", int(r)) for r in np.minimum(
+            np.searchsorted(cdf, rng.random(n), "right"), len(cdf) - 1)]
+    else:
+        dst = [account_key(seed, b"dst", lo + i) for i in range(n)]
+    hashes = [account_key(seed, b"blockhash", j)
+              for j in range(RECENT_BLOCKHASHES)]
+    cu = int(budget["cu_limit"]).to_bytes(4, "little")
+    ld = int(budget["loaded_data_bytes"]).to_bytes(4, "little")
+    ixs = b"\x03\x03\x00\x05\x02" + cu + b"\x03\x00\x05\x04" + ld
+    out, bad = [], []
+    for i in range(n):
+        s = int(payer[i])
+        msg = b"".join((b"\x01\x00\x02\x04", pubs[s], dst[i],
+                        SYSTEM_PROGRAM_ID, COMPUTE_BUDGET_PROGRAM_ID,
+                        hashes[bh[i]], ixs,
+                        b"\x02\x02\x00\x01\x0c\x02\x00\x00\x00",
+                        int(amount[i]).to_bytes(8, "little")))
+        sig = keys[s].sign(msg)
+        j = -1
+        if forged[i]:
+            j, bit = 0, int(fbit[i])
+            sg = bytearray(sig)
+            sg[bit // 8] ^= 1 << (bit % 8)
+            sig = bytes(sg)
+        out.append(b"\x01" + sig + msg)
+        bad.append(j)
+    return out, bad, checksums(out)
+
+
+def payers(mix: dict, seed: int) -> list:
+    """The signer pool's public keys, in pool order: the payers a
+    transfer mix draws from (genesis funds each of them)."""
+    return _keys(seed, int(mix["signer_pool"]))[1]
 
 
 WORD_WEIGHTS = np.random.default_rng(0x5EED).integers(
@@ -223,8 +348,13 @@ def sign(mix: dict, seed: int, p: Plan, procs: int) -> Corpus:
     """Sign every txn of a plan in a spawn pool of `procs` workers (the
     workers import only this module and OpenSSL)."""
     n = len(p.sig_cnt)
+    transfer = (mix["destinations"], tuple(mix["lamports"]),
+                mix["compute_budget"]) \
+        if mix.get("kind", "memo") == "transfer" else None
     tasks = [(seed, int(mix["signer_pool"]), lo, p.sig_cnt[lo:lo + CHUNK],
-              p.size[lo:lo + CHUNK], p.forged[lo:lo + CHUNK])
+              p.size[lo:lo + CHUNK], p.forged[lo:lo + CHUNK],
+              None if p.bad_sig is None else p.bad_sig[lo:lo + CHUNK],
+              transfer)
              for lo in range(0, n, CHUNK)]
     if procs > 1 and len(tasks) > 1:
         import multiprocessing as mp

@@ -15,6 +15,17 @@ fault is planted in the program inside them, where it runs:
                    (half of the batch left out of the check)
   state_unchanged  the ha-dedup and dedup tcaches never record a tag (a
                    step that returns its state unchanged)
+  bank_unchanged   the bank tile cancels each executed fork instead of
+                   publishing it (its state returned unchanged)
+  bank_half        the bank tile executes every other transfer of a wave
+                   and records them all (half of the batch left out)
+  bank_amount      the bank tile moves one lamport more than each
+                   transfer says (an answer altered where it is made)
+  poh_short        the poh tile's ticks hash half their count and record
+                   the whole (an answer altered where it is made)
+  poh_fast         the poh tile makes four ticks in each housekeeping
+                   pass, of 256 hashes each (set in the config for this
+                   fault alone, so they can outrun the wall clock)
 
 Prints the run's result line last, as run.py does.
 """
@@ -50,6 +61,38 @@ def plant():
         def no_insert(self, tags, mask=None):
             return np.zeros(len(tags), np.uint8)
         Tcache.insert_batch = no_insert
+    elif FAULT == "bank_unchanged":
+        from firedancer_tpu.funk.funk import Funk
+        Funk.txn_publish = Funk.txn_cancel
+    elif FAULT in ("bank_half", "bank_amount"):
+        import dataclasses
+
+        from firedancer_tpu.disco.tiles import BankAdapter
+        real_parse = BankAdapter._parse_transfers
+        seen = [0]                 # transfers parsed so far, tile-wide
+
+        def parse(self, frame, txn_cnt):
+            txns, mixin = real_parse(self, frame, txn_cnt)
+            if FAULT == "bank_half":
+                keep = [t for j, t in enumerate(txns, seen[0]) if j % 2]
+                seen[0] += len(txns)
+                return keep, mixin
+            return [dataclasses.replace(t, amount=t.amount + 1)
+                    for t in txns], mixin
+        BankAdapter._parse_transfers = parse
+    elif FAULT == "poh_short":
+        from firedancer_tpu.ops import poh
+        real_append = poh.host_poh_append
+        poh.host_poh_append = lambda state, n: real_append(state, n // 2)
+    elif FAULT == "poh_fast":
+        from firedancer_tpu.disco.tiles import PohAdapter
+        real_hk = PohAdapter.housekeeping
+
+        def housekeeping(self):
+            for _ in range(3):
+                self._tick()
+            real_hk(self)
+        PohAdapter.housekeeping = housekeeping
     elif FAULT != "none":
         raise ValueError(FAULT)
 
@@ -58,22 +101,29 @@ def small(cfg):
     cfg = json.loads(json.dumps(cfg))
     cfg["wksp_size"] = 1 << 26
     for ln in cfg["links"]:
-        ln["depth"] = 1024
+        ln["depth"] = 1024 if ln["mtu"] <= 1280 else min(ln["depth"], 256)
     for tc in cfg["tcaches"]:
         tc["depth"] = 1 << 14
     for t in cfg["tiles"]:
         if t["name"] == cfg["device_tile"]:
             t["args"]["batch"] = 64
+        if t["kind"] == "poh" and FAULT == "poh_fast":
+            t["args"]["hashes_per_tick"] = 256
     return cfg
 
 
 def main(workload: str) -> int:
     from perfbench import harness
     harness.PLATFORM = "cpu"
-    harness.DRAIN_S = harness.WARM_DRAIN_S = 3.0  # a fault's txns never come
+    # a drain ends once every valid txn came; only a fault that loses
+    # valid txns waits its whole limit, so only that one keeps it short.
+    # The warm-up's drain outlasts the CPU compiles, so the window sees
+    # the pipeline running.
+    harness.DRAIN_S = 3.0 if FAULT == "altered_verdict" else 60.0
+    harness.WARM_DRAIN_S = harness.DRAIN_S
     mix = {"none": {}, "altered_verdict": {},
            "half_unverified": {"forged_share": 0.3},
-           "state_unchanged": {}}[FAULT]
+           "state_unchanged": {}}.get(FAULT, {})
     out = harness.run_cell(
         workload, 2 ** 40 + 11, 2.0, False, T_START,
         overrides={"config": small,

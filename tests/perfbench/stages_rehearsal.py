@@ -22,7 +22,7 @@ def small(cfg: dict) -> dict:
     cfg = json.loads(json.dumps(cfg))
     cfg["wksp_size"] = 1 << 26
     for ln in cfg["links"]:
-        ln["depth"] = 1024
+        ln["depth"] = 1024 if ln["mtu"] <= 1280 else min(ln["depth"], 256)
     for tc in cfg["tcaches"]:
         tc["depth"] = 1 << 14
     for t in cfg["tiles"]:
@@ -34,7 +34,8 @@ def small(cfg: dict) -> dict:
 def main(workload: str) -> int:
     from perfbench import harness, stages_run
     harness.PLATFORM = "cpu"
-    harness.DRAIN_S = harness.WARM_DRAIN_S = 3.0
+    harness.WARM_DRAIN_S = 3.0
+    harness.DRAIN_S = 60.0      # ends once every valid txn came
     run_cell = harness.run_cell
 
     def small_cell(w, seed, seconds, trace, t0, rate=None):

@@ -79,3 +79,47 @@ def test_reference_agrees_with_labels():
                                        procs=1)
     assert np.array_equal(got, c.valid)
     assert (~got).sum() == 12
+
+
+@pytest.mark.parametrize("name,digest", [
+    ("mainnet",
+     "6ead0b343d3ca48b311abef34b8a7ab697c4981e529c0ce2b6502e3d455b382d"),
+    ("dupflood",
+     "fdbdbefaf259766ed0f63c15fa00e2cf85ed702a377a7c25eb5ab7181079518b"),
+])
+def test_frontdoor_corpus_is_pinned(name, digest):
+    """The frontdoor mixes build the same bytes, labels, schedule and
+    checksums for a seed as they did before the generator learned
+    transfers (mainnet's digest taken from that generator; dupflood's
+    from the one that fixed each resent txn's tuple for every seed)."""
+    import hashlib
+    p, c = _corpus(name, SEED, rate=3000)
+    h = hashlib.sha256()
+    for a in (c.flat, c.valid, p.send_t, p.send_txn, c.check):
+        h.update(a.tobytes())
+    assert h.hexdigest() == digest
+
+
+def test_resent_txn_tuples_are_the_same_for_every_seed():
+    """In a mix that resends, each txn's signature count, size, forged
+    flag, bad signature and resend delays form one tuple that every seed
+    shares (in another order): so the copies that race a forged txn's
+    verdict, and the signatures their re-verify walks, are the same work
+    for every seed."""
+    mix = _mix("dupflood")
+
+    def tuples(seed):
+        p = traffic.plan(mix, seed, 4000, 1.0)
+        n = len(p.sig_cnt)
+        first = np.full(n, np.inf)
+        np.minimum.at(first, p.send_txn, p.send_t)
+        sends = [[] for _ in range(n)]
+        for t, i in zip(p.send_t, p.send_txn):
+            sends[i].append(round(float(t - first[i]), 9))
+        return sorted((int(p.sig_cnt[i]), int(p.size[i]), bool(p.forged[i]),
+                       int(p.bad_sig[i]), tuple(sorted(sends[i])))
+                      for i in range(n))
+    a, b = tuples(SEED), tuples(SEED + 1)
+    assert a == b
+    assert sum(f for _, _, f, _, _ in a) == 20          # 2% of 1,000
+    assert all((j >= 0) == f and j < k for k, _, f, j, _ in a)
