@@ -15,10 +15,12 @@ gauge, and, with --trace 1, its profiler capture.
 The kind of the tile that produces the observed link names the judge.
 Of dedup's output: every offered txn against its signatures
 (reference.py). Of a poh tile's entries: the same over the committed
-txns, the PoH record itself, and the balances the program serves
-against a plain serial executor (ledger.py); the payers are funded
-through the bank tile's genesis arg, and read back over its JSON-RPC
-port after the drain.
+txns, the PoH record itself, the block rules a replaying validator
+holds each entry and slot to (blockrules.py, with the config's
+block_limits), and the balances the program serves against a plain
+serial executor (ledger.py); the payers are funded through the bank
+tile's genesis arg, and read back over its JSON-RPC port after the
+drain.
 
 Everything cell-specific is found by name: the workload entry of
 BENCHMARK.json names its config (configs/<name>.json, a self-contained
@@ -76,11 +78,13 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     w = cells[workload]
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     cfg = load_json(os.path.join(root, conf["file"]))
-    mix_path = os.path.join(HERE, "traffic", w["traffic"] + ".json")
+    mix_path = os.path.join(root, "perfbench", "traffic",
+                            w["traffic"] + ".json")
     from .traffic import load_mix
     return {
         "workload": w, "config": cfg, "mix": load_mix(mix_path),
-        "cell": load_json(os.path.join(HERE, "cells", workload + ".json")),
+        "cell": load_json(os.path.join(root, "perfbench", "cells",
+                                       workload + ".json")),
         "metrics": _metrics_for(bench, workload),
     }
 
@@ -227,8 +231,8 @@ class Observer:
     ending in a u16 txn count, then u16-framed txns): the observer joins
     the ring at its head (PoH ticks from boot on), walks each entry's
     txns, and also keeps the order of each txn's first delivery (the
-    commit order on the one chain) and every entry's header with the
-    time it came, for the ledger judge."""
+    commit order on the one chain), the entry that carried it, and
+    every entry's header with the time it came, for the ledger judge."""
 
     STOP, SEEN, ALTERED, UNKNOWN, OVERRUN, READY, ENTRIES = range(7)
 
@@ -247,9 +251,10 @@ class Observer:
         if entries:
             self.ext = shared_memory.SharedMemory(
                 create=True, size=_entry_views_size(n))
-            self.first_seq, self.hdr, self.hdr_t = _entry_views(
-                self.ext.buf, n)
+            self.first_seq, self.entry_of, self.hdr, self.hdr_t = \
+                _entry_views(self.ext.buf, n)
             self.first_seq[:] = -1
+            self.entry_of[:] = -1
         order = np.argsort(corpus.key)
         self.proc = mp.get_context("spawn").Process(
             target=_observe, name="perfbench-observer", daemon=True,
@@ -281,6 +286,7 @@ class Observer:
         if self.ext is not None:
             m = self.stat(self.ENTRIES)
             self.first_seq = self.first_seq.copy()
+            self.entry_of = self.entry_of.copy()
             self.hdr, self.hdr_t = self.hdr[:m].copy(), self.hdr_t[:m].copy()
             self.ext.close()
             self.ext.unlink()
@@ -302,20 +308,23 @@ def _views(buf, n: int):
 
 
 def _entry_views_size(n: int) -> int:
-    return 8 * n + 8 * ENTRY_CAP + ENTRY_HDR * ENTRY_CAP
+    return 16 * n + 8 * ENTRY_CAP + ENTRY_HDR * ENTRY_CAP
 
 
 def _entry_views(buf, n: int):
     first_seq = np.ndarray((n,), np.int64, buffer=buf, offset=0)
-    hdr_t = np.ndarray((ENTRY_CAP,), np.float64, buffer=buf, offset=8 * n)
+    entry_of = np.ndarray((n,), np.int64, buffer=buf, offset=8 * n)
+    hdr_t = np.ndarray((ENTRY_CAP,), np.float64, buffer=buf,
+                       offset=16 * n)
     hdr = np.ndarray((ENTRY_CAP, ENTRY_HDR), np.uint8, buffer=buf,
-                     offset=8 * n + 8 * ENTRY_CAP)
-    return first_seq, hdr, hdr_t
+                     offset=16 * n + 8 * ENTRY_CAP)
+    return first_seq, entry_of, hdr, hdr_t
 
 
 def entry_txns(buf, szs, k: int, mtu: int):
     """The txns inside k gathered PoH entry frames -> (payloads zero
-    padded to `mtu`, (m, mtu) uint8; their sizes; frames malformed).
+    padded to `mtu`, (m, mtu) uint8; their sizes; the frame of each;
+    frames malformed).
     A txn that would run past its frame ends the walk of that frame,
     which then counts as malformed."""
     rows, offs, lens, bad = [], [], [], 0
@@ -338,7 +347,7 @@ def entry_txns(buf, szs, k: int, mtu: int):
     out = np.zeros((len(rows), mtu), np.uint8)
     for j, (i, o, ln) in enumerate(zip(rows, offs, lens)):
         out[j, :ln] = buf[i, o:o + ln]
-    return out, np.array(lens, np.uint32), bad
+    return out, np.array(lens, np.uint32), np.array(rows, np.int64), bad
 
 
 def _observe(shm_name, n, plan, link, keys, order, sizes, checks,
@@ -362,7 +371,7 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks,
         n_max, width, seq0 = 4096, MTU, 0
     else:
         ext = shared_memory.SharedMemory(name=ext_name)
-        first_seq, hdr, hdr_t = _entry_views(ext.buf, n)
+        first_seq, entry_of, hdr, hdr_t = _entry_views(ext.buf, n)
         n_max, width, seq0 = 256, plan["links"][link]["mtu"], ring.seq
     buf = np.zeros((n_max, width), np.uint8)
     words = buf.view(np.uint64)
@@ -393,7 +402,7 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks,
             if ext_name is not None:
                 _observe_entries(buf, szs, k, now, keys, order, sizes,
                                  checks, first_t, count, stats, first_seq,
-                                 hdr, hdr_t)
+                                 entry_of, hdr, hdr_t)
                 continue
             key = buf[:k, 1:9].copy().view(np.uint64).ravel()
             pos = np.minimum(np.searchsorted(keys, key), len(keys) - 1)
@@ -414,16 +423,18 @@ def _observe(shm_name, n, plan, link, keys, order, sizes, checks,
         wksp.close()
         shm.close()
         if ext_name is not None:
-            del first_seq, hdr, hdr_t
+            del first_seq, entry_of, hdr, hdr_t
             ext.close()
 
 
 def _observe_entries(buf, szs, k, now, keys, order, sizes, checks,
-                     first_t, count, stats, first_seq, hdr, hdr_t):
+                     first_t, count, stats, first_seq, entry_of, hdr,
+                     hdr_t):
     """One gather of PoH entries: keep their headers, then match the
     txns inside them as the txn path matches frames, numbering each
     txn's first delivery in the order delivered (numbers rise; they
-    need not be consecutive)."""
+    need not be consecutive) and keeping the index of the entry (into
+    the kept headers) that carried it."""
     from .traffic import MTU, WORD_WEIGHTS
     m = int(stats[Observer.ENTRIES])
     if m + k > ENTRY_CAP:
@@ -432,7 +443,7 @@ def _observe_entries(buf, szs, k, now, keys, order, sizes, checks,
         hdr[m:m + k] = buf[:k, :ENTRY_HDR]
         hdr_t[m:m + k] = now
         stats[Observer.ENTRIES] = m + k
-    txns, tsz, bad = entry_txns(buf, szs, k, MTU)
+    txns, tsz, rows, bad = entry_txns(buf, szs, k, MTU)
     stats[Observer.UNKNOWN] += bad
     if not len(tsz):
         return
@@ -444,11 +455,14 @@ def _observe_entries(buf, szs, k, now, keys, order, sizes, checks,
     same &= (txns.view(np.uint64)[hit] * WORD_WEIGHTS).sum(
         axis=1, dtype=np.uint64) == checks[idx]
     np.add.at(count, idx, 1)
-    fresh = idx[np.isnan(first_t[idx])]
+    new = np.isnan(first_t[idx])
+    fresh, rows = idx[new], rows[hit][new]
     _, at = np.unique(fresh, return_index=True)
-    fresh = fresh[np.sort(at)]                  # in order of delivery
+    at = np.sort(at)                            # in order of delivery
+    fresh = fresh[at]
     first_t[fresh] = now
     first_seq[fresh] = stats[Observer.SEEN] + np.arange(len(fresh))
+    entry_of[fresh] = m + rows[at]
     stats[Observer.SEEN] += len(tsz)
     stats[Observer.UNKNOWN] += int((~hit).sum())
     stats[Observer.ALTERED] += int((~same).sum())
@@ -745,8 +759,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     r.late_s = inj.pub_t[sent_win] - r.sched_s
     r.backlog = backlog
     r.stalls = inj.stalls
-    r.lanes_window = int(corpus.sig_cnt[frames[n_warm:sent_hi]].astype(
-        np.int64).sum()) if mix["resend"].get("copies", 0) == 0 else None
     r.delta = delta(snap_a, snap_b)
     r.cfg = cfg
     r.trace = r.capture = None
@@ -789,6 +801,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"ledger: {len(committed)} committed txns executed, "
             f"{len(served)} balances compared, {len(obs.hdr)} PoH entries "
             f"checked in {time.monotonic() - t_ref:.1f} s")
+        t_ref = time.monotonic()
+        for k, v in ledger.block_checks(obs.hdr, obs.entry_of, corpus.txn,
+                                        cfg["block_limits"]).items():
+            checks[k] = [v, 0]
+        say(f"block rules: {len(committed)} committed txns checked in "
+            f"{time.monotonic() - t_ref:.1f} s")
 
     say("window: " + json.dumps(diagnostics(r, rate)))
     kind = "per_layer" if trace else "end_to_end"

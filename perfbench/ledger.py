@@ -26,6 +26,10 @@ a hash is SHA-256, a record entry's one hash is of prev | mixin, a
 tick's num_hashes hashes are of prev alone). `poh_mismatch` counts the
 entries that break the chain (see poh_mismatch); `ticks_early` the
 ticks that run more than one slot ahead of the wall clock. Limit 0.
+
+The block rules (blockrules.py) are judged over the committed txns,
+each in the entry that first carried it, each entry in the slot its
+header names (block_checks). Limit 0.
 """
 from __future__ import annotations
 
@@ -37,6 +41,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from .blockrules import block_rules
 from .reference import _cu16
 
 FEE_PER_SIGNATURE = 5000          # lamports per signature (fd_pack.h)
@@ -203,3 +208,20 @@ def ticks_early(hdrs, times, tick_s: float, ticks_per_slot: int) -> int:
     t = np.asarray(times)[np.asarray(hdrs)[:, 16] == 0]     # the ticks
     ahead = np.arange(len(t)) - (t - t[:1]) / tick_s
     return int((ahead > ticks_per_slot).sum())
+
+
+def block_checks(hdrs, entry_of, txn, limits: dict) -> dict:
+    """blockrules.block_rules over the committed txns: entry_of[i] is
+    the index into hdrs of the entry that first carried txn i (-1: never
+    committed), txn(i) its payload, and an entry's slot the u64 its
+    header starts with."""
+    entry_of = np.asarray(entry_of)
+    idx = np.flatnonzero(entry_of >= 0)
+    idx = idx[np.argsort(entry_of[idx], kind="stable")]
+    ent = entry_of[idx]
+    slots = np.ascontiguousarray(np.asarray(hdrs)[:, :8]).view(
+        "<u8").ravel()
+    groups = np.split(idx, np.flatnonzero(np.diff(ent)) + 1) \
+        if len(idx) else []
+    return block_rules(((int(slots[entry_of[g[0]]]),
+                         [txn(int(i)) for i in g]) for g in groups), limits)

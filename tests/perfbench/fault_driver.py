@@ -26,6 +26,12 @@ fault is planted in the program inside them, where it runs:
   poh_fast         the poh tile makes four ticks in each housekeeping
                    pass, of 256 hashes each (set in the config for this
                    fault alone, so they can outrun the wall clock)
+  pack_no_conflict pack's conflict test passes every txn, so a
+                   microblock can lock one account twice (run on a mix
+                   of two payers, so that one does)
+  pack_no_limits   pack's block-limit test passes every txn (run on
+                   txns asking for 1.4M compute units, so that a slot
+                   of them passes the 48M-unit block limit)
 
 Prints the run's result line last, as run.py does.
 """
@@ -93,6 +99,11 @@ def plant():
                 self._tick()
             real_hk(self)
         PohAdapter.housekeeping = housekeeping
+    elif FAULT in ("pack_no_conflict", "pack_no_limits"):
+        from firedancer_tpu.pack.scheduler import PackScheduler
+        name = "_conflicts" if FAULT == "pack_no_conflict" \
+            else "_block_allows"
+        setattr(PackScheduler, name, lambda self, *a: name != "_conflicts")
     elif FAULT != "none":
         raise ValueError(FAULT)
 
@@ -123,7 +134,11 @@ def main(workload: str) -> int:
     harness.WARM_DRAIN_S = harness.DRAIN_S
     mix = {"none": {}, "altered_verdict": {},
            "half_unverified": {"forged_share": 0.3},
-           "state_unchanged": {}}.get(FAULT, {})
+           "state_unchanged": {},
+           "pack_no_conflict": {"signer_pool": 2},
+           "pack_no_limits": {"compute_budget": {
+               "cu_limit": 1_400_000, "loaded_data_bytes": 32768}},
+           }.get(FAULT, {})
     out = harness.run_cell(
         workload, 2 ** 40 + 11, 2.0, False, T_START,
         overrides={"config": small,

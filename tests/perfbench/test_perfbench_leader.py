@@ -8,6 +8,7 @@ metric readers on a hand-built run."""
 import hashlib
 import json
 import os
+import shutil
 import sys
 from types import SimpleNamespace
 
@@ -18,14 +19,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from perfbench import harness, ledger, reference, traffic  # noqa: E402
+from perfbench import (blockrules, harness, ledger,  # noqa: E402
+                       reference, traffic)
 from test_perfbench_harness import KEYS, drive  # noqa: E402
 
 CELL = "leader.transfers.over"
 SEED = 2 ** 35 + 23
 LEDGER_CHECKS = ["wrong_accept", "missing", "double", "altered", "unknown",
                  "label_disagree", "state_mismatch", "poh_mismatch",
-                 "ticks_early"]
+                 "ticks_early", "entry_conflict", "block_cost_over",
+                 "acct_cost_over"]
 
 
 def test_cpu_rehearsal_is_correct_and_reports_committed_tps():
@@ -39,8 +42,9 @@ def test_cpu_rehearsal_is_correct_and_reports_committed_tps():
     assert out["metrics"]["committed_tps"]["unit"] == "txns/s"
     assert out["device"]["platform"] == "cpu"
     assert "perfbench: state: " in err
+    assert "perfbench: block rules: " in err
     tail = err.strip().splitlines()[-len(out["checks"]):]
-    assert tail[-1] == "check ticks_early: 0 (limit 0)"
+    assert tail[-1] == "check acct_cost_over: 0 (limit 0)"
 
 
 @pytest.mark.parametrize("fault", ["bank_unchanged", "bank_half",
@@ -164,9 +168,10 @@ def test_entry_decoder_reads_the_poh_wire_format():
     frames = _entries(groups)
     assert len(frames[0]) == harness.ENTRY_HDR      # the tick: no txns
     buf, szs = _gathered(frames)
-    txns, sizes, bad = harness.entry_txns(buf, szs, len(frames),
-                                          traffic.MTU)
+    txns, sizes, rows, bad = harness.entry_txns(buf, szs, len(frames),
+                                                traffic.MTU)
     assert bad == 0
+    assert rows.tolist() == [1, 1, 1, 2, 3, 3]      # the frame of each
     want = [t for g in groups for t in g]
     assert [txns[j, :sizes[j]].tobytes() for j in range(len(want))] == want
     # zero padded to the MTU: the corpus's checksums apply as they are
@@ -180,9 +185,9 @@ def test_entry_decoder_counts_a_truncated_frame():
     frames[1] = frames[1][:-10]                       # cut the last txn
     frames.append(b"\x00" * 20)                       # shorter than a header
     buf, szs = _gathered(frames)
-    txns, sizes, bad = harness.entry_txns(buf, szs, len(frames),
-                                          traffic.MTU)
-    assert bad == 2
+    txns, sizes, rows, bad = harness.entry_txns(buf, szs, len(frames),
+                                                traffic.MTU)
+    assert bad == 2 and rows.tolist() == [1]
     assert len(sizes) == 1 and txns[0, :sizes[0]].tobytes() == c.txn(0)
 
 
@@ -312,6 +317,29 @@ def test_no_fee_control_comes_out_not_correct():
     assert out["correct"] is False
     assert out["checks"]["state_mismatch"][0] > 0
     assert out["checks"]["missing"][0] == 0
+    for k in ("entry_conflict", "block_cost_over", "acct_cost_over"):
+        assert out["checks"][k] == [0, 0], k      # packed soundly
+
+
+@pytest.mark.parametrize("control,caught,cu_limit", [
+    ("no_conflict_check", "entry_conflict", 300),
+    ("no_block_limit", "block_cost_over", 1_400_000),
+])
+def test_block_rule_control_comes_out_not_correct(control, caught,
+                                                   cu_limit):
+    """A second of transfers (for no_block_limit each asking for 1.4M
+    compute units, so that the second's txns pass the block limit); only
+    the rule the control breaks reads above 0."""
+    from perfbench import control as ctl
+    out = ctl.control_run(
+        CELL, 2 ** 36 + 7, 1.0, control,
+        overrides={"cell": lambda c: dict(c, rate=1500, warmup_s=0.0),
+                   "mix": lambda m: dict(m, compute_budget=dict(
+                       m["compute_budget"], cu_limit=cu_limit))})
+    got = {k: v for k, (v, _) in out["checks"].items()}
+    assert out["correct"] is False
+    assert got[caught] > 0, got
+    assert {k for k, v in got.items() if v} == {caught}
 
 
 # -- the transfer mix --------------------------------------------------------
@@ -389,17 +417,141 @@ def test_tick_ms_is_silent_without_ticks():
     assert harness.reader("tick_ms.leader")(_run({})) is None
 
 
-def test_leader_metrics_are_declared_for_the_leader_cell():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["committed_tps"]["workloads"] == [CELL]
-    spec = harness.load_cell(CELL)
-    assert [m["name"] for m in spec["metrics"]["end_to_end"]] == [
-        "committed_tps", "setup_s"]
+# -- leader cells as data: what holds for any number of them -------------
+
+ZIPF = {"rule": "zipf", "count": 1024, "exponent": 0.99}
+
+
+def add_zipf_cell(root):
+    """Add leader.zipf.over to a copy of the benchmark at `root` as new
+    files and new BENCHMARK.json entries only: a config that is a copy
+    of leader-1chip's, the transfers mix with Zipf destinations, a cell
+    file, and the cell's name appended to committed_tps's workloads."""
+    pb = os.path.join(root, "perfbench")
+    shutil.copy(os.path.join(pb, "configs", "leader-1chip.json"),
+                os.path.join(pb, "configs", "leader-zipf-1chip.json"))
+    mix = harness.load_json(os.path.join(pb, "traffic", "transfers.json"))
+    with open(os.path.join(pb, "traffic", "zipf.json"), "w") as f:
+        json.dump(dict(mix, name="zipf", destinations=ZIPF), f)
+    shutil.copy(os.path.join(pb, "cells", CELL + ".json"),
+                os.path.join(pb, "cells", "leader.zipf.over.json"))
+    path = os.path.join(root, "BENCHMARK.json")
+    bench = harness.load_json(path)
+    conf = next(c for c in bench["configs"] if c["name"] == "leader-1chip")
+    bench["configs"].append(dict(conf, name="leader-zipf-1chip",
+                                 file="perfbench/configs/"
+                                 "leader-zipf-1chip.json"))
+    bench["workloads"].append({
+        "name": "leader.zipf.over", "config": "leader-zipf-1chip",
+        "traffic": "zipf", "chips": 1,
+        "why": "transfers to 1,024 Zipf-0.99 accounts: pack's conflicts"})
+    next(m for m in bench["end_to_end"]
+         if m["name"] == "committed_tps")["workloads"].append(
+        "leader.zipf.over")
+    with open(path, "w") as f:
+        json.dump(bench, f, indent=1)
+
+
+@pytest.fixture(scope="module", params=["repo", "zipf_added"])
+def root(request, tmp_path_factory):
+    """The repo's benchmark, and a copy of it with a second leader cell
+    added as data."""
+    if request.param == "repo":
+        return ROOT
+    dst = tmp_path_factory.mktemp("room")
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
+    shutil.copytree(os.path.join(ROOT, "perfbench"), dst / "perfbench",
+                    ignore=shutil.ignore_patterns(".out", "__pycache__"))
+    add_zipf_cell(str(dst))
+    return str(dst)
+
+
+def _leader_cells(root) -> list:
+    """Cells whose config's observed link a poh tile produces."""
+    bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
+    out = []
+    for w in bench["workloads"]:
+        cfg = harness.load_cell(w["name"], root)["config"]
+        producer = next(t for t in cfg["tiles"]
+                        if cfg["observe"] in t.get("outs", ()))
+        if producer["kind"] == "poh":
+            out.append(w["name"])
+    return out
+
+
+def _e2e(root) -> dict:
+    bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
+    return {m["name"]: m for m in bench["end_to_end"]}
+
+
+def test_committed_tps_cells_are_leader_cells(root):
+    cells = _e2e(root)["committed_tps"]["workloads"]
+    assert CELL in cells
+    assert set(cells) <= set(_leader_cells(root))
+
+
+def test_leader_cells_report_committed_tps_and_setup_s(root):
+    for cell in _leader_cells(root):
+        spec = harness.load_cell(cell, root)
+        names = [m["name"] for m in spec["metrics"]["end_to_end"]]
+        assert {"committed_tps", "setup_s"} <= set(names), cell
+        assert spec["config"]["block_limits"]["block"] > 0
+        assert spec["config"]["block_limits"]["writable_account"] > 0
+    assert [m["name"] for m in harness.load_cell(
+        CELL, root)["metrics"]["end_to_end"]] == ["committed_tps", "setup_s"]
+
+
+def test_leader_metrics_are_declared_for_the_leader_cell(root):
+    bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
+    cells = set(_e2e(root)["committed_tps"]["workloads"])
     pl = [m for m in bench["per_layer"] if m["name"].endswith(".leader")]
-    assert [m["name"] for m in pl] == ["pack_busy.leader", "tick_ms.leader"]
     for m in pl:
-        assert m["moves"] == "committed_tps" and m["workloads"] == [CELL]
+        assert m["moves"] == "committed_tps"
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
+    spec = harness.load_cell(CELL, root)
+    assert {"pack_busy.leader", "tick_ms.leader"} <= {
+        m["name"] for m in spec["metrics"]["per_layer"]}
+
+
+def test_a_second_leader_cell_is_added_as_data(tmp_path):
+    """The copy holds every file of the repo's benchmark as it was, and
+    BENCHMARK.json only gains entries; load_cell reads all four files
+    of the new cell from the copy."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "perfbench"), tmp_path / "perfbench",
+                    ignore=shutil.ignore_patterns(".out", "__pycache__"))
+    add_zipf_cell(str(tmp_path))
+    new = {"perfbench/configs/leader-zipf-1chip.json",
+           "perfbench/traffic/zipf.json",
+           "perfbench/cells/leader.zipf.over.json"}
+    for dirpath, _, files in os.walk(tmp_path / "perfbench"):
+        for f in files:
+            rel = os.path.relpath(os.path.join(dirpath, f), tmp_path)
+            if rel not in new:
+                with open(os.path.join(ROOT, rel), "rb") as a, \
+                        open(os.path.join(tmp_path, rel), "rb") as b:
+                    assert a.read() == b.read(), rel
+    old = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    got = harness.load_json(os.path.join(tmp_path, "BENCHMARK.json"))
+    assert got["configs"][:-1] == old["configs"]
+    assert got["workloads"][:-1] == old["workloads"]
+    assert got["per_layer"] == old["per_layer"]
+    for a, b in zip(old["end_to_end"], got["end_to_end"]):
+        if a["name"] == "committed_tps":
+            assert b["workloads"] == a["workloads"] + ["leader.zipf.over"]
+            b = dict(b, workloads=a["workloads"])
+        assert a == b
+    spec = harness.load_cell("leader.zipf.over", str(tmp_path))
+    assert spec["mix"]["destinations"] == ZIPF
+    assert spec["cell"]["rate"] > 0
+    assert spec["config"]["observe"] == "poh_entries"
+    assert "leader.zipf.over" in _leader_cells(str(tmp_path))
+    p = traffic.plan(spec["mix"], SEED, 50, 1.0)
+    c = traffic.sign(spec["mix"], SEED, p, procs=1)
+    dst = {ledger.parse_transfers(c.txn(i))[1][0][1]
+           for i in range(len(c.size))}
+    ranks = {traffic.account_key(SEED, b"acct", r) for r in range(1024)}
+    assert dst <= ranks
 
 
 @pytest.mark.parametrize("budget,cost", [
@@ -419,4 +571,5 @@ def test_transfer_cost_as_pack_prices_it(budget, cost):
     t = c.txn(0)
     assert len(t) == traffic.TRANSFER_SIZE
     assert compute_cost(parse_txn(t), t).total == cost
+    assert blockrules.locks_and_cost(t)[2] == cost
     assert ledger.parse_transfers(t)[1][0][0] == t[69:101]
