@@ -17,8 +17,12 @@ one process holds the chip at any time and this parent never imports JAX:
             corrupt R, S, msg, A; non-canonical S; small-order A and
             R), verdicts equal bit for bit to utils/ed25519_ref.py; the
             same call limited to its first LIVE lanes (`n_live`) gives
-            those lanes the same verdicts and every later lane False; the
-            RLC prefilter `pallas_msm.rlc_verify_batch_tpu` passes an
+            those lanes the same verdicts and every later lane False;
+            the verify tile's own staging sets and jit at 8192, 300 and
+            1300 live lanes, in dispatches of its width (printed),
+            verdicts equal to the oracle on the live lanes and False
+            past each dispatch's count; the RLC prefilter
+            `pallas_msm.rlc_verify_batch_tpu` passes an
             all-valid batch and fails one with a forged lane; the
             Mosaic lane/sublane rolls rotate like jnp.roll.
   pipeline  `python -m firedancer_tpu.app.run cfg/default.toml
@@ -61,6 +65,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, ".chip_smoke")         # logs (git-ignored)
 BATCH, MSG_LEN, SEED = 8192, 1232, 21
 LIVE = 1300                     # a part-full call: 6 of 32 grid programs
+# the verify tile's dispatches: the whole set first, so the later ones
+# find its bytes in their dead lanes
+TILE_LIVE = (BATCH, 300, LIVE)
 KERNEL_TIMEOUT_S = 900          # cold MSM compile alone is ~4 min
 PIPELINE_BOOT_S = 600
 PIPELINE_RUN_S = 300
@@ -279,6 +286,8 @@ def kernel_child(data: str, mesh: int) -> dict:
     say(f"kernel: strict n_live={LIVE} verdicts == full width on the live "
         f"lanes, False on the {len(part) - LIVE} past the count")
 
+    check_tile_dispatch(d)
+
     from firedancer_tpu.ops.ed25519 import rlc_verify_fn
     rlc = rlc_verify_fn()       # the verify tile's resolver
     rargs = [jnp.asarray(d[k]) for k in
@@ -294,6 +303,50 @@ def kernel_child(data: str, mesh: int) -> dict:
         raise PhaseError("rlc: the batch with a forged lane passed")
     say("kernel: rlc passes the valid batch, fails the forged one")
     return info
+
+
+def check_tile_dispatch(d, live=TILE_LIVE):
+    """Dispatch through the verify tile's own staging sets and jit at
+    each live count, in chunks of the tile's dispatch width as its
+    assembler cuts them, dead lens zeroed as the assembler does.
+    Verdicts equal the oracle on the live lanes and read False past
+    each dispatch's count."""
+    import jax
+    import numpy as np
+
+    from firedancer_tpu.runtime import Ring, Tcache, Workspace
+    from firedancer_tpu.tiles.verify import VerifyTile
+    n, max_len = d["msg"].shape
+    w = Workspace(f"/fdtpu_smoke_{os.getpid()}", 1 << 22)
+    try:
+        t0 = time.perf_counter()
+        tile = VerifyTile(Ring.create(w, depth=64, mtu=1280),
+                          Ring.create(w, depth=64, mtu=1280),
+                          Tcache(w, depth=64), batch=n, max_len=max_len)
+        width = tile._width
+        say(f"tile: width {width} of batch {n} warmed in "
+            f"{time.perf_counter() - t0:.1f} s (info)")
+        bs = tile._bufsets[0]
+        for k in live:
+            for lo in range(0, k, width):
+                m = min(width, k - lo)
+                for key in ("sig", "pub", "msg", "ln"):
+                    getattr(bs, key)[:m] = d[key][lo:lo + m]
+                bs.ln[m:] = 0
+                got = np.asarray(jax.block_until_ready(
+                    tile._device_verify(bs, m)))
+                if len(got) != width or not np.array_equal(
+                        got[:m], d["want"][lo:lo + m]) or got[m:].any():
+                    raise PhaseError(
+                        f"tile: lanes {lo}-{lo + m} of {k}: verdicts "
+                        f"differ from the oracle, or a lane past the "
+                        f"count reads True")
+            say(f"tile: {k} live lanes in {-(-k // width)} dispatch(es) of "
+                f"width {width}: verdicts == oracle on the live lanes "
+                f"({int(d['want'][:k].sum())} valid), False past each count")
+    finally:
+        w.close()
+        w.unlink()
 
 
 def run_kernel_phase(data: str, mesh: int) -> dict:

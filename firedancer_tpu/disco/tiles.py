@@ -303,13 +303,14 @@ class VerifyAdapter:
                # capture's own start/stop), strict lanes dispatched,
                # parked duplicates and their host re-verifies, and the
                # summed residence of the txns that reached a device lane,
-               # and the strict kernel's grid programs run
+               # the strict kernel's grid programs run and the lanes each
+               # device dispatch shipped
                "ns_gather", "ns_parse", "ns_dedup", "ns_assemble",
                "ns_dispatch", "ns_readback", "ns_finalize",
                "ns_deferred", "ns_publish", "ns_capture",
                "lanes", "deferred_parked", "deferred_host_verifies",
                "txn_hold_ns", "txn_device_ns", "txn_egress_ns",
-               "txn_timed", "kernel_programs"]
+               "txn_timed", "kernel_programs", "staged_lanes"]
     GAUGES = ["cpu_fallback", "tpu_jit_compiles", "tpu_jit_cache_miss",
               "tpu_inflight", "tpu_mem_bytes", "tpu_compile_ns"]
     # declared (not name-sniffed) device-telemetry slots: the renderer

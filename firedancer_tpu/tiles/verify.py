@@ -9,22 +9,31 @@ req/resp offload behind the ring ABI):
     --jit(verify_batch) on device--> verdicts
     --tcache dedup on first sig--> out ring (payload + PASS sig)
 
-Batch assembly keeps ONE compiled shape (short batches are padded with
-dead lanes, masked after) so XLA never recompiles in steady state. The
-live-lane count rides each dispatch as a traced scalar: on the Pallas
-path the strict kernel skips its 256-lane grid programs that hold only
-padding, so a short batch costs what its live lanes cost (counted in
-`kernel_programs`); the jnp path on the CPU (no grid) and the
-multi-device mesh take no count. A txn with k signatures occupies k
-lanes and passes only if all k verify (the reference loops sigs the
-same way, fd_verify_tile.h:94).
+Batch assembly keeps ONE compiled shape, the dispatch WIDTH (short
+chunks are padded with dead lanes, masked after), so XLA never
+recompiles in steady state. On the single-device Pallas path the width
+is one SHA-512 grid program, 1,024 lanes, below a larger batch
+(`dispatch_width`): a gather of up to `batch` frames is assembled,
+shipped and run in chunks of that width, so a dispatch of ~300 live
+lanes moves 1.4 MB, not the 10.9 MB of 8192 x 1232, and runs one SHA
+program, not eight. One width, not several: every compiled width costs
+each boot its trace, lowering and load of the strict kernel (~15-20 s
+on a v5e host, compile cache or not). The live-lane count rides each
+dispatch as a traced scalar: the strict kernel skips its 256-lane grid
+programs that hold only padding (counted in `kernel_programs`). The
+jnp path on the CPU (no grid) and the multi-device mesh (which shards
+the batch axis) dispatch the whole batch and take no count. A txn with
+k signatures occupies k lanes and passes only if all k verify (the
+reference loops sigs the same way, fd_verify_tile.h:94); its lanes
+never split across chunks.
 
 Device staging (r10): each rotating buffer set is ONE contiguous host
 buffer (len|sig|pub|msg lanes packed back to back — the native
 assembler writes straight into views of it), so a dispatch is a single
-async `device_put` of ~2.6 MB followed by the jit, which splits the
-lanes back out on-device (donated on real accelerators — the transfer
-buffer is consumed by the computation, never copied again). Because
+async `device_put` of width x (100 + max_len) bytes followed by the
+jit, which splits the lanes back out on-device (donated on real
+accelerators, though the bool verdicts cannot reuse the uint8 buffer,
+so the donation only frees it after the call). Because
 the put is async and each in-flight batch owns its own staging set,
 the host->device transfer of batch k overlaps the device compute of
 batch k-1 instead of serializing four little `jnp.asarray` copies
@@ -65,20 +74,22 @@ sound because ha-dedup tcaches are per-tile and round-robin frag
 ownership is disjoint.
 
 Bulk RLC pre-filter (r14, `[tile.verify] mode = "bulk_prefilter"`):
-a FULL assembled chunk — or any chunk while the ingest-saturation
-window is open — is gated by ONE random-linear-combination batch
-equation (ops/ed25519.rlc_verify_batch on CPU, ops/pallas_msm on
+each chunk of a FULL gather (its candidates need a whole batch of
+lanes) — or any chunk while the ingest-saturation window is open — is
+gated by ONE random-linear-combination batch equation padded to the
+dispatch width (ops/ed25519.rlc_verify_batch on CPU, ops/pallas_msm on
 accelerators, secret per-chunk z) BEFORE the strict dispatch — the
-flood front door ROADMAP item 4 names. Sub-full chunks in peacetime
-skip the equation entirely: the filter's economics only work at batch
-grain (a trickle pays less running the strict kernel directly), and a
-flood by definition fills chunks. The strict kernel stays the
+flood front door ROADMAP item 4 names. The chunks of a sub-full
+gather in peacetime skip the equation entirely: the filter's economics
+only work at batch grain (a trickle pays less running the strict
+kernel directly), and a flood by definition fills gathers. The strict
+kernel stays the
 final accept authority (rlc is cofactored, NOT a consensus drop-in —
 tests/test_rlc.py pins the torsion divergence class), so a batch that
 slips the filter is still judged strictly and zero frags are ever
 falsely accepted. What the filter buys is the flood path: a chunk that
-FAILS the batch equation while ingest is saturated — a FULL chunk is
-its own saturation proof, the hot window covers partial chunks during
+FAILS the batch equation while ingest is saturated — a FULL gather is
+its own saturation proof, the hot window covers partial gathers during
 a sustained burst — is bisected, and if BOTH halves fail too (an
 all-garbage chunk — a forged-sig flood at line rate) the whole chunk
 is dropped at MSM cost without spending a strict dispatch; a chunk
@@ -87,10 +98,11 @@ a chunk with garbage in BOTH halves is shed whole, its valid lanes
 with it (the chip smoke saw 4,096 valid txns shed from one chunk of
 1,024 valid + 4,096 forged/torsion + 3,072 valid, PR 21), so
 `prefilter_shed = False` — the filter runs, never sheds — is the
-setting that keeps every valid txn. A sub-full
-failing chunk off-hot just proceeds to strict (fail-closed, zero
-behavior change in peacetime beyond the one batch check). rlc_* metrics count batches/lanes/sheds and accumulate kernel
-time for the rlc_prefilter_vps bench stanza.
+setting that keeps every valid txn. A failing chunk of a sub-full
+gather off-hot just proceeds to strict (fail-closed, zero behavior
+change in peacetime beyond the one batch check). rlc_* metrics count
+batches/lanes/sheds and accumulate kernel time for the
+rlc_prefilter_vps bench stanza.
 
 Device robustness: a device that cannot warm up fails the tile at
 boot. After a healthy boot, dispatch is wrapped in bounded retry,
@@ -168,6 +180,16 @@ def parse_batch(buf: np.ndarray, sizes: np.ndarray, seed: bytes):
 _FN_CACHE: dict = {}
 
 
+def dispatch_width(batch: int, grain: int, program: int) -> int:
+    """Lanes of one counted device dispatch: one SHA-512 grid program
+    (`program`, ops/pallas_sha.TBATCH = 1,024) where the batch is a
+    larger multiple of it and it is a multiple of the strict kernel's
+    `grain`, else the whole batch."""
+    if batch > program and batch % program == 0 and program % grain == 0:
+        return program
+    return batch
+
+
 class _StageBuf:
     """One rotating staging set: a single contiguous host buffer whose
     lane regions (len|sig|pub|msg) are numpy views the native assembler
@@ -235,8 +257,9 @@ class VerifyTile:
             "txn_hold_ns": 0, "txn_device_ns": 0, "txn_egress_ns": 0,
             "txn_timed": 0,
             # strict-kernel grid programs run: ceil(lanes / 256) per
-            # counted device dispatch, batch / 256 (all) otherwise
-            "kernel_programs": 0,
+            # counted device dispatch, batch / 256 (all) otherwise; and
+            # the lanes each such dispatch shipped, the batch otherwise
+            "kernel_programs": 0, "staged_lanes": 0,
         }
         if mode not in ("strict", "bulk_prefilter"):
             raise ValueError(f"unknown verify mode {mode!r} "
@@ -318,6 +341,7 @@ class VerifyTile:
             else:
                 # fused Pallas kernels on accelerator backends
                 from ..ops.pallas_ed import verify_batch as vb
+                from ..ops.pallas_sha import TBATCH
             ndev = min(int(devices), len(devs))
             if ndev > 1:
                 # shard the batch axis over the device mesh: the
@@ -339,6 +363,10 @@ class VerifyTile:
                 counted = False
             self.devices = ndev
             self._counted, self._grain = counted, tb
+            # only a counted dispatch narrows: the jnp path and the
+            # mesh keep the whole batch
+            self._width = dispatch_width(batch, tb, TBATCH) if counted \
+                else batch
             # staged dispatch: the jit consumes ONE packed uint8 buffer
             # (the whole staging set) and splits the lanes on-device —
             # host->device is a single transfer per dispatch — plus the
@@ -347,7 +375,7 @@ class VerifyTile:
             # dispatch device_puts a fresh copy, so the computation may
             # consume it); CPU device_put can alias host memory, where
             # donation would hand XLA the live staging array.
-            bsz, mlen = batch, max_len
+            bsz, mlen = self._width, max_len
             o_sig, o_pub = 4 * bsz, (4 + 64) * bsz
             o_msg = o_pub + 32 * bsz
             fn_key = (bsz, mlen, ndev, devs[0].platform)
@@ -379,9 +407,8 @@ class VerifyTile:
         # so an in-flight transfer never reads a reused host buffer.
         self.inflight = max(1, int(os.environ.get(
             "FDTPU_VERIFY_INFLIGHT", "2")))
-        self._bufsets = [_StageBuf(batch, max_len)
-                         for _ in range(self.inflight + 1)]
-        self._bufset_fut = [None] * (self.inflight + 1)
+        self._bufsets = self._stage_sets()
+        self._bufset_fut = [None] * len(self._bufsets)
         self._disp = 0
         from collections import deque
         self._pending: deque = deque()
@@ -425,9 +452,15 @@ class VerifyTile:
             # inject a host-oracle _rlc_fn (tracing + compiling the MSM
             # graph costs minutes on CPU — tier-1 exercises the wiring
             # against the oracle, the slow suite runs the real kernel).
-            self._rlc_ok(self._bufsets[0], 0, min(2, self.batch),
-                         self.batch)
+            self._rlc_ok(self._bufsets[0], 0, 2)
         self.compile_ns = monotonic_ns() - warmup_t0
+
+    def _stage_sets(self) -> list:
+        """`inflight` + 1 batches of staging sets at the dispatch width,
+        so the chunks of the batches in flight never wait on each
+        other's verdicts."""
+        n = (self.inflight + 1) * (self.batch // self._width)
+        return [_StageBuf(self._width, self.max_len) for _ in range(n)]
 
     def _warmup_once(self, bs: _StageBuf) -> str | None:
         """One warmup attempt on a daemon thread with a deadline (a
@@ -440,9 +473,9 @@ class VerifyTile:
         def _worker():
             try:
                 import jax
-                # the full batch: boot runs every grid program once
+                # the full width: boot runs every grid program once
                 out = jax.block_until_ready(
-                    self._device_verify(bs, self.batch))
+                    self._device_verify(bs, self._width))
                 self._warm_shards = len(
                     {s.device for s in out.addressable_shards})
                 q.put(None)
@@ -485,14 +518,13 @@ class VerifyTile:
         cofactored equation — tests/test_rlc.py)."""
         return self._rlc_rng.integers(0, 256, (n, 16), dtype=np.uint8)
 
-    def _rlc_ok(self, bs: _StageBuf, start: int, stop: int,
-                width: int) -> bool:
+    def _rlc_ok(self, bs: _StageBuf, start: int, stop: int) -> bool:
         """One cofactored RLC batch equation over assembled lanes
-        [start, stop), padded to `width` (= batch everywhere, so the
-        jit only ever sees ONE shape: tracing the MSM graph costs
-        minutes on CPU and a mid-run retrace would starve heartbeats
-        and trip the wedge watchdog; bisect halves just ride the full
-        width with dead lanes). Pad lanes carry z = 0, which zeroes
+        [start, stop), padded to the dispatch width (so the jit only
+        ever sees ONE shape: tracing the MSM graph costs minutes on
+        CPU and a mid-run retrace would starve heartbeats and trip the
+        wedge watchdog; bisect halves just ride the full width with
+        dead lanes). Pad lanes carry z = 0, which zeroes
         every one of their scalar terms — an identity contribution to
         the sum regardless of what the stale lane bytes decode to.
         Platform-dispatched like gossvf: the Pallas MSM kernel on
@@ -509,7 +541,7 @@ class VerifyTile:
             from ..ops.ed25519 import rlc_verify_fn
             self._rlc_fn = rlc_verify_fn()
         import jax.numpy as jnp
-        k = stop - start
+        k, width = stop - start, self._width
         sig = np.zeros((width, 64), np.uint8)
         pub = np.zeros((width, 32), np.uint8)
         msg = np.zeros((width, self.max_len), np.uint8)
@@ -529,7 +561,7 @@ class VerifyTile:
         """The flood front door: one RLC batch equation per assembled
         chunk, BEFORE the strict dispatch. Returns False only when the
         chunk should be SHED (equation failed while ingest is
-        saturated — chunk full, or the hot window open — AND both
+        saturated — a full gather, or the hot window open — AND both
         bisection halves fail too — an all-garbage
         chunk, the forged-sig-flood signature); True always proceeds
         to the strict kernel, which remains the sole accept authority
@@ -537,12 +569,12 @@ class VerifyTile:
         t0 = monotonic_ns()
         self.metrics["rlc_batches"] += 1
         self.metrics["rlc_lanes"] += lanes
-        ok = self._rlc_ok(bs, 0, lanes, self.batch)
+        ok = self._rlc_ok(bs, 0, lanes)
         keep = True
         if ok:
             self.metrics["rlc_pass"] += 1
         elif self.prefilter_shed and lanes >= 2:
-            # the caller already attested saturation (full chunk, or
+            # the caller already attested saturation (full gather, or
             # the hot window open at assembly) — deliberately NOT
             # re-sampled here: the equation above costs real wall time
             # on slow backends (~175ms on the CPU jnp kernel) and the
@@ -554,8 +586,7 @@ class VerifyTile:
             # included (see the module docstring)
             h = lanes // 2
             self.metrics["rlc_batches"] += 2
-            keep = self._rlc_ok(bs, 0, h, self.batch) \
-                or self._rlc_ok(bs, h, lanes, self.batch)
+            keep = self._rlc_ok(bs, 0, h) or self._rlc_ok(bs, h, lanes)
         self.metrics["rlc_ns"] += monotonic_ns() - t0
         return keep
 
@@ -824,6 +855,9 @@ class VerifyTile:
         # or cannot place is dropped, never forwarded unverified.
         chunks = []
         cursor = ct.c_int64(0)
+        # ingest saturation is the gather's to attest, not a chunk's: a
+        # chunk of the width fills long before a batch of lanes does
+        full = int(meta[cand, 1].sum()) >= self.batch
         while cursor.value < n:
             k = self._disp % len(self._bufsets)
             if self._bufset_fut[k] is not None:
@@ -841,7 +875,7 @@ class VerifyTile:
                 buf.ctypes.data_as(_u8p),
                 sizes.ctypes.data_as(_u32p),
                 meta.ctypes.data_as(_i32p), skip.ctypes.data_as(_u8p),
-                n, buf.shape[1], ct.byref(cursor), self.batch,
+                n, buf.shape[1], ct.byref(cursor), self._width,
                 self.max_len,
                 bs.sig.ctypes.data_as(_u8p),
                 bs.pub.ctypes.data_as(_u8p),
@@ -852,8 +886,7 @@ class VerifyTile:
             if not lanes:
                 break
             if self.mode == "bulk_prefilter" and not self.degraded \
-                    and (lanes >= self.batch
-                         or monotonic_ns() < self._hot_until) \
+                    and (full or monotonic_ns() < self._hot_until) \
                     and not self._rlc_prefilter(bs, lanes):
                 # all-garbage chunk under ingest saturation: shed the
                 # whole chunk at MSM cost — an all-False verdict array
@@ -874,9 +907,11 @@ class VerifyTile:
             self.metrics["lanes"] += lanes
             # only a counted device dispatch skips programs; the jnp
             # path, the mesh and host verdicts count the whole batch
-            live = (lanes if self._counted
-                    and not isinstance(fut, np.ndarray) else self.batch)
+            dev = self._counted and not isinstance(fut, np.ndarray)
+            live, width = (lanes, self._width) if dev \
+                else (self.batch, self.batch)
             self.metrics["kernel_programs"] += -(-live // self._grain)
+            self.metrics["staged_lanes"] += width
             chunks.append((fut, bs.txn[:lanes].copy()))
             t = st.add(_DISPATCH, t)
         # residence of the txns that reached a device lane: each waited

@@ -112,10 +112,11 @@ class ScriptedFut:
         return self.v
 
 
-def _script_backend(tile, counts=None):
+def _script_backend(tile, counts=None, shipped=None):
     """Swap the tile's jit for a scripted fake AFTER warmup: records
     each dispatch's transfer shape (and, given `counts`, its live-lane
-    count) and returns a ScriptedFut."""
+    count; given `shipped`, a copy of the bytes it shipped) and returns
+    a ScriptedFut."""
     futs = []
     shapes = []
     flat_len = tile._bufsets[0].flat.shape[0]
@@ -124,6 +125,8 @@ def _script_backend(tile, counts=None):
         shapes.append(tuple(np.asarray(flat).shape))
         if counts is not None:
             counts.append(n)
+        if shipped is not None:
+            shipped.append(np.array(flat, np.uint8))
         fut = ScriptedFut(np.ones(tile.batch, bool))
         futs.append(fut)
         return fut
@@ -199,9 +202,13 @@ def test_kernel_programs_whole_batch_where_nothing_skips(
     """A dispatch that carries no count (the CPU jnp path, the mesh)
     runs every program, and one answered with host verdicts (degraded)
     skipped nothing on the device: each adds the whole batch's programs
-    to `kernel_programs`, never a skip that did not happen."""
+    to `kernel_programs`, never a skip that did not happen, and the
+    whole batch to `staged_lanes`, never a width that did not ship."""
     monkeypatch.setattr(_shared_tile, "_grain", 8)
-    monkeypatch.setattr(_shared_tile, "_counted", path == "host_verdicts")
+    if path == "host_verdicts":
+        _narrow(_shared_tile, monkeypatch, width=8)
+    else:
+        monkeypatch.setattr(_shared_tile, "_counted", False)
     tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
     futs, _, _ = _script_backend(tile)
     tile.degraded = path == "host_verdicts"
@@ -214,7 +221,174 @@ def test_kernel_programs_whole_batch_where_nothing_skips(
     tile.flush()
     assert tile.metrics["lanes"] == 5
     assert tile.metrics["kernel_programs"] == BATCH // 8
+    assert tile.metrics["staged_lanes"] == BATCH
     assert _collect(out_ring) == [bytes(t) for t in txns[:5]]
+
+
+# -- dispatch width: chunks narrower than the batch ---------------------------
+
+WIDTH = 8
+
+
+def _narrow(tile, monkeypatch, width=WIDTH):
+    """Make the shared tile dispatch as the Pallas path does below a
+    larger batch: staging sets and the assembler's cap at `width`."""
+    monkeypatch.setattr(tile, "_counted", True)
+    monkeypatch.setattr(tile, "_width", width)
+    monkeypatch.setattr(tile, "_bufsets", tile._stage_sets())
+
+
+def _lanes(flat, width):
+    """(lens, sigs) of a shipped transfer laid out at `width` lanes."""
+    ln = flat[:4 * width].view(np.int32)
+    sig = flat[4 * width:68 * width].reshape(width, 64)
+    return ln, sig
+
+
+def _multisig(i: int, m: int) -> bytes:
+    """A parseable txn with m signatures (bytes only: the scripted
+    backend decides its verdict)."""
+    from firedancer_tpu.protocol.txn import build_message, build_txn
+    signers = [bytes([i, j + 1]) + bytes(30) for j in range(m)]
+    msg = build_message(signers, [bytes(32)], b"\x11" * 32,
+                        [(m, bytes([0]), b"x")])
+    return build_txn([bytes([i, j]) + bytes(62) for j in range(m)], msg)
+
+
+def test_dispatch_width_from_the_batch():
+    """One SHA-512 program's lanes below a larger multiple of them,
+    else the batch."""
+    from firedancer_tpu.ops.pallas_sha import TBATCH
+    from firedancer_tpu.tiles.verify import dispatch_width
+    assert TBATCH == 1024
+    assert dispatch_width(8192, 256, TBATCH) == 1024
+    assert dispatch_width(3072, 256, TBATCH) == 1024
+    assert dispatch_width(1024, 256, TBATCH) == 1024   # the batch itself
+    assert dispatch_width(2500, 256, TBATCH) == 2500   # not a multiple
+    assert dispatch_width(8192, 2048, TBATCH) == 8192  # off the grain
+    assert dispatch_width(16, 256, TBATCH) == 16
+
+
+@pytest.mark.parametrize("k", [1, 5, WIDTH])
+def test_dispatch_ships_its_width_with_the_live_count(
+        wksp, txns, _shared_tile, monkeypatch, k):
+    """A counted dispatch of k live lanes ships its whole staging set,
+    width x row bytes laid out for that width (lens, then signatures),
+    with every lane past k dead, and passes n = k."""
+    _narrow(_shared_tile, monkeypatch)
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    counts, shipped = [], []
+    futs, shapes, _ = _script_backend(tile, counts, shipped)
+    for t in txns[:k]:                    # single-signer: one lane each
+        in_ring.publish(t, sig=1)
+    tile.poll_once()
+    row = tile._bufsets[0].flat.shape[0] // WIDTH
+    assert shapes == [(WIDTH * row,)] and counts == [k]
+    ln, sig = _lanes(shipped[0], WIDTH)
+    assert ln[:k].tolist() == [len(t) - 65 for t in txns[:k]]
+    assert not ln[k:].any()
+    assert [bytes(s) for s in sig[:k]] == [bytes(t[1:65]) for t in txns[:k]]
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert tile.metrics["staged_lanes"] == WIDTH
+    assert _collect(out_ring) == [bytes(t) for t in txns[:k]]
+
+
+def test_lanes_past_the_width_ship_in_chunks_of_whole_txns(
+        wksp, _shared_tile, monkeypatch):
+    """Candidates that need more lanes than the width ship in several
+    dispatches of that width, each holding whole txns in order (no
+    txn's signatures split), all from one gather; `staged_lanes` sums
+    the widths shipped."""
+    _narrow(_shared_tile, monkeypatch)
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    sigs = [3, 3, 3, 3, 3, 2, 3]          # 20 lanes: 6, 6, then 3 + 2 + 3
+    batch = [_multisig(i + 1, m) for i, m in enumerate(sigs)]
+    counts, shipped = [], []
+    futs, shapes, _ = _script_backend(tile, counts, shipped)
+    for t in batch:
+        in_ring.publish(t, sig=1)
+    tile.poll_once()
+    row = tile._bufsets[0].flat.shape[0] // WIDTH
+    assert shapes == [(WIDTH * row,)] * 3
+    assert counts == [6, 6, 8]
+    want = [t[1 + 64 * j:1 + 64 * (j + 1)]
+            for t, m in zip(batch, sigs) for j in range(m)]
+    got = []
+    for flat, n in zip(shipped, counts):
+        _, sig = _lanes(flat, WIDTH)
+        got += [bytes(s) for s in sig[:n]]
+    assert got == want                    # whole txns, in order
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert tile.metrics["batches"] == 3 and tile.metrics["lanes"] == 20
+    assert tile.metrics["staged_lanes"] == 3 * WIDTH
+    assert _collect(out_ring) == batch
+
+
+def test_chunks_of_the_batches_in_flight_never_wait_on_each_other(
+        wksp, _shared_tile, monkeypatch):
+    """With `inflight` batches of chunks scripted unresolved, every
+    chunk still finds a free staging set: no dispatch waits on another
+    chunk's verdicts, and all retire once ready."""
+    _narrow(_shared_tile, monkeypatch)
+    tile, in_ring, out_ring = _mk_tile(wksp, _tile=_shared_tile)
+    monkeypatch.setattr(tile, "device_timeout_s", 0.05)
+    assert tile.inflight == 2 and len(tile._bufsets) == 6
+    futs, _, _ = _script_backend(tile)
+    gathers = [[_multisig(i, 3) for i in range(g, g + 5)]
+               for g in (1, 6)]           # 15 lanes: chunks of 6, 6, 3
+    for batch in gathers:
+        for t in batch:
+            in_ring.publish(t, sig=1)
+        tile.poll_once()
+    assert len(futs) == 6 and len(tile._pending) == 2
+    assert not tile.degraded and tile.metrics["device_errors"] == 0
+    for f in futs:
+        f.ready = True
+    tile.flush()
+    assert not tile._pending
+    assert _collect(out_ring) == gathers[0] + gathers[1]
+
+
+def test_boot_warms_the_dispatch_width(_shared_tile, monkeypatch):
+    """The boot warmup ships one full staging set at the dispatch
+    width, so nothing compiles mid-run."""
+    import jax.numpy as jnp
+    _narrow(_shared_tile, monkeypatch)
+    bs = _shared_tile._bufsets[0]
+    row = bs.flat.shape[0] // WIDTH
+    seen = []
+
+    def fake_fn(flat, n):
+        seen.append((flat.shape, int(n)))
+        return jnp.zeros(flat.shape[0] // row, bool)
+    monkeypatch.setattr(_shared_tile, "_fn", fake_fn)
+    assert _shared_tile._warmup_once(bs) is None
+    assert seen == [((WIDTH * row,), WIDTH)]
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_uncounted_paths_dispatch_the_whole_batch(wksp, monkeypatch,
+                                                  devices):
+    """The CPU's jnp path and the mesh take no count and dispatch the
+    whole batch, whatever width the batch would give a counted path."""
+    import jax
+
+    def warm(self, bs):
+        self._warm_shards = 1
+        return None
+    monkeypatch.setattr(VerifyTile, "_warmup_once", warm)
+    tile = VerifyTile(Ring.create(wksp, depth=64, mtu=1280),
+                      Ring.create(wksp, depth=64, mtu=1280),
+                      Tcache(wksp, depth=64), batch=2048,
+                      devices=devices)
+    assert tile.devices == min(devices, len(jax.devices()))
+    assert not tile._counted
+    assert tile._width == 2048
+    assert all(len(bs.txn) == 2048 for bs in tile._bufsets)
 
 
 def test_coalesce_holds_subfull_until_lane_budget_fills(wksp, txns, _shared_tile):

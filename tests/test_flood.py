@@ -207,6 +207,45 @@ def test_forged_flood_sheds_garbage_chunks_mixed_never_collateral(
     assert tile.metrics["tx"] == 4     # nothing new forwarded
 
 
+def test_prefilter_saturation_is_the_gathers_not_a_chunks(
+        wksp, monkeypatch):
+    """Dispatched in chunks narrower than the batch (the Pallas path's
+    width): a full chunk from a sub-full gather in peacetime skips the
+    equation, as a sub-full batch does; a full gather gates each of its
+    chunks, each equation padded to the width, not the batch."""
+    import jax.numpy as jnp
+    width = 8
+    widths = []
+
+    def rlc(sig, *rest):
+        widths.append(len(sig))
+        return host_rlc(sig, *rest)
+    tile, in_ring, out_ring = _mk_prefilter_tile(wksp, monkeypatch,
+                                                 rlc_fn=rlc)
+    monkeypatch.setattr(tile, "_counted", True)
+    monkeypatch.setattr(tile, "_width", width)
+    monkeypatch.setattr(tile, "_bufsets", tile._stage_sets())
+    monkeypatch.setattr(tile, "_bufset_fut", [None] * len(tile._bufsets))
+    monkeypatch.setattr(tile, "_fn", lambda flat, n: jnp.zeros(width, bool))
+    for i, f in enumerate(attack_frames("flood_forged", width, seed=11)):
+        in_ring.publish(f, sig=i)
+    tile._hot_until = 0
+    _drain(tile)
+    m = tile.metrics
+    assert m["batches"] == 1 and m["lanes"] == width   # one full chunk
+    assert m["rlc_lanes"] == 0 and m["rlc_shed"] == 0  # filter idle
+    assert m["verify_fail"] == width
+
+    for i, f in enumerate(attack_frames("flood_forged", BATCH, seed=13,
+                                         pool=BATCH)):
+        in_ring.publish(f, sig=100 + i)
+    _drain(tile)                       # one full gather: 4 chunks
+    assert m["rlc_lanes"] == BATCH and m["rlc_shed"] == BATCH
+    assert m["batches"] == 1           # every chunk shed, none strict
+    assert widths and set(widths) == {width}
+    assert m["tx"] == 0 and _collect(out_ring) == []
+
+
 def test_duplicate_storm_earns_no_device_work(wksp, monkeypatch):
     """flood_dup: one valid txn replayed — every copy past the first
     dies in ha-dedup / the in-flight reservation, and the storm never

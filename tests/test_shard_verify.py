@@ -75,6 +75,7 @@ def test_verify_tile_shard_map_multidevice():
         tile = VerifyTile(in_ring, out_ring, tc, batch=16,
                           devices=len(jax.devices()))
         assert tile.devices >= 2
+        assert tile._width == 16          # the mesh dispatches the batch
         txns = make_signed_txns(12, seed=3)
         for i, t in enumerate(txns):
             in_ring.publish(t, sig=i)

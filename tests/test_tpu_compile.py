@@ -3,7 +3,8 @@
 Every other test runs the kernels on the CPU, in interpret mode or with a
 jnp stand-in for the Mosaic roll, so none of them lowers a kernel for
 the chip. Here each kernel of the sigverify hot path is compiled at the
-bench width (batch 8192, msg_len 1232) for one chip of a described,
+bench width (batch 8192, msg_len 1232; the counted strict call also at
+the verify tile's dispatch width, 1024) for one chip of a described,
 not attached, `v5e:2x2` topology: the TPU compiler refuses here what it
 would refuse on the chip (an unsupported primitive in the kernel, a
 negative roll, too much VMEM). Nothing runs, so this says nothing about
@@ -76,8 +77,12 @@ def _rlc(sig, pub, msg, ln, z):
     return rlc_verify_batch_tpu(sig, pub, msg, ln, z)
 
 
-_SIG = [((B, 64), "uint8"), ((B, 32), "uint8"), ((B, L), "uint8"),
-        ((B,), "int32")]
+def _sig(b):
+    return [((b, 64), "uint8"), ((b, 32), "uint8"), ((b, L), "uint8"),
+            ((b,), "int32")]
+
+
+_SIG = _sig(B)
 
 
 @pytest.mark.parametrize("fn,shapes", [
@@ -85,12 +90,18 @@ _SIG = [((B, 64), "uint8"), ((B, 32), "uint8"), ((B, L), "uint8"),
     (_verify, _SIG),
     # the live-lane count: scalar prefetch and the program guard
     (_verify_counted, _SIG + [((), "int32")]),
+    # the verify tile's dispatch width (tiles/verify.py): one SHA
+    # program, four strict-kernel programs
+    (_verify_counted, _sig(1024) + [((), "int32")]),
 ], ids=["pallas_sha.sha512", "pallas_ed.verify_batch",
-        "pallas_ed.verify_batch.n_live"])
+        "pallas_ed.verify_batch.n_live", "pallas_ed.verify_batch.w1024"])
 def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, fn, shapes):
     _compile(one_chip, fn, *shapes)
 
 
 @pytest.mark.slow   # ~4 min of TPU compile on an 8-core host
-def test_rlc_msm_compiles_for_v5e(one_chip, no_compile_cache):
-    _compile(one_chip, _rlc, *_SIG, ((B, 16), "uint8"))
+@pytest.mark.parametrize("b", [B, 1024], ids=["batch", "w1024"])
+def test_rlc_msm_compiles_for_v5e(one_chip, no_compile_cache, b):
+    """At the bench width, and at the verify tile's dispatch width, to
+    which its prefilter pads each chunk."""
+    _compile(one_chip, _rlc, *_sig(b), ((b, 16), "uint8"))
