@@ -1399,59 +1399,6 @@ def _flood_bench():
     sys.stdout.flush()
 
 
-def _autotune_bench():
-    """fdtune offline sweep as a bench stage (opt-in:
-    FDTPU_BENCH_AUTOTUNE=1). Drives tune/search.run_sweep with this
-    file's _e2e_run as the measurement — one topology boot per knob
-    point, the same harness the e2e stage trusts — and persists the
-    winning vector as a provenance-stamped tuned profile next to the
-    BENCH json (loadable via FDTPU_TUNED_PROFILE). The reported
-    tuned_vs_default_tps is >= 1.0 by construction: the default point
-    is always measured and the winner is the argmax including it."""
-    from firedancer_tpu.tune import knob_space
-    from firedancer_tpu.tune.profile import make_profile, save_profile
-    from firedancer_tpu.tune.search import run_sweep
-    count = int(os.environ.get("FDTPU_BENCH_AUTOTUNE_COUNT", "16384"))
-    unique = int(os.environ.get("FDTPU_BENCH_AUTOTUNE_UNIQUE", "256"))
-    points = int(os.environ.get("FDTPU_BENCH_AUTOTUNE_POINTS", "3"))
-    state = os.environ.get(
-        "FDTPU_BENCH_AUTOTUNE_STATE",
-        os.path.join(tempfile.gettempdir(), "fdtune_sweep_state.json"))
-    out_path = os.environ.get("FDTPU_TUNED_PROFILE_OUT",
-                              os.path.join(HERE, "tuned_profile.json"))
-    space = knob_space(None)
-
-    def measure(pt):
-        rec = _e2e_run(
-            count, unique,
-            batch=int(pt.get("verify_batch",
-                             space["verify_batch"]["default"])),
-            coalesce_us=float(pt.get("coalesce_us",
-                                     space["coalesce_us"]["default"])),
-            profile=False)
-        return rec["e2e_tps"]
-
-    res = run_sweep(measure, state, points=points,
-                    log=lambda m: print(f"autotune: {m}",
-                                        file=sys.stderr))
-    doc = make_profile(res["knobs"], res["tuned_tps"],
-                       res["default_tps"],
-                       sweep={"count": count, "unique": unique,
-                              "points": res["points"],
-                              "measured": res["measured"],
-                              "stage": "bench-autotune"})
-    save_profile(doc, out_path)
-    print(json.dumps({
-        "tuned_vs_default_tps": round(res["tuned_vs_default_tps"], 4),
-        "autotune_knobs": res["knobs"],
-        "autotune_default_tps": round(res["default_tps"], 1),
-        "autotune_tuned_tps": round(res["tuned_tps"], 1),
-        "autotune_points": res["points"],
-        "autotune_profile": out_path,
-    }))
-    sys.stdout.flush()
-
-
 def _run_child(env_extra: dict, timeout_s: float,
                require_key: str | None = "metric"):
     """Spawn bench.py as a child with extra env; return the last JSON
@@ -1489,9 +1436,6 @@ def main():
         return
     if os.environ.get("FDTPU_BENCH_CATCHUP_CHILD") == "1":
         _catchup_bench()
-        return
-    if os.environ.get("FDTPU_BENCH_AUTOTUNE_CHILD") == "1":
-        _autotune_bench()
         return
     if os.environ.get("FDTPU_BENCH_CHILD") == "1":
         _child_bench()
@@ -1598,27 +1542,6 @@ def main():
                     result[k] = v
         except Exception as e7:  # noqa: BLE001
             result["catchup_error"] = f"{e7!r}"[:300]
-
-    # fdtune autotune stage (r20): OPT-IN (a full sweep is many e2e
-    # boots — minutes, not seconds), unlike the skip-style stages
-    # above. Runs the offline knob sweep through _e2e_run, persists
-    # the tuned profile, and records tuned_vs_default_tps (gated >=
-    # 1.0 by fdbench). A killed sweep resumes: the child's checkpoint
-    # (FDTPU_BENCH_AUTOTUNE_STATE) survives across runs.
-    if os.environ.get("FDTPU_BENCH_AUTOTUNE") == "1":
-        try:
-            env = {"FDTPU_BENCH_AUTOTUNE_CHILD": "1"}
-            at = _run_child(
-                env,
-                float(os.environ.get("FDTPU_BENCH_AUTOTUNE_TIMEOUT",
-                                     "1800")),
-                require_key="tuned_vs_default_tps")
-            for k, v in at.items():
-                if k.startswith("autotune_") \
-                        or k == "tuned_vs_default_tps":
-                    result[k] = v
-        except Exception as e8:  # noqa: BLE001
-            result["autotune_error"] = f"{e8!r}"[:300]
 
     # multichip layout stanza (ROADMAP 1b): the same machine-readable
     # candidate-layout record dryrun_multichip prints into the

@@ -101,14 +101,6 @@ Schema:
     [witness.stage.kernel_vps]   # per-stage override: enable,
     timeout_s = 900.0            #  timeout_s, cmd (argv), env
 
-    [tune]                   # fdtune knob space + controller policy
-    enable = true            #  (tune/__init__.py): topo.build carves
-    cooldown_s = 2.0         #  the shm knob mailbox, the controller
-    hysteresis = 0.25        #  tile steers runtime knobs through it;
-                             #  [tune.knob.<name>] overrides bounds.
-                             #  FDTPU_TUNED_PROFILE overlays a sweep's
-                             #  tuned profile onto the declared tiles
-
     [[tile.chaos.events]]    # seeded fault plan (utils/chaos.py):
     action = "crash"         #  crash | freeze_hb | wedge | stall_fseq
     at_rx = 24               #  | fail_dispatch (verify tile); fire at
@@ -143,7 +135,7 @@ except ModuleNotFoundError:          # py<3.11
 
 _TOP_SECTIONS = {"topology", "link", "tcache", "tile", "trace", "slo",
                  "prof", "shed", "witness", "funk", "replay",
-                 "snapshot", "flight", "tune"}
+                 "snapshot", "flight"}
 
 
 def _deep_merge(base: dict, over: dict) -> dict:
@@ -194,7 +186,7 @@ def load_config(*paths, overrides: dict | None = None) -> dict:
                                               layer[key], str(p))
         for key in ("topology", "trace", "slo", "prof", "shed",
                     "witness", "funk", "replay", "snapshot",
-                    "flight", "tune"):
+                    "flight"):
             if key in layer:
                 merged = _deep_merge(cfg.get(key, {}), layer[key])
                 if key == "slo" and "target" in layer[key]:
@@ -291,19 +283,11 @@ def build_topology(cfg: dict, name: str | None = None):
     flight_cfg = cfg.get("flight")
     if flight_cfg is not None:
         normalize_flight(flight_cfg)
-    # [tune] autotuning knob space + controller policy — same gate
-    # (tune/__init__ is the one validator; topo.build carves the knob
-    # mailbox when enabled)
-    from ..tune import normalize_tune
-    tune_cfg = cfg.get("tune")
-    if tune_cfg is not None:
-        normalize_tune(tune_cfg)
     topo = Topology(name or top.get("name", f"cfg{os.getpid()}"),
                     wksp_size=int(top.get("wksp_size", 1 << 26)),
                     trace=trace_cfg, slo=slo_cfg, prof=prof_cfg,
                     shed=shed_cfg, funk=funk_cfg, replay=replay_cfg,
-                    snapshot=snap_cfg, flight=flight_cfg,
-                    tune=tune_cfg)
+                    snapshot=snap_cfg, flight=flight_cfg)
     for ln in cfg.get("link", []):
         topo.link(ln["name"], depth=int(ln.get("depth", 128)),
                   mtu=int(ln.get("mtu", 1280)))
@@ -337,11 +321,4 @@ def build_topology(cfg: dict, name: str | None = None):
                 args["cpu_idx"] = int(cpu0)
             topo.tile(t["name"], t["kind"], ins=t.get("ins", ()),
                       outs=t.get("outs", ()), **args)
-    # FDTPU_TUNED_PROFILE: overlay a sweep's tuned knob values onto the
-    # declared tiles before build (tune/profile.py checks provenance;
-    # config keys the profile does not carry stay authoritative)
-    prof_path = os.environ.get("FDTPU_TUNED_PROFILE")
-    if prof_path:
-        from ..tune.profile import apply_profile, load_profile
-        apply_profile(topo, load_profile(prof_path))
     return topo

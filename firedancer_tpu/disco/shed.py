@@ -158,26 +158,6 @@ def effective_shed(topo_cfg: dict | None,
     return base
 
 
-def slo_breach_count(plan: dict, wksp) -> int:
-    """Read the topology's metric tile's slo_breach gauge (0 when no
-    metric tile / no SLO engine) — the cross-tile overload signal: an
-    [slo] breach anywhere trips ingest tiles into shed mode, read-side
-    only at housekeeping cadence."""
-    from . import topo as topo_mod
-    for tn, spec in plan.get("tiles", {}).items():
-        if spec.get("kind") != "metric":
-            continue
-        names = spec.get("metrics_names", [])
-        if "slo_breach" not in names:
-            continue
-        try:
-            vals = topo_mod.read_metrics(wksp, plan, tn)
-            return int(vals[names.index("slo_breach")])
-        except Exception:        # noqa: BLE001 — teardown race
-            return 0
-    return 0
-
-
 class PeerGate:
     """The per-tile policing gate: token buckets + bounded peer table
     + stake-weighted overload shedding. One instance per ingest tile
@@ -186,14 +166,11 @@ class PeerGate:
 
     __slots__ = ("rate", "burst", "max_peers", "min_stake", "hold_ns",
                  "stakes", "peers", "overload_until", "shed_total",
-                 "shed_rate", "shed_unstaked", "shed_drop", "evicted",
-                 "base_rate", "tighten")
+                 "shed_rate", "shed_unstaked", "shed_drop", "evicted")
 
     def __init__(self, cfg: dict):
         cfg = normalize_shed(cfg)
         self.rate = cfg["rate_pps"]
-        self.base_rate = self.rate     # config value; `rate` is the
-        self.tighten = 0               # tighten-scaled effective rate
         self.burst = cfg["burst"]
         self.max_peers = cfg["max_peers"]
         self.min_stake = cfg["min_stake"]
@@ -245,17 +222,6 @@ class PeerGate:
     def overloaded(self, now: int | None = None) -> bool:
         return (now if now is not None
                 else monotonic_ns()) < self.overload_until
-
-    def set_tighten(self, level: int):
-        """fdtune shed_tighten knob: scale every peer's admit rate to
-        base_rate/(1+level) — level 0 restores the config rate. Burst
-        and the peer table are untouched, so loosening is instant and
-        the knob composes with (does not replace) overload mode."""
-        level = max(0, int(level))
-        if level == self.tighten:
-            return
-        self.tighten = level
-        self.rate = self.base_rate / (1 + level)
 
     # -- admission -----------------------------------------------------------
 

@@ -522,108 +522,39 @@ class SloEngine:
             } for st in self.targets
         }
 
-    def pressure(self) -> dict:
-        """The shared pressure roll-up (same shape as
-        PressureProbe.poll), from the engine's own state — the metric
-        tile gets the true fast-window burn fraction instead of the
-        cross-process breach-counter approximation."""
-        if getattr(self, "_probe", None) is None:
-            self._probe = PressureProbe(self.plan, self.wksp)
-        bp_delta, worst = self._probe.link_pressure()
-        breached = self.breached
-        return {"breached": breached,
-                "burn": max((st.fast_frac for st in self.targets),
-                            default=0.0),
-                "bp_delta": bp_delta, "worst_link": worst,
-                "overloaded": bool(breached)}
-
 
 # ---------------------------------------------------------------------------
-# the cross-process pressure roll-up (fdtune / shed overload coupling)
+# the cross-process overload probe (shed overload coupling)
 # ---------------------------------------------------------------------------
 
 class PressureProbe:
     """ONE definition of \"the topology is under pressure\", readable
     from any tile at housekeeping cadence: the metric tile's
-    slo_breach gauge (is any objective burning NOW), the slo_breaches
-    counter delta (did a breach edge land since the last poll — the
-    cross-process burn approximation), and the worst per-link producer
-    backpressure delta with its link name (WHERE the topology is
-    saturating). Shared by the ingest doors' overload polling
-    (disco/tiles._shed_slo_poll) and the fdtune controller's decision
-    loop, so \"overloaded\" means the same thing to both."""
+    slo_breach gauge (is any objective burning NOW). Read by the
+    ingest doors' overload polling (disco/tiles._shed_slo_poll)."""
 
     def __init__(self, plan: dict, wksp):
         self.plan, self.wksp = plan, wksp
         self._metric_tile = None
-        self._breach_idx = self._breaches_idx = None
+        self._breach_idx = None
         for tn, spec in plan.get("tiles", {}).items():
             if spec.get("kind") != "metric":
                 continue
             names = spec.get("metrics_names", [])
-            if "slo_breach" in names and "slo_breaches" in names:
+            if "slo_breach" in names:
                 self._metric_tile = tn
                 self._breach_idx = names.index("slo_breach")
-                self._breaches_idx = names.index("slo_breaches")
                 break
-        self._link_offs = {
-            ln: li["prod_metrics_off"]
-            for ln, li in plan.get("links", {}).items()
-            if li.get("prod_metrics_off") is not None}
-        self._last_bp: dict[str, int] = {}
-        self._last_breaches: int | None = None
 
-    def _gauge(self) -> tuple[int, int]:
-        """(slo_breach gauge, slo_breaches counter) — (0, 0) when the
-        topology has no metric tile / no SLO engine."""
+    def overloaded(self) -> bool:
+        """Is any objective burning right now (one metric-tile read);
+        False when the topology has no metric tile / no SLO engine."""
         if self._metric_tile is None:
-            return 0, 0
+            return False
         from . import topo as topo_mod
         try:
             vals = topo_mod.read_metrics(self.wksp, self.plan,
                                          self._metric_tile)
-            return (int(vals[self._breach_idx]),
-                    int(vals[self._breaches_idx]))
+            return int(vals[self._breach_idx]) > 0
         except Exception:        # noqa: BLE001 — teardown race
-            return 0, 0
-
-    def link_pressure(self) -> tuple[int, str | None]:
-        """(worst per-link producer-backpressure delta since the last
-        poll, that link's name) — the saturating-hop attribution."""
-        import numpy as np
-        from .metrics import LINK_PROD_COUNTERS, LINK_PROD_U64
-        bp_i = LINK_PROD_COUNTERS.index("backpressure")
-        worst_delta, worst_link = 0, None
-        for ln, off in self._link_offs.items():
-            try:
-                raw = self.wksp.view(off, LINK_PROD_U64 * 8) \
-                    .view(np.uint64).copy()
-            except Exception:    # noqa: BLE001 — teardown race
-                continue
-            bp = int(raw[bp_i])
-            delta = bp - self._last_bp.get(ln, bp)
-            self._last_bp[ln] = bp
-            if delta > worst_delta:
-                worst_delta, worst_link = delta, ln
-        return worst_delta, worst_link
-
-    def overloaded(self) -> bool:
-        """The cheap form for ingest-door polling: is any objective
-        burning right now (one metric-tile read, no link scan)."""
-        return self._gauge()[0] > 0
-
-    def poll(self) -> dict:
-        """One pressure sample: {breached, burn, bp_delta, worst_link,
-        overloaded}. `burn` is 1.0 when a breach edge landed since the
-        last poll (new slo_breaches), else 0 — the cross-process
-        approximation of the engine's fast-window fraction."""
-        breached, breaches = self._gauge()
-        burn = 0.0
-        if self._last_breaches is not None and \
-                breaches > self._last_breaches:
-            burn = 1.0
-        self._last_breaches = breaches
-        bp_delta, worst = self.link_pressure()
-        return {"breached": breached, "burn": burn,
-                "bp_delta": bp_delta, "worst_link": worst,
-                "overloaded": bool(breached)}
+            return False

@@ -388,16 +388,6 @@ class VerifyAdapter:
             landed = self._capture.poll()
             if self._capture.active != was or landed:
                 self.stages.add(_STAGE_CAPTURE, t0)
-        knobs = getattr(self.ctx, "knobs", None)
-        if knobs is not None:
-            v = knobs.get("coalesce_us")
-            if v is not None:
-                self.tile.set_coalesce_ns(v * 1_000)
-            v = knobs.get("bulk_prefilter")
-            if v is not None and self.tile.mode == "bulk_prefilter":
-                # arming/relaxing the shed path is runtime-safe; the
-                # MODE (compiled kernel family) never switches live
-                self.tile.prefilter_shed = bool(v)
 
     def on_halt(self):
         if self._capture is not None:
@@ -720,13 +710,6 @@ class PackAdapter:
             self._slot_t0 = time.monotonic()
             self.m["blocks"] += 1
             self.cur_slot += 1
-        knobs = getattr(self.ctx, "knobs", None)
-        if knobs is not None:
-            v = knobs.get("pack_wave")
-            if v is not None:
-                # wave is read per poll; shrinking only throttles NEW
-                # microblocks, outstanding ones drain via completions
-                self.wave = max(1, v)
 
     def in_seqs(self):
         return dict(self.seqs)
@@ -1502,15 +1485,6 @@ class BankAdapter:
             # that never completed, no partial commits in the store
             self.fanout.halt()
 
-    def housekeeping(self):
-        knobs = getattr(self.ctx, "knobs", None)
-        if knobs is not None:
-            v = knobs.get("bank_wave")
-            if v is not None:
-                # wave is the per-poll microblock gather depth; the
-                # in-flight wave is unaffected, the next gather shrinks
-                self.wave = max(1, v)
-
     def in_seqs(self):
         s = {self.in_link: self.seq}
         if self.fanout is not None:
@@ -1601,15 +1575,6 @@ class ExecAdapter:
             publish_wave(self.out, self.out_fseqs, comps,
                          cnc=getattr(self.ctx, "cnc", None))
         return n
-
-    def housekeeping(self):
-        knobs = getattr(self.ctx, "knobs", None)
-        if knobs is not None:
-            v = knobs.get("exec_dispatch")
-            if v is not None:
-                # per-poll gather depth only — frames already gathered
-                # this poll finish, so shrinking takes one poll
-                self.batch = max(1, v)
 
     def in_seqs(self):
         return {self.in_link: self.seq}
@@ -1755,12 +1720,9 @@ def _shed_for(ctx, args):
 
 def _shed_slo_poll(ctx, gate):
     """Cross-tile overload coupling, polled at housekeeping cadence:
-    an [slo] breach anywhere (the metric tile's slo_breach gauge, via
-    the shared PressureProbe roll-up — the same overload definition
-    the fdtune controller steers by) trips this tile's door into
-    stake-weighted shedding for the hold window. The fdtune
-    shed_tighten knob rides the same poll: the controller's posted
-    level scales this door's per-peer admit rate."""
+    an [slo] breach anywhere (the metric tile's slo_breach gauge, read
+    through PressureProbe) trips this tile's door into stake-weighted
+    shedding for the hold window."""
     if gate is None:
         return
     probe = getattr(ctx, "_pressure_probe", None)
@@ -1769,11 +1731,6 @@ def _shed_slo_poll(ctx, gate):
         probe = ctx._pressure_probe = PressureProbe(ctx.plan, ctx.wksp)
     if probe.overloaded():
         gate.trip_overload()
-    knobs = getattr(ctx, "knobs", None)
-    if knobs is not None:
-        v = knobs.get("shed_tighten")
-        if v is not None:
-            gate.set_tighten(v)
 
 
 @register("sock")
@@ -3032,44 +2989,6 @@ class FlightAdapter:
 
     def metrics_items(self):
         return dict(self.recorder.metrics)
-
-
-@register("controller")
-class ControllerAdapter:
-    """fdtune adaptive-controller tile (r20): the knob mailbox's single
-    writer. No links — a pure reader of the shm metrics/SLO plane at
-    housekeeping cadence (tune/controller.py Controller), steering the
-    runtime knob subset and leaving an EV_TUNE trace record per
-    decision (which the flight recorder archives durably). topo.build
-    refuses to boot this kind without an enabled [tune] section, so
-    the Controller constructor's mailbox join cannot fail here.
-
-    args: none — all configuration rides the plan's [tune] section
-    (validated at config load + topo.build + fdlint bad-tune)."""
-
-    METRICS = ["decisions", "reverts", "pressure_pct", "breached",
-               "moves_in_window"]
-    GAUGES = ["pressure_pct", "breached", "moves_in_window"]
-
-    def __init__(self, ctx, args):
-        from ..tune.controller import Controller
-        self.ctx = ctx
-        self.controller = Controller(ctx.plan, ctx.wksp,
-                                     cfg=ctx.plan.get("tune"),
-                                     trace=ctx.trace)
-
-    def housekeeping(self):
-        self.controller.poll()
-
-    def poll_once(self) -> int:
-        return 0
-
-    def metrics_items(self):
-        c = self.controller
-        return {"decisions": c.decisions, "reverts": c.reverts,
-                "pressure_pct": int(c.pressure * 100),
-                "breached": int(c.last.get("breached", 0)),
-                "moves_in_window": len(c._moves)}
 
 
 @register("bundle")

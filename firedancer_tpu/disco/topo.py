@@ -87,8 +87,7 @@ class Topology:
                  prof: dict | None = None, shed: dict | None = None,
                  funk: dict | None = None, replay: dict | None = None,
                  snapshot: dict | None = None,
-                 flight: dict | None = None,
-                 tune: dict | None = None):
+                 flight: dict | None = None):
         self.name = name
         self.wksp_size = wksp_size
         self.links: dict[str, LinkSpec] = {}
@@ -120,10 +119,6 @@ class Topology:
         # [flight] durable telemetry archive (flight/__init__ schema):
         # the recorder tile reads the normalized section off the plan
         self.flight = flight
-        # [tune] autotuning knob space + controller policy
-        # (tune/__init__ schema); enable=true makes build() carve the
-        # shm knob mailbox the controller tile steers through
-        self.tune = tune
 
     def link(self, name: str, depth: int = 128, mtu: int = 1280,
              external: bool = False):
@@ -361,28 +356,6 @@ class Topology:
             from ..flight import normalize_flight as _norm_flight
             plan["flight"] = _norm_flight(self.flight) \
                 if self.flight is not None else None
-            # [tune]: validated here (fail before launch); when enabled
-            # the knob mailbox is carved (single writer: the controller
-            # tile) and the runtime knob order becomes plan ABI —
-            # disabled topologies get NO region and NO plan keys, so
-            # TileCtx.knobs stays None (the fdtrace disabled contract)
-            from ..tune import RUNTIME_KNOBS, normalize_tune \
-                as _norm_tune
-            tune_cfg = _norm_tune(self.tune) \
-                if self.tune is not None else None
-            plan["tune"] = tune_cfg
-            if tune_cfg is not None and tune_cfg["enable"]:
-                from ..runtime import KnobMailbox
-                mb = KnobMailbox.create(w, len(RUNTIME_KNOBS))
-                plan["tune_mailbox_off"] = mb.off
-                plan["tune_knobs"] = list(RUNTIME_KNOBS)
-            has_controller = any(t.kind == "controller"
-                                 for t in self.tiles.values())
-            if has_controller and "tune_mailbox_off" not in plan:
-                raise ValueError(
-                    "controller tile declared but [tune] is missing "
-                    "or disabled — it would have no knob mailbox to "
-                    "steer")
             for tn, t in self.tiles.items():
                 if "shed" in t.args:
                     _norm_shed(t.args["shed"], per_tile=True)
@@ -572,13 +545,6 @@ class TileCtx:
         # — the stem starts a sampler thread only when a region exists
         from ..prof import region_for as _prof_region_for
         self.prof = _prof_region_for(plan, self.wksp, tile_name)
-
-        # fdtune knob mailbox (read side): None unless the plan carved
-        # the mailbox AND this tile's kind consumes a runtime knob —
-        # adapters read their effective knobs once per housekeeping
-        # pass, one attribute check when disabled
-        from ..tune import reader_for as _knob_reader_for
-        self.knobs = _knob_reader_for(plan, self.wksp, tile_name)
 
         # per-link telemetry views (fdmetrics v2): consumer blocks for
         # this tile's in links, producer blocks for its out links —

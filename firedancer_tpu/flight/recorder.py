@@ -45,7 +45,7 @@ from .codec import (FRAME_SZ, KIND_HIST, KIND_LINK, KIND_MARK,
 # wait/work/consume spans stay shm-only — the archive is history, not
 # a second trace ring)
 _TRACE_KEEP = ("boot", "halt", "fail", "chaos", "watchdog", "restart",
-               "down", "slo", "cpu_fallback", "compile", "tune")
+               "down", "slo", "cpu_fallback", "compile")
 
 
 class FlightRecorder:

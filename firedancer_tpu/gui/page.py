@@ -74,8 +74,6 @@ border-radius:3px;padding:0 6px;margin:1px 4px 1px 0;font-size:11px}
 <div class="kpi"><div class="kv" id="ktiles">-</div><div class="kl">tiles up</div></div>
 <div class="kpi" id="kcatch" hidden><div class="kv" id="kbehind">-</div>
 <div class="kl">slots behind <span id="kcdetail"></span></div></div>
-<div class="kpi" id="ktune" hidden><div class="kv" id="kpress">-</div>
-<div class="kl">tune pressure <span id="ktdetail"></span></div></div>
 </div>
 <div id="prov" hidden></div>
 <nav>
@@ -254,21 +252,6 @@ function applyDelta(d){
    det="restore "+cu.restore_pct+"%";
   if(cu.divergent_slot)det="DIVERGED @ slot "+cu.divergent_slot;
   $("kcdetail").textContent="· "+det;}
- /* fdtune panel (controller topologies only: d.tune != null) —
-    what the controller changed, when, and which hop justified it */
- const tu=d.tune;
- $("ktune").hidden=!tu;
- if(tu){
-  $("kpress").textContent=(tu.pressure_pct||0)+"%";
-  $("kpress").classList.toggle("bad",(tu.pressure_pct||0)>=50);
-  const steered=Object.entries(tu.knobs||{})
-   .filter(([k,v])=>v.steered).map(([k,v])=>k+"="+v.value);
-  let det=tu.decisions+" moves";
-  if(steered.length)det+=" · "+steered.join(" ");
-  const rec=(tu.recent||[]).slice(-1)[0];
-  if(rec)det+=" · last "+rec.knob+"->"+rec.value+
-   (rec.hop?" ["+rec.hop+"]":"");
-  $("ktdetail").textContent="· "+det;}
  /* slo tab */
  if(d.slo){$("sbr").textContent=d.slo.breach||0;
   $("sbs").textContent=d.slo.breaches||0;

@@ -170,10 +170,6 @@ class DeltaSource:
             (tn for tn, s in plan["tiles"].items()
              if s["kind"] == "snapld"), None)
         self._replay_win: deque = deque()    # (ns, txns) samples
-        # fdtune surface (r20): the controller tile, if steering
-        self._controller_tile = next(
-            (tn for tn, s in plan["tiles"].items()
-             if s["kind"] == "controller"), None)
 
     # -- TPS (satellite fix: tempo.monotonic_ns, THE topology clock —
     # the rate must agree with trace/prof timelines, not drift on a
@@ -292,50 +288,6 @@ class DeltaSource:
                     100.0 * min(sm.get("bytes", 0), total) / total, 1)
         return out
 
-    # -- fdtune panel (r20 controller surface) ------------------------------
-
-    def _tune(self) -> dict | None:
-        """Tuning panel: what the controller changed, when, and which
-        saturating hop justified it — controller counters, the live
-        knob-mailbox state (steered vs config-authoritative), and the
-        recent EV_TUNE decisions off the controller's trace ring. None
-        on a topology with no controller tile (the delta stays lean)."""
-        ct = self._controller_tile
-        names = self.plan.get("tune_knobs")
-        off = self.plan.get("tune_mailbox_off")
-        if ct is None or not names or off is None:
-            return None
-        from ..runtime import KnobMailbox
-        cm = self._tile_metrics(ct)
-        mb = KnobMailbox(self.wksp, off, len(names))
-        knobs = {}
-        for i, n in enumerate(names):
-            value, seq = mb.read(i)
-            knobs[n] = {"value": value if seq else None,
-                        "steered": bool(seq)}
-        out = {
-            "pressure_pct": cm.get("pressure_pct", 0),
-            "breached": cm.get("breached", 0),
-            "decisions": cm.get("decisions", 0),
-            "reverts": cm.get("reverts", 0),
-            "moves_in_window": cm.get("moves_in_window", 0),
-            "knobs": knobs,
-            "recent": [],
-        }
-        if self.plan["tiles"][ct].get("trace_off") is not None:
-            from ..trace import export
-            from ..trace.events import EV_TUNE
-            evs = export.read_rings(self.plan, self.wksp,
-                                    tiles=[ct]).get(ct, [])
-            out["recent"] = [
-                {"ts": e["ts"],
-                 "knob": (names[e["count"]]
-                          if e["count"] < len(names)
-                          else f"knob[{e['count']}]"),
-                 "value": e["arg"], "hop": e["link"]}
-                for e in evs if e["etype"] == EV_TUNE][-8:]
-        return out
-
     def delta(self) -> dict:
         """One protocol delta. Raises on a torn/halting topology —
         callers own the 503/skip policy (the gui tile's summary route
@@ -355,5 +307,4 @@ class DeltaSource:
                 read_link_metrics(self.wksp, self.plan)),
             "slo": self._slo(),
             "catchup": self._catchup(now),
-            "tune": self._tune(),
         }

@@ -452,8 +452,6 @@ SECTION_MIRRORS = (
      "WITNESS_SECTION_KEYS", ("stage",)),
     ("flight", "flight/__init__.py", "FLIGHT_DEFAULTS",
      "FLIGHT_SECTION_KEYS", ()),
-    ("tune", "tune/__init__.py", "TUNE_DEFAULTS",
-     "TUNE_SECTION_KEYS", ()),
 )
 
 _ADAPTERS_SUFFIX = "disco/tiles.py"

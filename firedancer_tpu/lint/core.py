@@ -142,15 +142,6 @@ RULES: dict[str, tuple[str, str, str]] = {
         "incident_window_s, node_id not u16, unknown source family) — "
         "the telemetry-archive config must validate at review, not "
         "when the recorder tile boots"),
-    "bad-tune": (
-        "graph", "error",
-        "[tune] section rejected by the tune/__init__.py schema "
-        "(unknown key with did-you-mean, non-positive interval/"
-        "cooldown/recovery/window, hysteresis outside (0,1), "
-        "cooldown_s < interval_s, [tune.knob.<name>] naming an "
-        "unknown knob or with min > max / default outside bounds), "
-        "or a controller tile is declared without an enabled [tune] "
-        "section to give it a knob mailbox"),
     # -- tile-contract family (lint/contracts.py) ------------------------
     "reserved-metric": (
         "contract", "error",

@@ -87,8 +87,7 @@ def model_from_config(cfg: dict) -> dict:
             "witness": cfg.get("witness"), "funk": cfg.get("funk"),
             "replay": cfg.get("replay"),
             "snapshot": cfg.get("snapshot"),
-            "flight": cfg.get("flight"),
-            "tune": cfg.get("tune")}
+            "flight": cfg.get("flight")}
 
 
 def model_from_topology(topo) -> dict:
@@ -109,8 +108,7 @@ def model_from_topology(topo) -> dict:
             "funk": getattr(topo, "funk", None),
             "replay": getattr(topo, "replay", None),
             "snapshot": getattr(topo, "snapshot", None),
-            "flight": getattr(topo, "flight", None),
-            "tune": getattr(topo, "tune", None)}
+            "flight": getattr(topo, "flight", None)}
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +259,6 @@ def _check_model(model: dict, path: str, lines: _Lines) -> list[Finding]:
     out.extend(_check_replay(model, path))
     out.extend(_check_snapshot(model, path))
     out.extend(_check_flight(model, path))
-    out.extend(_check_tune(model, path, lines))
     return out
 
 
@@ -346,36 +343,6 @@ def _check_flight(model, path) -> list[Finding]:
         except Exception as e:
             out.append(finding("bad-flight", path, 0,
                                f"[flight]: {e}"))
-    return out
-
-
-def _check_tune(model, path, lines) -> list[Finding]:
-    """[tune] section: the tune/__init__.py schema gate (one
-    validator, same as config load and topo.build's mailbox carve) —
-    unknown keys, out-of-range cadences/hysteresis, bad per-knob
-    overrides all land as review-time findings with a did-you-mean.
-    Plus the coherence check topo.build enforces at boot: a controller
-    tile without an enabled [tune] section has no mailbox to steer."""
-    from ..tune import normalize_tune
-    out: list[Finding] = []
-    spec = model.get("tune")
-    cfg = None
-    if spec is not None:
-        try:
-            cfg = normalize_tune(spec)
-        except Exception as e:
-            out.append(finding("bad-tune", path, 0, f"[tune]: {e}"))
-    controllers = [tn for tn, t in model["tiles"].items()
-                   if t["kind"] == "controller"]
-    enabled = bool(cfg and cfg["enable"])
-    if controllers and not enabled and not (spec is not None
-                                            and cfg is None):
-        # (when the section itself failed validation, the bad-tune
-        # schema finding above already owns the problem)
-        _emit(out, lines, "bad-tune", controllers[0],
-              f"controller tile {controllers[0]!r} declared but [tune] "
-              "is missing or disabled — it would have no knob mailbox "
-              "to steer")
     return out
 
 

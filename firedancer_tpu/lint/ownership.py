@@ -41,7 +41,6 @@ from .core import Finding, filter_suppressed, finding
 # from matching unrelated objects
 _TRACE_RECV = re.compile(r"(?:^|\.)_?(?:tr|trace)$")
 _PROF_RECV = re.compile(r"(?:^|\.)_?(?:prof|region)$")
-_MAILBOX_RECV = re.compile(r"(?:^|\.)_?(?:mb|mailbox)$")
 
 # region -> (doc, writer module suffixes). A suffix ending in "/"
 # allows the whole subpackage.
@@ -50,7 +49,7 @@ SHM_REGIONS: dict[str, tuple[str, tuple[str, ...]]] = {
         "a tile's flight-recorder ring (trace/recorder.py); owned by "
         "the recording tile's process",
         ("trace/", "disco/stem.py", "disco/tiles.py", "tiles/",
-         "prof/device.py", "disco/slo.py", "tune/controller.py")),
+         "prof/device.py", "disco/slo.py")),
     "sup-slots": (
         "the supervisor-reserved sup_* metric slots; owned by the "
         "supervisor loop alone — tiles only read them",
@@ -72,12 +71,6 @@ SHM_REGIONS: dict[str, tuple[str, tuple[str, ...]]] = {
         "req/ack); written via ProfRegion APIs from the owning "
         "tile's sampler",
         ("prof/",)),
-    "knob-mailbox": (
-        "the fdtune knob mailbox (runtime/tango.py KnobMailbox); "
-        "single writer per topology — the controller tile's decision "
-        "loop alone posts, every steered adapter only reads its "
-        "slots (tune/__init__.py KnobReader)",
-        ("tune/controller.py",)),
 }
 
 TORN_READ_EXEMPT = ("runtime/tango.py",)
@@ -119,8 +112,6 @@ def _region_of_call(node: ast.Call) -> str | None:
     if name in ("record", "request_capture", "ack_capture") and \
             _PROF_RECV.search(_recv_text(f)):
         return "prof-region"
-    if name == "post" and _MAILBOX_RECV.search(_recv_text(f)):
-        return "knob-mailbox"
     if name in ("rec_write", "rec_remove"):
         for a in node.args:
             if "RESTORE_MARKER" in ast.unparse(a):

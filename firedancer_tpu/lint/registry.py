@@ -98,16 +98,6 @@ SNAPSHOT_SECTION_KEYS = ("path", "every_slots", "min_slot", "compress",
 FLIGHT_SECTION_KEYS = ("dir", "segment_mb", "retain_mb", "hz",
                        "sources", "incident_window_s", "node_id")
 
-# [tune] topology-section keys (mirror of tune/__init__.py
-# TUNE_DEFAULTS / KNOB_KEYS — tests/test_tune.py keeps the mirror
-# honest). [tune.knob.<name>] names resolve against the tune KNOBS
-# catalog; validated by normalize_tune at config load, topo.build
-# (mailbox carve), and the graph analyzer's bad-tune rule.
-TUNE_SECTION_KEYS = ("enable", "interval_s", "cooldown_s", "recovery_s",
-                     "hysteresis", "max_moves", "window_s", "bp_ref",
-                     "knob")
-TUNE_KNOB_KEYS = ("min", "max", "step", "default")
-
 # [witness] topology-section keys (mirror of witness/plan.py
 # WITNESS_DEFAULTS / WITNESS_STAGE_KEYS — tests/test_witness.py keeps
 # the mirror honest). Stage names in `stages` / [witness.stage.<name>]
@@ -198,9 +188,6 @@ TILE_ARGS: dict[str, dict[str, str | None]] = {
     # flight recorder tile (r19): all configuration rides the plan's
     # [flight] section — the adapter reads no args at all
     "flight": {},
-    # adaptive controller tile (r20, fdtune): all configuration rides
-    # the plan's [tune] section — the adapter reads no args at all
-    "controller": {},
     "bundle": {"engine": None, "path": None, "authority": None},
     "plugin": {"sock_path": None, "data_hex_max": None},
     "netlnk": {},

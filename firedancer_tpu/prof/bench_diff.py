@@ -54,10 +54,6 @@ GATE_METRICS = (
     # follower catch-up (r17): snapshot-restore + tail replay over the
     # exec family — the "become a follower" throughput contract
     ("replay_tps", "catch-up replay tps"),
-    # fdtune (r20): the offline sweep's knee ratio — >= 1.0 by
-    # construction (the default point is always in the argmax set), so
-    # ANY regression here means the sweep machinery broke, not noise
-    ("tuned_vs_default_tps", "tuned vs default tps"),
 )
 
 # report-only metrics: lower-is-better (or too noisy to gate), so a

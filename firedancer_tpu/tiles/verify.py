@@ -776,21 +776,6 @@ class VerifyTile:
                             self._hold_sizes[:n].copy(), n,
                             self._hold_segs)
 
-    def set_coalesce_ns(self, ns: int):
-        """Runtime coalesce-window steer (the fdtune coalesce_us
-        knob). Narrowing to 0 flushes any held remainder first so no
-        frags park forever; widening from 0 allocates the hold buffer
-        the constructor skipped on the never-coalescing fast path."""
-        ns = max(0, int(ns))
-        if ns == self._coalesce_ns:
-            return
-        if ns == 0 and self._hold_n:
-            self._flush_hold()
-        if ns and self._hold_buf is None:
-            self._hold_buf = np.zeros((self.batch, self.max_len),
-                                      np.uint8)
-        self._coalesce_ns = ns
-
     def _process_batch(self, buf, sizes, n: int, segs):
         """Parse -> tag -> ha-dedup + batched in-flight reservation ->
         fixed-shape device chunks, dispatched async (the verify

@@ -44,10 +44,8 @@ EV_COMPILE = 18      # instant: jit cache grew — a compile the padding
                      #   count = total compiled variants)
 EV_PROF_CAPTURE = 19  # span: bounded device-trace window (fdprof
                      #   DeviceCapture; count = doorbell req id)
-EV_TUNE = 20         # instant: controller knob decision (fdtune;
-                     #   arg = new knob value, count = knob index into
-                     #   the plan's tune_knobs list, link = the
-                     #   saturating hop that justified the move)
+                     # 20: unused (retired; ids are a ring ABI, never
+                     #   renumbered)
 EV_STAGE = 21        # span: one stage of a tile's batch (count = stage
                      #   id, STAGE_IDS below) — the verify tile's host
                      #   and device stages, a device capture's own
@@ -64,7 +62,7 @@ NAMES = {
     EV_CPU_FALLBACK: "cpu_fallback", EV_CHAOS: "chaos",
     EV_WATCHDOG: "watchdog", EV_RESTART: "restart", EV_DOWN: "down",
     EV_SLO: "slo", EV_COMPILE: "compile",
-    EV_PROF_CAPTURE: "prof_capture", EV_TUNE: "tune",
+    EV_PROF_CAPTURE: "prof_capture",
     EV_STAGE: "stage", EV_STALL: "stall",
 }
 

@@ -73,7 +73,6 @@ _MERGE_RULES = {
     "exec_scale": ((), ("exec_scale",)),
     "flood_soak": (("rlc_prefilter_vps",), ("flood_",)),
     "catchup": (("replay_tps",), ("catchup_",)),
-    "autotune": (("tuned_vs_default_tps",), ("autotune_",)),
 }
 
 

@@ -33,11 +33,6 @@ Stage catalog (plan order — the hash chain follows it):
                    ShmFunk snapshot racing live tail ingest — replay
                    over the exec family against the oracle's pinned
                    bank hashes, measured as replay_tps/catchup_s (r17)
-    autotune       bench.py autotune stage: the fdtune offline knob
-                   sweep through _e2e_run — one topology boot per
-                   config point, resumable checkpoint — persisting a
-                   provenance-stamped tuned profile and
-                   tuned_vs_default_tps (>= 1.0 by construction) (r20)
     multichip      witness/multichip.py: the shard_map layout shootout
                    — per-chip rr tiles vs one mesh tile, measured side
                    by side with per-device memory/occupancy series
@@ -56,7 +51,7 @@ import sys
 # ordered: the sweep runs (and the hash chain links) in this order
 STAGES = ("device_probe", "kernel_vps", "mxu_fmul", "e2e_feed",
           "leader_knee", "exec_scale", "flood_soak", "catchup",
-          "autotune", "multichip")
+          "multichip")
 
 # [witness] section keys (lint/registry.py WITNESS_SECTION_KEYS is the
 # static mirror — tests/test_witness.py keeps it honest)
@@ -209,9 +204,6 @@ _CPU_SMOKE_STAGE_ENV = {
                 "FDTPU_BENCH_CATCHUP_SLOTS": "8",
                 "FDTPU_BENCH_CATCHUP_SNAP_SLOT": "3",
                 "FDTPU_BENCH_CATCHUP_EXEC_TILES": "2"},
-    "autotune": {"FDTPU_BENCH_AUTOTUNE_COUNT": "2048",
-                 "FDTPU_BENCH_AUTOTUNE_UNIQUE": "128",
-                 "FDTPU_BENCH_AUTOTUNE_POINTS": "2"},
 }
 
 
@@ -235,7 +227,6 @@ def default_stage_cmds(repo_root: str,
         "exec_scale": [py, bench],
         "flood_soak": [py, bench],
         "catchup": [py, bench],
-        "autotune": [py, bench],
         "multichip": multi,
     }
 
@@ -248,7 +239,6 @@ _STAGE_CHILD_ENV = {
     "exec_scale": {"FDTPU_BENCH_EXEC_SCALE_CHILD": "1"},
     "flood_soak": {"FDTPU_BENCH_FLOOD_CHILD": "1"},
     "catchup": {"FDTPU_BENCH_CATCHUP_CHILD": "1"},
-    "autotune": {"FDTPU_BENCH_AUTOTUNE_CHILD": "1"},
 }
 
 

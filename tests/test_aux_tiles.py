@@ -41,6 +41,11 @@ def test_ipecho_service_roundtrip():
         sv, ip, port = ipecho_query(("127.0.0.1", a.port))
         assert sv == 5122
         assert ip == "127.0.0.1" and port > 0
+        # the server counts a query after its reply is sent, so the
+        # reply can reach the client first: wait for the count
+        deadline = time.monotonic() + 10.0
+        while a.queries < 1 and time.monotonic() < deadline:
+            time.sleep(0.001)
         assert a.queries == 1
     finally:
         a.on_halt()

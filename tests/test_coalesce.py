@@ -391,6 +391,67 @@ def test_uncounted_paths_dispatch_the_whole_batch(wksp, monkeypatch,
     assert all(len(bs.txn) == 2048 for bs in tile._bufsets)
 
 
+def _skip_warmup(monkeypatch):
+    """Construct VerifyTiles without tracing the verify jit."""
+    def warm(self, bs):
+        self._warm_shards = 1
+        return None
+    monkeypatch.setattr(VerifyTile, "_warmup_once", warm)
+
+
+@pytest.mark.parametrize("coalesce_us", [0.0, 0.0004, 1.0, 200.0])
+def test_hold_buffer_only_with_a_window(wksp, monkeypatch, coalesce_us):
+    """The coalescing window is fixed at construction: a tile whose
+    window rounds to 0 ns dispatches every gather as-is and never
+    allocates the hold buffer; any other window gets one batch of
+    rows up front."""
+    _skip_warmup(monkeypatch)
+    tile = VerifyTile(Ring.create(wksp, depth=64, mtu=1280),
+                      Ring.create(wksp, depth=64, mtu=1280),
+                      Tcache(wksp, depth=64), batch=BATCH,
+                      coalesce_us=coalesce_us)
+    ns = int(coalesce_us * 1e3)
+    assert tile._coalesce_ns == ns
+    if ns:
+        assert tile._hold_buf.shape == (BATCH, tile.max_len)
+    else:
+        assert tile._hold_buf is None
+
+
+@pytest.mark.parametrize("coalesce_us", [0, 200])
+def test_verify_adapter_window_fixed_across_housekeeping(monkeypatch,
+                                                         coalesce_us):
+    """The verify adapter takes the window from the tile's args and
+    its housekeeping passes leave the window and the hold buffer as
+    construction made them."""
+    from firedancer_tpu.disco import Topology
+    from firedancer_tpu.disco.tiles import REGISTRY
+    from firedancer_tpu.disco.topo import TileCtx
+    _skip_warmup(monkeypatch)
+    plan = (Topology(f"cohk{coalesce_us}_{os.getpid()}",
+                     wksp_size=1 << 22)
+            .link("a_b", depth=64).link("b_c", depth=64)
+            .tcache("vtc", depth=64)
+            .tile("a", "synth", outs=["a_b"], count=8, unique=8)
+            .tile("b", "verify", ins=["a_b"], outs=["b_c"],
+                  batch=BATCH, tcache="vtc", coalesce_us=coalesce_us)
+            .tile("c", "sink", ins=["b_c"])).build()
+    ctx = TileCtx(plan, "b")
+    try:
+        adapter = REGISTRY["verify"](ctx, plan["tiles"]["b"]["args"])
+        tile = adapter.tile
+        hold = tile._hold_buf
+        assert tile._coalesce_ns == coalesce_us * 1_000
+        assert (hold is not None) == (coalesce_us > 0)
+        for _ in range(3):
+            adapter.housekeeping()
+        assert tile._coalesce_ns == coalesce_us * 1_000
+        assert tile._hold_buf is hold
+    finally:
+        ctx.close()
+        Workspace.unlink_name(plan["wksp"]["name"])
+
+
 def test_coalesce_holds_subfull_until_lane_budget_fills(wksp, txns, _shared_tile):
     """With a long window, a sub-full gather dispatches nothing; the
     window flushes the instant the lane budget (one compiled batch)

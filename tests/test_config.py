@@ -87,6 +87,41 @@ def test_unknown_section_rejected(tmp_path):
         load_config(p)
 
 
+# a retired [tune] section, in the shapes configs carried it: the
+# disabled default, an enabled controller policy, a knob override
+STALE_TUNE = {
+    "disabled": "[tune]\nenable = false\n",
+    "enabled": "[tune]\nenable = true\ninterval_s = 0.25\n"
+               "cooldown_s = 2.0\n",
+    "knob_override": "[tune.knob.coalesce_us]\nmin = 0\nmax = 2000\n"
+                     "step = 50\n",
+}
+
+
+@pytest.mark.parametrize("body", list(STALE_TUNE.values()),
+                         ids=list(STALE_TUNE))
+def test_stale_tune_section_rejected(cfgdir, body):
+    """[tune] is not a config section: a layer that still carries one
+    is refused at load, never silently ignored."""
+    p = cfgdir / "stale.toml"
+    p.write_text(body)
+    with pytest.raises(ValueError,
+                       match=r"stale.toml: unknown config sections "
+                             r"\['tune'\]"):
+        load_config(cfgdir / "base.toml", p)
+
+
+def test_stale_controller_tile_rejected_as_unknown_kind(cfgdir):
+    """`controller` is not a tile kind: the config's tile gate refuses
+    it by name before anything is built."""
+    p = cfgdir / "stale.toml"
+    p.write_text('[[tile]]\nname = "ctl"\nkind = "controller"\n')
+    cfg = load_config(cfgdir / "base.toml", p)
+    with pytest.raises(ValueError,
+                       match=r"'ctl': unknown kind 'controller'"):
+        build_topology(cfg)
+
+
 def test_unknown_tile_key_rejected_with_hint():
     """A typo'd tile arg used to pass through silently as an arg the
     adapter never reads; the schema gate (key registry shared with

@@ -588,3 +588,76 @@ def test_exec_family_leader_loop_clean():
     finally:
         runner.halt()
         runner.close()
+
+
+# ---------------------------------------------------------------------------
+# the leader adapters' parameters: read once from the tile's args
+# ---------------------------------------------------------------------------
+
+def _args_plan(tag, args):
+    """pack -> bank -> (exec family) wiring, built but never launched:
+    the adapters are constructed in this process from the plan."""
+    from firedancer_tpu.disco import Topology
+    topo = (
+        Topology(f"targs{tag}{os.getpid()}", wksp_size=1 << 24,
+                 funk={"backend": "shm", "heap_mb": 1})
+        .link("dedup_pack", depth=32, mtu=1280)
+        .link("pack_bank0", depth=32, mtu=1 << 15)
+        .link("bank0_done", depth=32, mtu=64)
+        .link("exec_disp0", depth=32, mtu=4096)
+        .link("exec_done0", depth=32, mtu=64)
+        .tile("synth", "synth", outs=["dedup_pack"], count=8, unique=8)
+        .tile("pack", "pack", ins=["dedup_pack", ("bank0_done", False)],
+              outs=["pack_bank0"], txn_in="dedup_pack",
+              bank_links=["pack_bank0"], done_links=["bank0_done"],
+              **args.get("pack", {}))
+        .tile("bank0", "bank", ins=["pack_bank0"], outs=["bank0_done"],
+              exec="stub", **args.get("bank0", {}))
+        .tile("dsynth", "synth", outs=["exec_disp0"], count=8, unique=8)
+        .tile("exec0", "exec", ins=["exec_disp0"], outs=["exec_done0"],
+              **args.get("exec0", {}))
+        .tile("dsink", "sink", ins=["exec_done0"]))
+    return topo.build()
+
+
+# (tile, its arg, the adapter attribute it sets, the value; None = the
+# arg left out, so the adapter's own default)
+ARG_CASES = {
+    "pack_wave": ("pack", "wave", "wave", 3),
+    "pack_wave_wide": ("pack", "wave", "wave", 9),
+    "pack_wave_default": ("pack", "wave", "wave", None),
+    "bank_wave": ("bank0", "wave", "wave", 2),
+    "bank_wave_wide": ("bank0", "wave", "wave", 11),
+    "bank_wave_default": ("bank0", "wave", "wave", None),
+    "exec_depth": ("exec0", "batch", "batch", 5),
+    "exec_depth_wide": ("exec0", "batch", "batch", 13),
+    "exec_depth_default": ("exec0", "batch", "batch", None),
+}
+ARG_DEFAULTS = {"pack": 4, "bank0": 8, "exec0": 8}
+
+
+@pytest.mark.parametrize("tile,arg,attr,value", list(ARG_CASES.values()),
+                         ids=list(ARG_CASES))
+def test_leader_adapter_params_come_from_tile_args(tile, arg, attr,
+                                                   value):
+    """pack's and the bank's `wave` and the exec tile's dispatch depth
+    are read from the plan's tile args at construction, and stay as
+    read while the stem runs its housekeeping passes."""
+    from firedancer_tpu.disco.stem import Stem
+    from firedancer_tpu.disco.tiles import REGISTRY
+    from firedancer_tpu.disco.topo import TileCtx
+    plan = _args_plan(f"{tile}{value}",
+                      {tile: {arg: value}} if value is not None else {})
+    ctx = None
+    try:
+        ctx = TileCtx(plan, tile)
+        spec = plan["tiles"][tile]
+        adapter = REGISTRY[spec["kind"]](ctx, spec["args"])
+        want = ARG_DEFAULTS[tile] if value is None else value
+        assert getattr(adapter, attr) == want
+        Stem(ctx, adapter, hk_interval_s=0.0).run(max_iters=8)
+        assert getattr(adapter, attr) == want
+    finally:
+        if ctx is not None:
+            ctx.close()
+        Workspace.unlink_name(plan["wksp"]["name"])

@@ -484,47 +484,62 @@ def test_flight_section_is_clean_when_valid():
     assert lint_config(cfg, "<fixture>") == []
 
 
-def test_bad_tune_schema_and_did_you_mean():
-    # typo'd [tune] key: the tune/__init__.py schema gate
-    findings = lint_config(_cfg(tune={"intervals": 0.5}), "<fixture>")
-    fires_once(findings, "bad-tune")
-    assert "did you mean 'interval_s'" in findings[0].message
-    # out-of-range policy + a bad knob-override bound
-    fires_once(lint_config(_cfg(tune={"hysteresis": 2.0}),
-                           "<fixture>"), "bad-tune")
-    fires_once(lint_config(
-        _cfg(tune={"knob": {"coalesce_us": {"min": 9, "max": 3}}}),
-        "<fixture>"), "bad-tune")
-    # unknown knob with suggestion
-    findings = lint_config(_cfg(tune={"knob": {"coalesce_u": {}}}),
-                           "<fixture>")
-    fires_once(findings, "bad-tune")
-    assert "did you mean 'coalesce_us'" in findings[0].message
-    # a controller tile with no enabled [tune] has nothing to steer
+# a config that predates the retired runtime-knob plane: fdlint
+# reports it through the rules it already has, no rule of its own
+CLEAN_TOML = """
+[[link]]
+name = "a_b"
+depth = 64
+mtu = 1280
+
+[[tile]]
+name = "src"
+kind = "synth"
+outs = ["a_b"]
+
+[[tile]]
+name = "dst"
+kind = "sink"
+ins = ["a_b"]
+"""
+
+STALE_TUNE = {
+    "disabled": "[tune]\nenable = false\n",
+    "enabled": "[tune]\nenable = true\ninterval_s = 0.25\n",
+    "knob_override": "[tune.knob.coalesce_us]\nmax = 1000\n",
+}
+
+
+@pytest.mark.parametrize("body", list(STALE_TUNE.values()),
+                         ids=list(STALE_TUNE))
+def test_stale_tune_section_is_a_load_failure(tmp_path, body):
+    p = tmp_path / "stale.toml"
+    p.write_text(CLEAN_TOML)
+    assert lint_config_file(str(p)) == []
+    p.write_text(CLEAN_TOML + body)
+    findings = lint_config_file(str(p))
+    fires_once(findings, "dangling-ref")
+    assert "config failed to load" in findings[0].message
+    assert "unknown config sections ['tune']" in findings[0].message
+
+
+def test_stale_controller_tile_fires_unknown_kind():
     cfg = _cfg()
     cfg["tile"].append({"name": "ctl", "kind": "controller"})
-    fires_once(lint_config(cfg, "<fixture>"), "bad-tune")
+    findings = lint_config(cfg, "<fixture>")
+    fires_once(findings, "unknown-kind")
+    assert "'controller'" in findings[0].message
 
 
-def test_tune_section_is_clean_when_valid():
-    cfg = _cfg(tune={"enable": True, "interval_s": 0.25,
-                     "cooldown_s": 1.0, "recovery_s": 2.0,
-                     "hysteresis": 0.25, "max_moves": 4,
-                     "window_s": 5.0, "bp_ref": 100.0,
-                     "knob": {"coalesce_us": {"max": 1000,
-                                              "step": 50}}})
-    cfg["tile"].append({"name": "ctl", "kind": "controller"})
-    assert lint_config(cfg, "<fixture>") == []
-
-
-def test_tune_registry_mirror():
-    """TUNE_SECTION_KEYS/TUNE_KNOB_KEYS mirror the validator's tables
-    — same contract the flight/replay/snapshot mirrors pin."""
-    from firedancer_tpu.lint.registry import (TUNE_KNOB_KEYS,
-                                              TUNE_SECTION_KEYS)
-    from firedancer_tpu.tune import KNOB_KEYS, TUNE_DEFAULTS
-    assert set(TUNE_SECTION_KEYS) == set(TUNE_DEFAULTS)
-    assert set(TUNE_KNOB_KEYS) == set(KNOB_KEYS)
+def test_stale_controller_tile_in_a_file_fires_unknown_kind(tmp_path):
+    """From a TOML file the controller tile still loads (the kind gate
+    is the topology builder's), so fdlint names it, with its line."""
+    p = tmp_path / "stale.toml"
+    p.write_text(CLEAN_TOML + '\n[[tile]]\nname = "ctl"\n'
+                 'kind = "controller"\n')
+    findings = lint_config_file(str(p))
+    fires_once(findings, "unknown-kind")
+    assert findings[0].line > 0
 
 
 def test_flight_registry_mirror():

@@ -3,6 +3,8 @@ the lint/registry key mirror, and the PeerGate policy — token buckets,
 bounded peer table with stake-aware eviction, stake-weighted overload
 shedding with clock-expiry recovery. All host-side, no jax.
 """
+import os
+
 import pytest
 
 from firedancer_tpu.disco.shed import (PeerGate, SHED_DEFAULTS,
@@ -102,7 +104,7 @@ def test_bad_shed_rejected_at_config_load_and_topo_build():
 
 def test_plan_carries_shed_and_breach_reader_is_zero_safe():
     from firedancer_tpu.disco import Topology
-    from firedancer_tpu.disco.shed import slo_breach_count
+    from firedancer_tpu.disco.slo import PressureProbe
     topo = (Topology("shedplan", shed={"rate_pps": 11.0})
             .link("a_b", depth=32)
             .tile("s", "sock", outs=["a_b"])
@@ -112,7 +114,7 @@ def test_plan_carries_shed_and_breach_reader_is_zero_safe():
     assert plan["shed"]["burst"] == SHED_DEFAULTS["burst"]
     # no metric tile in the plan: the overload coupling reads 0, never
     # raises (ingest tiles poll this at housekeeping cadence)
-    assert slo_breach_count(plan, None) == 0
+    assert PressureProbe(plan, None).overloaded() is False
 
 
 # -- PeerGate: token buckets ------------------------------------------------
@@ -211,3 +213,88 @@ def test_count_drop_attributes_drop_newest():
     assert g.shed_unstaked == 1          # only the unstaked peer
     c = g.counters()
     assert c["shed"] == 2 and c["overload"] == 0 and c["peers"] == 0
+
+
+# -- cross-tile overload coupling (disco/tiles._shed_slo_poll) ---------------
+
+def _door_plan(metric: bool, tag: str):
+    from firedancer_tpu.disco import Topology
+    topo = (Topology(f"door{tag}{os.getpid()}", wksp_size=1 << 21)
+            .link("a_b", depth=32)
+            .tile("s", "sock", outs=["a_b"])
+            .tile("d", "sink", ins=["a_b"]))
+    if metric:
+        topo.tile("metric", "metric", port=0)
+    return topo.build()
+
+
+def _set_breach(plan, w, value: int):
+    import numpy as np
+    from firedancer_tpu.disco.topo import METRICS_SLOTS
+    spec = plan["tiles"]["metric"]
+    view = w.view(spec["metrics_off"], METRICS_SLOTS * 8).view(np.uint64)
+    view[spec["metrics_names"].index("slo_breach")] = value
+
+
+# (metric tile declared, slo_breach gauge, door expected to trip)
+DOOR_CASES = {"breach": (True, 1, True), "clear": (True, 0, False),
+              "no_metric_tile": (False, None, False)}
+
+
+@pytest.mark.parametrize("metric,breach,tripped",
+                         list(DOOR_CASES.values()), ids=list(DOOR_CASES))
+def test_pressure_probe_reads_the_slo_breach_gauge(metric, breach,
+                                                   tripped):
+    """PressureProbe on a real plan: overloaded() is the metric tile's
+    slo_breach gauge as it stands in shm, and False with no metric
+    tile to read."""
+    from firedancer_tpu.disco.slo import PressureProbe
+    from firedancer_tpu.runtime import Workspace
+    plan = _door_plan(metric, "p")
+    w = Workspace(plan["wksp"]["name"], plan["wksp"]["size"],
+                  create=False)
+    try:
+        probe = PressureProbe(plan, w)
+        assert probe.overloaded() is False       # fresh plan: all zero
+        if breach is not None:
+            _set_breach(plan, w, breach)
+        assert probe.overloaded() is tripped
+        if breach:
+            _set_breach(plan, w, 0)              # the breach clears
+            assert probe.overloaded() is False
+    finally:
+        w.close()
+        Workspace.unlink_name(plan["wksp"]["name"])
+
+
+@pytest.mark.parametrize("metric,breach,tripped",
+                         list(DOOR_CASES.values()), ids=list(DOOR_CASES))
+def test_shed_slo_poll_trips_the_door_only_on_a_breach(metric, breach,
+                                                       tripped):
+    """The ingest doors' housekeeping poll: a set slo_breach gauge
+    trips the gate into overload for its hold window; a clear gauge, or
+    a topology with no metric tile, leaves the door alone. The probe
+    is made once per tile and kept on its context."""
+    from types import SimpleNamespace
+
+    from firedancer_tpu.disco.tiles import _shed_slo_poll
+    from firedancer_tpu.runtime import Workspace
+    plan = _door_plan(metric, "s")
+    w = Workspace(plan["wksp"]["name"], plan["wksp"]["size"],
+                  create=False)
+    try:
+        if breach is not None:
+            _set_breach(plan, w, breach)
+        ctx = SimpleNamespace(plan=plan, wksp=w)
+        gate = PeerGate({"overload_hold_s": 30.0})
+        _shed_slo_poll(ctx, None)                # no gate: nothing read
+        assert not hasattr(ctx, "_pressure_probe")
+        _shed_slo_poll(ctx, gate)
+        assert gate.overloaded() is tripped
+        probe = ctx._pressure_probe
+        _shed_slo_poll(ctx, gate)
+        assert ctx._pressure_probe is probe
+        assert gate.overloaded() is tripped
+    finally:
+        w.close()
+        Workspace.unlink_name(plan["wksp"]["name"])

@@ -62,10 +62,6 @@ def _scripted_cfg(extra=None, stages=None):
         "flood_soak": {"cmd": _ok_cmd(
             {"platform": "tpu", "flood_goodput_tps": 900.0,
              "flood_pass": True, "rlc_prefilter_vps": 480000.0})},
-        "autotune": {"cmd": _ok_cmd(
-            {"platform": "cpu", "tuned_vs_default_tps": 1.04,
-             "autotune_knobs": {"coalesce_us": 400, "verify_batch": 32},
-             "autotune_points": 9})},
         "multichip": {"cmd": _ok_cmd(
             {"platform": "tpu", "multichip_devices": 2,
              "layouts": {"one_mesh_tile": {"vps": 800000.0},
@@ -463,7 +459,6 @@ def test_artifact_merges_all_stanzas(sweep):
     assert doc["e2e_tps"] == 53000.0
     assert doc["e2e_leader_knee_tps"] == 1200.0
     assert doc["flood_pass"] is True
-    assert doc["tuned_vs_default_tps"] == 1.04
     assert doc["mxu_fmul"]["mxu_verdict"] == "NO-GO"
     assert doc["multichip_choice"] == "rr_tiles"
     # witnessed-vs-fallback is explicit per metric
